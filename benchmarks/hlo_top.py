@@ -9,6 +9,8 @@ memory traffic) by result bytes, printing the JAX source metadata --
 this is the "profile" of the dry-run perf loop (EXPERIMENTS.md §Perf).
 """
 import os
+# a CPU-only tool: it never takes a chip, even on a machine with one
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 
 import argparse  # noqa: E402

@@ -6,8 +6,9 @@ Per (arch x shape x mesh) record (produced by repro.launch.dryrun):
   memory_s     = HLO_bytes / HBM_bw                (per device)
   collective_s = ring wire bytes / (links x link_bw) (per device)
 
-Hardware constants: TPU-v5e-class -- 197 TFLOP/s bf16, 819 GB/s HBM,
-~50 GB/s/link ICI, 4 links/chip usable on a 2-D torus axis pair.
+Hardware constants: the v5e entry of ``repro.core.cost.CHIPS`` (197
+TFLOP/s bf16, 819 GB/s HBM), ~50 GB/s/link ICI, 4 links/chip usable on
+a 2-D torus axis pair.
 HLO FLOPs/bytes are the scan-extrapolated per-device totals (XLA counts
 a while body once; the dry-run recovers multiplicity by compiling 1- and
 2-group unrolled variants -- see dryrun.py).
@@ -18,8 +19,10 @@ import argparse
 import json
 from typing import Any, Dict
 
-PEAK_FLOPS = 197e12
-HBM_BW = 819e9
+from repro.core.cost import CHIPS, DSE_TARGET
+
+PEAK_FLOPS = CHIPS[DSE_TARGET].peak_flops
+HBM_BW = CHIPS[DSE_TARGET].hbm_bytes_per_s
 LINK_BW = 50e9
 N_LINKS = 4
 

@@ -73,7 +73,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import ir
+from repro.core import backend, ir
 from repro.core import measure as measure_mod
 from repro.core import resilience, telemetry
 from repro.core.codegen_jax import execute
@@ -758,6 +758,7 @@ def main(argv=None) -> None:
                     help="write rows as BENCH_<rev>.json (OUT = dir or "
                          ".json path)")
     args = ap.parse_args(argv)
+    backend.enable_compile_cache()
     TIMING["repeat"] = args.repeat
     TIMING["warmup"] = args.warmup
     TIMING["topk"] = args.topk

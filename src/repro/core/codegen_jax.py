@@ -229,13 +229,13 @@ def _execute_groupbyfold(p: ir.GroupByFold, env: Env, outer_idx: Tuple) -> Any:
             partial = _execute(p.inner, sub, stack)
             return p.combine(acc, partial)
         key, val = p.fn(stack, *_windows(sub, p, stack))
-        key = jnp.asarray(key, jnp.int32)
-        cur = jax.lax.dynamic_slice(
-            acc, (key,) + (0,) * len(p.elem_shape), (1,) + tuple(p.elem_shape))
+        starts = (jnp.asarray(key, jnp.int32),) \
+            + (jnp.int32(0),) * len(p.elem_shape)
+        cur = jax.lax.dynamic_slice(acc, starts,
+                                    (1,) + tuple(p.elem_shape))
         new = p.combine(cur[0], jnp.asarray(val, acc.dtype))
         new = jnp.asarray(new, acc.dtype).reshape((1,) + tuple(p.elem_shape))
-        return jax.lax.dynamic_update_slice(
-            acc, new, (key,) + (0,) * len(p.elem_shape))
+        return jax.lax.dynamic_update_slice(acc, new, starts)
 
     return jax.lax.fori_loop(0, n, body, acc0)
 

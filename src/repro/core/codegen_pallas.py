@@ -20,22 +20,22 @@ of MaxJ templates (see Table 4 mapping in DESIGN.md):
     accumulator block, Map terminals stream a write-once output block
     per grid step (never revisited)
 
-Kernels are validated in ``interpret=True`` mode against the
-``codegen_jax`` oracle; TPU (MXU/VMEM alignment) is the codegen target.
+Every kernel is validated against the ``codegen_jax`` oracle in Pallas
+interpret mode on the CPU; on a TPU Mosaic compiles it
+(``backend.interpret`` decides, from the JAX backend).
 """
 from __future__ import annotations
 
+import dataclasses
+import math
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
 
-from . import ir, resilience, telemetry
+from . import backend, ir, resilience, telemetry
 from .affine import AffineMap
-
-INTERPRET = True  # container is CPU-only; flip on real TPU
 
 
 def _call_map(amap: "AffineMap", stack: Tuple) -> Tuple:
@@ -84,40 +84,138 @@ def _block_index_map(copy_map: AffineMap, tile_shape: Tuple[int, ...],
     return imap
 
 
-def _gather_window(tile, amap, window: Tuple[int, ...], stack):
-    """Slice one access window out of an on-chip tile at the given index
-    stack (singleton dims squeezed, matching the oracle's semantics)."""
-    starts = _call_map(amap, tuple(stack))
-    starts = tuple(jnp.asarray(s, jnp.int32) for s in starts[-tile.ndim:])
-    return jnp.squeeze(jax.lax.dynamic_slice(tile, starts, window))
+def _tile_view(ref, a: ir.Access, grid_rank: int,
+               dom: Tuple[int, ...], row0=0, at: Tuple = ()):
+    """What ``a`` reads at local indices ``row0 + [0, dom[0])`` x
+    ``[0, dom[1:])`` in one grid step, loaded from the on-chip tile
+    ``ref[at]`` as one slice (``at`` indexes leading buffer dims, such
+    as a stage scratch's rotating slot).
 
-
-def _vmapped_tile_fn(inner: ir.Map, n_reads: int) -> Callable:
-    """Vector template: apply the Map's scalar fn across the whole tile.
-
-    Reads must be tile-local (AffineMap with zero base).  Returns
-    f(grid_idx, *tiles) -> tile of inner.shape.
+    Reads must be tile-local: the window may not move with the grid
+    index, and each local dim either leaves it in place (one window
+    shared by the whole tile) or advances one tile dim by one where the
+    window is 1 wide (a row read).  Returns ``(view, dims)``: the
+    per-index windows, squeezed as the oracle squeezes them, stacked
+    along the local dims ``dims`` as leading axes in order.
     """
-    dom = inner.domain
+    shape = tuple(ref.shape[len(at):])
+    nd, k = len(shape), len(dom)
+    window = tuple(a.window)
 
-    def run(grid_idx, *tiles):
-        def body(flat):
-            idx = []
-            rem = flat
-            for e in reversed(dom):
-                idx.append(rem % e)
-                rem = rem // e
-            idx = tuple(reversed(idx))
-            stack = tuple(grid_idx) + idx
-            wins = [_gather_window(t, a.index_map, a.window, stack)
-                    for t, a in zip(tiles, inner.reads)]
-            return inner.fn(stack, *wins)
+    def start(stack):
+        return tuple(int(s) for s in _call_map(a.index_map, stack)[-nd:])
 
-        n = int(np.prod(dom))
-        vals = jax.vmap(body)(jnp.arange(n, dtype=jnp.int32))
-        return vals.reshape(tuple(dom) + vals.shape[1:])
+    base = start((0,) * (grid_rank + k))
 
-    return run
+    def step(j):
+        unit = tuple(int(i == j) for i in range(grid_rank + k))
+        return tuple(s - b for s, b in zip(start(unit), base))
+
+    src = getattr(a.src, "name", type(a.src).__name__)
+    if any(any(step(j)) for j in range(grid_rank)):
+        raise NotImplementedError(
+            f"tile read of {src} moves with the grid index; only "
+            "tile-local reads lower to a kernel")
+    stepped: Dict[int, int] = {}          # tile dim -> local dim
+    for j in range(k):
+        st = step(grid_rank + j)
+        hot = [d for d, s in enumerate(st) if s]
+        if not hot:
+            continue
+        if len(hot) != 1 or st[hot[0]] != 1 or window[hot[0]] != 1 \
+                or hot[0] in stepped:
+            raise NotImplementedError(
+                f"tile read of {src} steps {st} per local index; only "
+                "unit-stride row reads and shared windows lower")
+        stepped[hot[0]] = j
+    idx = []
+    for d in range(nd):
+        j = stepped.get(d)
+        ext = window[d] if j is None else dom[j]
+        if base[d] < 0 or base[d] + ext > shape[d]:
+            raise NotImplementedError(
+                f"tile read of {src} leaves its {shape} tile")
+        if j == 0 and not isinstance(row0, int):
+            idx.append(pl.ds(base[d] + row0, ext))
+        else:
+            lo = base[d] + (row0 if j == 0 else 0)
+            idx.append(slice(lo, lo + ext))
+    view = ref[tuple(at) + tuple(idx)]
+    order = sorted(stepped, key=stepped.get)
+    rest = [d for d in range(nd) if d not in stepped]
+    if order + rest != list(range(nd)):
+        view = jnp.transpose(view, order + rest)
+    vshape = tuple(dom[stepped[d]] for d in order) \
+        + tuple(window[d] for d in rest if window[d] != 1)
+    return view.reshape(vshape), tuple(stepped[d] for d in order)
+
+
+def _tile_apply(fn: Callable, grid_idx, dom: Tuple[int, ...], refs,
+                reads, row0=0, ats=None) -> Any:
+    """Vector template: evaluate the per-index body
+    ``fn(stack, *windows)`` at local indices ``row0 + [0, dom[0])`` x
+    ``[0, dom[1:])`` at once.
+
+    The body is vmapped over slices of the on-chip tiles
+    (``_tile_view``), so it runs as vector code on the tile; nothing is
+    gathered per element.  Returns the body's value(s) stacked to
+    ``dom + value shape``.
+    """
+    grid_idx = tuple(grid_idx)
+    k = len(dom)
+    ats = ats or [()] * len(refs)
+    views = [_tile_view(r, a, len(grid_idx), dom, row0, at)
+             for r, a, at in zip(refs, reads, ats)]
+
+    def body(*args):
+        return fn(grid_idx + tuple(args[:k]), *args[k:])
+
+    for j in reversed(range(k)):   # outermost vmap strips local dim 0
+        axes = tuple(0 if i == j else None for i in range(k)) \
+            + tuple(0 if j in dims else None for _, dims in views)
+        body = jax.vmap(body, in_axes=axes)
+    local = [jax.lax.broadcasted_iota(jnp.int32, (e,), 0) for e in dom]
+    local[0] = local[0] + row0
+    return body(*local, *[v for v, _ in views])
+
+
+def _is_add(combine: Callable, shape, dtype) -> bool:
+    """True when ``combine(a, b)`` is exactly ``a + b``."""
+    spec = jax.ShapeDtypeStruct(tuple(shape), jnp.dtype(dtype))
+    jaxpr = jax.make_jaxpr(combine)(spec, spec).jaxpr
+    if len(jaxpr.eqns) != 1:
+        return False
+    eqn = jaxpr.eqns[0]
+    return (eqn.primitive is jax.lax.add_p
+            and list(eqn.invars) == list(jaxpr.invars)
+            and list(eqn.outvars) == list(jaxpr.outvars))
+
+
+def _combine_rows(combine: Callable, vals):
+    """Fold the leading axis of ``vals`` with the associative
+    ``combine``: one vector sum when it is addition, a halving tree of
+    vectorised combines otherwise."""
+    if _is_add(combine, vals.shape[1:], vals.dtype):
+        return jnp.sum(vals, axis=0)
+    while vals.shape[0] > 1:
+        h = vals.shape[0] // 2
+        head = jax.vmap(combine)(vals[:h], vals[h:2 * h])
+        vals = (jnp.concatenate([head, vals[2 * h:]])
+                if vals.shape[0] % 2 else head)
+    return vals[0]
+
+
+def _cam_update(keys, vals, num_keys: int, dtype):
+    """CAM template: a tile's (key, value) pairs scattered into a dense
+    ``(num_keys, value words)`` block by one one-hot MXU matmul (keys
+    outside ``[0, num_keys)`` drop out, as with ``jax.nn.one_hot``)."""
+    b = keys.shape[0]
+    onehot_t = (jax.lax.broadcasted_iota(jnp.int32, (num_keys, b), 0)
+                == keys.astype(jnp.int32)[None, :]).astype(dtype)
+    vals2 = jnp.asarray(vals, dtype).reshape(b, -1)
+    # HIGHEST: the MXU's default pass would round f32 values to bf16
+    return jnp.dot(onehot_t, vals2, preferred_element_type=dtype,
+                   precision=jax.lax.Precision.HIGHEST)
 
 
 # --------------------------------------------------------------------
@@ -130,8 +228,9 @@ def lower_tiled_map(p: ir.MultiFold) -> Callable:
     inner = p.inner
     grid = tuple(p.domain)
     loads = [tc for tc in p.loads if isinstance(tc.src, ir.Tensor)]
-    assert len(loads) == len(inner.reads), "all reads must be tiled"
-    tile_fn = _vmapped_tile_fn(inner, len(loads))
+    slot = {tc.uid: i for i, tc in enumerate(loads)}
+    assert all(getattr(a.src, "uid", None) in slot for a in inner.reads), \
+        "all reads must be tiled"
 
     in_specs = [
         pl.BlockSpec(tc.tile_shape,
@@ -147,9 +246,10 @@ def lower_tiled_map(p: ir.MultiFold) -> Callable:
     def kernel(*refs):
         *ins, out = refs
         gidx = tuple(pl.program_id(i) for i in range(len(grid)))
-        out[...] = tile_fn(gidx, *[r[...] for r in ins]).astype(out.dtype)
-
-    order = {tc.uid: i for i, tc in enumerate(loads)}
+        tiles = [ins[slot[a.src.uid]] for a in inner.reads]
+        vals = _tile_apply(inner.fn, gidx, tuple(inner.domain), tiles,
+                           inner.reads)
+        out[...] = vals.reshape(out.shape).astype(out.dtype)
 
     def call(**tensors):
         args = [jnp.asarray(tensors[tc.src.name]) for tc in loads]
@@ -157,7 +257,7 @@ def lower_tiled_map(p: ir.MultiFold) -> Callable:
             kernel, grid=grid, in_specs=in_specs, out_specs=out_spec,
             out_shape=jax.ShapeDtypeStruct(tuple(p.range_shape),
                                            jnp.dtype(p.dtype)),
-            interpret=INTERPRET)(*args)
+            interpret=backend.interpret())(*args)
 
     return call
 
@@ -217,7 +317,7 @@ def lower_tiled_gemm(p: ir.MultiFold) -> Callable:
             kernel, grid=grid, in_specs=in_specs, out_specs=out_spec,
             out_shape=jax.ShapeDtypeStruct(tuple(p.range_shape),
                                            jnp.dtype(p.dtype)),
-            interpret=INTERPRET)(x, y)
+            interpret=backend.interpret())(x, y)
 
     return call
 
@@ -240,7 +340,6 @@ def lower_tiled_groupby(p: ir.GroupByFold,
     assert len(loads) == len(inner.reads)
     elem = tuple(p.elem_shape)
     k = p.num_keys
-    ew = int(np.prod(elem)) if elem else 1
 
     in_specs = [
         pl.BlockSpec(tc.tile_shape,
@@ -258,31 +357,16 @@ def lower_tiled_groupby(p: ir.GroupByFold,
         def _init():
             out[...] = jnp.asarray(p.init(), out.dtype)
 
-        tiles = [r[...] for r in ins]
-
-        def body(l):
-            stack = (gi, l)
-            wins = []
-            for t, a in zip(tiles, inner.reads):
-                starts = _call_map(a.index_map, stack)
-                starts = tuple(jnp.asarray(s, jnp.int32)
-                               for s in starts[-t.ndim:])
-                wins.append(jnp.squeeze(
-                    jax.lax.dynamic_slice(t, starts, a.window)))
-            return inner.fn(stack, *wins)
-
-        keys, vals = jax.vmap(body)(jnp.arange(b, dtype=jnp.int32))
-        onehot = jax.nn.one_hot(keys, k, dtype=out.dtype)       # (b, k)
-        vals2 = jnp.asarray(vals, out.dtype).reshape(b, ew)     # (b, ew)
-        upd = jnp.dot(onehot.T, vals2)                          # MXU scatter
-        out[...] += upd.reshape(out_shape)
+        keys, vals = _tile_apply(inner.fn, (gi,), (b,), ins, inner.reads)
+        out[...] += _cam_update(keys, vals, k, out.dtype
+                                ).reshape(out_shape)
 
     def call(**tensors):
         args = [jnp.asarray(tensors[tc.src.name]) for tc in loads]
         return pl.pallas_call(
             kernel, grid=(g,), in_specs=in_specs, out_specs=out_spec,
             out_shape=jax.ShapeDtypeStruct(out_shape, jnp.dtype(p.dtype)),
-            interpret=INTERPRET)(*args)
+            interpret=backend.interpret())(*args)
 
     return call
 
@@ -324,20 +408,7 @@ def lower_tiled_flatmap(p: ir.FlatMap) -> Callable:
             buf[...] = jnp.zeros_like(buf)
             cnt[...] = jnp.zeros_like(cnt)
 
-        tiles = [r[...] for r in ins]
-
-        def body(l):
-            stack = (gi, l)
-            wins = []
-            for t, a in zip(tiles, inner.reads):
-                starts = _call_map(a.index_map, stack)
-                starts = tuple(jnp.asarray(s, jnp.int32)
-                               for s in starts[-t.ndim:])
-                wins.append(jnp.squeeze(
-                    jax.lax.dynamic_slice(t, starts, a.window)))
-            return inner.fn(stack, *wins)
-
-        vals, cnts = jax.vmap(body)(jnp.arange(b, dtype=jnp.int32))
+        vals, cnts = _tile_apply(inner.fn, (gi,), (b,), ins, inner.reads)
         vals = vals.reshape(b * m)
         lane = jnp.arange(m)[None, :]
         valid = (lane < cnts[:, None]).reshape(b * m)
@@ -362,7 +433,7 @@ def lower_tiled_flatmap(p: ir.FlatMap) -> Callable:
                 jax.ShapeDtypeStruct((cap,), jnp.dtype(p.dtype)),
                 jax.ShapeDtypeStruct((1,), jnp.int32),
             ],
-            interpret=INTERPRET)(*args)
+            interpret=backend.interpret())(*args)
         return buf, cnt[0]
 
     return call
@@ -373,18 +444,35 @@ def lower_tiled_flatmap(p: ir.FlatMap) -> Callable:
 # --------------------------------------------------------------------
 
 
-def _read_tiles(reads, env: Dict[str, Any], stack):
-    """Resolve a pattern's reads against in-kernel buffers keyed by the
-    TileCopy uid (input blocks and VMEM stage scratch alike)."""
-    wins = []
-    for a in reads:
+# rows one vector pass of a fused kernel evaluates: the body's code and
+# its temporaries stay the size of one chunk however large the DSE
+# makes the tile (Mosaic emits straight-line code per vreg)
+_CHUNK_ROWS = 1024
+
+
+def _chunk_rows(b: int) -> int:
+    """All of a small tile; else the largest power-of-two part of ``b``
+    up to ``_CHUNK_ROWS``, kept lane-aligned (a multiple of 128)."""
+    if b <= _CHUNK_ROWS:
+        return b
+    c = math.gcd(b, _CHUNK_ROWS)
+    return c if c >= 128 else b
+
+
+def _env_apply(q: ir.Pattern, fn: Callable, g, env: Dict[str, Any],
+               r0, rows: int):
+    """``_tile_apply`` of a fused tile pattern over its local rows
+    ``r0 + [0, rows)``, reading the in-kernel buffers that ``env``
+    keys by TileCopy uid as ``(ref, leading index)`` (input blocks and
+    VMEM stage scratch alike)."""
+    for a in q.reads:
         if not isinstance(a.src, ir.TileCopy):
             raise NotImplementedError(
                 f"fused chain: read of {type(a.src).__name__} left in "
                 "place (expected every source tiled into VMEM)")
-        wins.append(_gather_window(env[a.src.uid], a.index_map,
-                                   a.window, stack))
-    return wins
+    bufs = [env[a.src.uid] for a in q.reads]
+    return _tile_apply(fn, (g,), (rows,), [r for r, _ in bufs], q.reads,
+                       r0, [at for _, at in bufs])
 
 
 def _collect_dag_loads(terminals):
@@ -420,13 +508,23 @@ def _collect_dag_loads(terminals):
     return tensor_groups, stage_loads
 
 
-def _terminal_emitter(p: ir.Pattern):
-    """Template selection for one fused-DAG terminal.
+@dataclasses.dataclass(frozen=True)
+class _Terminal:
+    """How one fused-DAG terminal writes its output: the full output
+    array shape, the logical shape results reshape to, the output
+    BlockSpec, ``init(out)`` run at grid step 0 (None for streamed
+    outputs), and ``emit(g, out, env, r0, rows)`` which folds or writes
+    the terminal's rows ``r0 + [0, rows)`` of grid step ``g``."""
 
-    Returns ``(out_full, out_shape, spec, emit)``: the padded full
-    output array shape, the logical shape to reshape results to, the
-    output BlockSpec, and ``emit(g, out, env)`` which updates the
-    terminal's output block at grid step ``g``:
+    full: Tuple[int, ...]
+    shape: Tuple[int, ...]
+    spec: Any
+    init: Optional[Callable]
+    emit: Callable
+
+
+def _terminal_emitter(p: ir.Pattern) -> _Terminal:
+    """Template selection for one fused-DAG terminal:
 
       * fold terminal       -> revisited accumulator block (init at
                                g == 0, partial fold merged via combine)
@@ -450,18 +548,17 @@ def _terminal_emitter(p: ir.Pattern):
         if len(elem) > 1:
             raise NotImplementedError(
                 "Map terminals stream blocks of rank <= 2")
-        out_block = (b,) + (elem if elem else (1,))
+        row = elem if elem else (1,)
         out_shape = tuple(p.range_shape)            # (n,) + elem
-        out_full = (out_shape[0],) + (elem if elem else (1,))
-        tile_fn = _stage_tile_fn(q)
 
-        def emit_map(g, out, env):
-            tile = tile_fn((g,), env)
-            out[...] = jnp.asarray(tile, out.dtype).reshape(out_block)
+        def emit_map(g, out, env, r0, rows):
+            vals = _env_apply(q, q.fn, g, env, r0, rows)
+            out[pl.ds(r0, rows)] = jnp.asarray(vals, out.dtype
+                                               ).reshape((rows,) + row)
 
-        spec = pl.BlockSpec(
-            out_block, lambda g: (g,) + (0,) * (len(out_block) - 1))
-        return out_full, out_shape, spec, emit_map
+        spec = pl.BlockSpec((b,) + row, lambda g: (g,) + (0,) * len(row))
+        return _Terminal((out_shape[0],) + row, out_shape, spec, None,
+                         emit_map)
 
     if isinstance(p, ir.MultiFold):
         # terminal fold: revisited accumulator block, inner partial
@@ -476,26 +573,27 @@ def _terminal_emitter(p: ir.Pattern):
         if len(range_shape) > 2:
             raise NotImplementedError("fold accumulators of rank <= 2")
 
-        def emit_fold(g, out, env):
-            @pl.when(g == 0)
-            def _init():
-                out[...] = jnp.asarray(p.init(), out.dtype
-                                       ).reshape(out_block)
+        def init_fold(out):
+            out[...] = jnp.asarray(p.init(), out.dtype).reshape(out_block)
 
-            def body(l, acc):
-                stack = (g, l)
-                wins = _read_tiles(q.reads, env, stack)
-                return jnp.asarray(q.fn(stack, acc, *wins),
-                                   acc.dtype).reshape(acc.shape)
+        def emit_fold(g, out, env, r0, rows):
+            # every index's update applied to the identity, then folded
+            # with combine: a fold body updates its accumulator by
+            # combining in its own contribution, f(acc, x) =
+            # combine(acc, f(init, x)), so the order-free tree is the
+            # sequential fold
+            def one(s, *w):
+                z = jnp.asarray(q.init(), out.dtype)
+                return jnp.asarray(q.fn(s, z, *w), out.dtype)
 
-            partial = jax.lax.fori_loop(
-                0, b, body, jnp.asarray(q.init(), jnp.dtype(p.dtype)))
-            cur = out[...].reshape(range_shape)
-            out[...] = jnp.asarray(p.combine(cur, partial),
-                                   out.dtype).reshape(out_block)
+            per = _env_apply(q, one, g, env, r0, rows)
+            partial = _combine_rows(q.combine, per).reshape(out_block)
+            out[...] = jnp.asarray(p.combine(out[...], partial),
+                                   out.dtype)
 
         spec = pl.BlockSpec(out_block, lambda g: (0,) * len(out_block))
-        return out_block, range_shape, spec, emit_fold  # full == block
+        return _Terminal(out_block, range_shape, spec, init_fold,
+                         emit_fold)
 
     if isinstance(p, ir.GroupByFold):
         # terminal keyed fold: CAM template (one-hot MXU scatter) into a
@@ -503,53 +601,29 @@ def _terminal_emitter(p: ir.Pattern):
         if not isinstance(q, ir.GroupByFold):
             raise NotImplementedError("fused chain: keyed-fold tile "
                                       "expected under GroupByFold root")
+        if not _is_add(p.combine, p.shape, p.dtype):
+            raise NotImplementedError(
+                "fused keyed-fold terminal: combine must be addition "
+                "(the one-hot MXU scatter sums)")
         elem = tuple(p.elem_shape)
         k = p.num_keys
-        ew = int(np.prod(elem)) if elem else 1
-        out_shape = (k,) + elem
         # scalar elements would make a rank-1 (k,) block; pad to (k, 1)
         # (Mosaic wants >= 2-D blocks, same as _padded_out for folds)
         out_block = (k,) + (elem if elem else (1,))
 
-        def emit_cam(g, out, env):
-            @pl.when(g == 0)
-            def _init():
-                out[...] = jnp.asarray(p.init(), out.dtype
-                                       ).reshape(out_block)
+        def init_cam(out):
+            out[...] = jnp.asarray(p.init(), out.dtype).reshape(out_block)
 
-            def body(l):
-                stack = (g, l)
-                return q.fn(stack, *_read_tiles(q.reads, env, stack))
-
-            keys, vals = jax.vmap(body)(jnp.arange(b, dtype=jnp.int32))
-            onehot = jax.nn.one_hot(keys, k, dtype=out.dtype)
-            vals2 = jnp.asarray(vals, out.dtype).reshape(b, ew)
-            out[...] += jnp.dot(onehot.T, vals2,
-                                preferred_element_type=out.dtype
-                                ).reshape(out_block)
+        def emit_cam(g, out, env, r0, rows):
+            keys, vals = _env_apply(q, q.fn, g, env, r0, rows)
+            out[...] += _cam_update(keys, vals, k, out.dtype
+                                    ).reshape(out_block)
 
         spec = pl.BlockSpec(out_block, lambda g: (0,) * len(out_block))
-        return out_block, out_shape, spec, emit_cam
+        return _Terminal(out_block, (k,) + elem, spec, init_cam, emit_cam)
 
     raise NotImplementedError(
         f"no fused-chain template for terminal {type(p).__name__}")
-
-
-def _stage_tile_fn(stage: ir.Map) -> Callable:
-    """Producer stage: compute the whole (b,)+elem tile for one grid
-    step.  f(grid_idx, env) -> tile (lands in this stage's VMEM
-    scratch)."""
-    (b,) = stage.domain
-
-    def run(grid_idx, env):
-        def body(l):
-            stack = tuple(grid_idx) + (l,)
-            return stage.fn(stack, *_read_tiles(stage.reads, env, stack))
-
-        vals = jax.vmap(body)(jnp.arange(b, dtype=jnp.int32))
-        return vals.reshape((b,) + tuple(stage.elem_shape))
-
-    return run
 
 
 def _padded_out(range_shape: Tuple[int, ...]) -> Tuple[int, ...]:
@@ -609,16 +683,23 @@ def _lower_fused_dag_body(terminals, grid_n: int, depth: int) -> Callable:
                      _block_index_map(tc.index_map, tc.tile_shape, 1))
         for tc in reps
     ]
-    scratch_shapes = [pltpu.VMEM((depth,) + tuple(tc.tile_shape),
-                                 jnp.dtype(tc.dtype))
+    # stage scratch: ``depth`` rotating slots on a leading untiled dim;
+    # a 1-D stage tile sits as one (1, b) lane row per slot
+    def slot_shape(tc):
+        t = tuple(tc.tile_shape)
+        return (depth,) + ((1,) + t if len(t) == 1 else t)
+
+    scratch_shapes = [pltpu.VMEM(slot_shape(tc), jnp.dtype(tc.dtype))
                       for tc in stage_loads]
-    stage_fns = [_stage_tile_fn(tc.src) for tc in stage_loads]
+    stages = [tc.src for tc in stage_loads]
+    for st in stages:
+        if not isinstance(st, ir.Map) or len(st.domain) != 1:
+            raise NotImplementedError(
+                "fused chain: producer stages must be 1-D tile Maps")
 
     emitters = [_terminal_emitter(t) for _, t in terminals]
-    out_specs = [spec for _, _, spec, _ in emitters]
-    out_structs = [jax.ShapeDtypeStruct(full, jnp.dtype(t.dtype))
-                   for (full, _, _, _), (_, t) in zip(emitters, terminals)]
-
+    (b,) = terminals[0][1].inner.domain
+    rows = _chunk_rows(b)
     n_in, n_out = len(reps), len(terminals)
 
     def kernel(*refs):
@@ -628,50 +709,57 @@ def _lower_fused_dag_body(terminals, grid_n: int, depth: int) -> Callable:
         g = pl.program_id(0)
         env: Dict[str, Any] = {}
         for uids, r in zip(uid_lists, ins):
-            val = r[...]
             for uid in uids:  # every tree's alias of this tile
-                env[uid] = val
+                env[uid] = (r, ())
+        # consumers read the scratch, not the producing SSA value: the
+        # scratch IS the stage's on-chip buffer (what plan_memory
+        # charges); the slot rotates through the depth copies so
+        # successive grid steps never overwrite a tile a deeper
+        # pipeline stage could still be draining (WAR avoidance)
         slot = g % depth
-        for tc, fn, sc in zip(stage_loads, stage_fns, scratch):
-            sc[pl.ds(slot, 1)] = fn((g,), env).astype(sc.dtype)[None]
-            # consumers read the scratch ref, not the producing SSA
-            # value: the scratch IS the stage's on-chip buffer (it is
-            # what plan_memory charges and what the docs promise), so
-            # it must not be a dead write-only allocation; the slot
-            # rotates through the depth copies so successive grid
-            # steps never overwrite a tile a deeper pipeline stage
-            # could still be draining (WAR avoidance)
-            env[tc.uid] = sc[pl.ds(slot, 1)][0]
-        for (_, _, _, emit), out in zip(emitters, outs):
-            emit(g, out, env)
+        at = {tc.uid: (slot,) + (0,) * (len(sc.shape) - len(tc.tile_shape)
+                                       - 1)
+              for tc, sc in zip(stage_loads, scratch)}
+        for tc, sc in zip(stage_loads, scratch):
+            env[tc.uid] = (sc, at[tc.uid])
+
+        @pl.when(g == 0)
+        def _init():
+            for t, out in zip(emitters, outs):
+                if t.init is not None:
+                    t.init(out)
+
+        def chunk(r0):
+            for tc, st, sc in zip(stage_loads, stages, scratch):
+                vals = _env_apply(st, st.fn, g, env, r0, rows)
+                sc[at[tc.uid] + (pl.ds(r0, rows),)] = jnp.asarray(
+                    vals, sc.dtype).reshape((rows,) + tuple(st.elem_shape))
+            for t, out in zip(emitters, outs):
+                t.emit(g, out, env, r0, rows)
+
+        if rows == b:
+            chunk(0)
+        else:
+            def body(i, carry):
+                chunk(pl.multiple_of(i * rows, rows))
+                return carry
+
+            jax.lax.fori_loop(0, b // rows, body, 0)
 
     run = jax.jit(pl.pallas_call(
         kernel, grid=(grid_n,), in_specs=in_specs,
-        out_specs=out_specs, out_shape=out_structs,
-        scratch_shapes=scratch_shapes, interpret=INTERPRET))
+        out_specs=[t.spec for t in emitters],
+        out_shape=[jax.ShapeDtypeStruct(t.full, jnp.dtype(p.dtype))
+                   for t, (_, p) in zip(emitters, terminals)],
+        scratch_shapes=scratch_shapes, interpret=backend.interpret()))
 
     names = [name for name, _ in terminals]
-    shapes = [shape for _, shape, _, _ in emitters]
 
     def call(**tensors):
         args = [jnp.asarray(tensors[tc.src.name]) for tc in reps]
         outs = run(*args)
-        return {name: out.reshape(shape)
-                for name, shape, out in zip(names, shapes, outs)}
-
-    return call
-
-
-def lower_fused_chain(p: ir.Pattern, depth: int = 2) -> Callable:
-    """Single-terminal front-end over ``lower_fused_dag`` (the PR-2
-    chain API): one fused pattern in, the bare output array out."""
-    if not (p.strided and len(p.domain) == 1):
-        raise NotImplementedError("fused chain: 1-D strided root expected")
-    (grid_n,) = p.domain
-    dag_call = lower_fused_dag(((p.name, p),), grid_n, depth=depth)
-
-    def call(**tensors):
-        return dag_call(**tensors)[p.name]
+        return {name: out.reshape(t.shape)
+                for name, t, out in zip(names, emitters, outs)}
 
     return call
 
@@ -719,9 +807,11 @@ def lower_fused_pipeline(pipe, *, plan=None,
             fdag = plmod.fuse_dag(sub, b, vmem_budget_words=budget // 4)
             runner = lower_fused_dag(fdag.terminals, fdag.grid, depth=d)
             how = "megakernel"
-        except NotImplementedError:
+        except NotImplementedError as e:
             runner = plmod.unfused_runner(sub)  # correctness first
             how = "oracle-chain"
+            resilience.record("lower", resilience.classify(e), sub.name,
+                              how, str(e))
 
             def as_dict(r, names):
                 def run(**tensors):
@@ -787,7 +877,8 @@ def lower_for_timing(p: ir.Pattern, sizes: Dict[str, Tuple[int, ...]], *,
     template fall back to the jitted ``codegen_jax`` oracle of the
     *tiled* IR -- the same executable the fig7 rows time, so measured
     rankings stay comparable across candidates.  On CPU the Pallas path
-    runs in interpret mode (``INTERPRET``); the timing DB records that.
+    runs in interpret mode (``backend.interpret``); the timing DB
+    records that.
     Returns ``(fn, how)`` with ``how`` in {"pallas", "oracle"}.
     """
     from .codegen_jax import execute
@@ -892,28 +983,29 @@ def lower_auto(p: ir.Pattern, *, plan=None, vmem_budget: Optional[int] = None,
 
 def lower_paged_decode(*, batch: int, kv_heads: int, group: int,
                        head_dim: int, page_size: int, n_pages_max: int,
-                       layout: str = "split",
-                       pages_per_step: int = 1) -> Callable:
+                       layout: str = "split") -> Callable:
     """Emit the fused decode megakernel over a paged KV cache.
 
     The ``decode_attention`` DAG lowered as one kernel per layer: the
     KV-append producer writes the step's token into its page slot, then
     the flash-attention fold streams the request's pages with online
     softmax.  The streaming domain is *ragged* (``ir.RaggedExtent``):
-    the grid iterates the static page bound ``n_pages_max`` and
-    predicates in-kernel on the live ``seq_lens`` -- pages past the
-    length contribute exact zeros (mask to ``-1e30`` before the
-    running-max update), so the result is independent of whatever the
-    unallocated page-table tail points at.
+    each request folds only its live pages, ``seq_len // page_size +
+    1`` of the static bound ``n_pages_max``, and masks the slots of the
+    last page past its length to ``-1e30`` before the running-max
+    update, so the result never depends on what unassigned pages hold.
 
-    Layouts: ``split`` takes/returns two pools ``(P, ps, Hkv, dh)``;
-    ``fused`` one head-interleaved pool ``(P, ps, 2*Hkv, dh)`` (K at
-    head ``2h``, V at ``2h+1``) whose page streams both operands of a
-    head in one burst.  Grid is ``(batch, kv_heads)``; the pool blocks
-    are whole-array and revisited (constant index map), the first grid
-    step seeds the output pool from the input, and every step appends
-    only its own ``(request, head)`` slice -- the TPU grid is
-    sequential, so appends never race the copy.
+    Layouts: ``split`` takes/returns two pools ``(P, ps, Hkv*dh)``;
+    ``fused`` one head-interleaved pool ``(P, ps, 2*Hkv*dh)`` (K of
+    head ``h`` at head slot ``2h``, V at ``2h+1``) whose page streams
+    both operands of a head in one burst.  A token is one lane-dense
+    row, so a page is whole (sublane, lane) tiles in HBM and VMEM alike
+    and Mosaic needs no relayout of the pool.  The grid is one step per
+    request.  The page
+    table and the lengths are scalar-prefetched into SMEM; the pools
+    stay in HBM, aliased input to output, so the append writes one
+    token row in place and each live page is DMA'd into a VMEM page
+    buffer -- no step ever copies a whole pool.
 
     Returns ``call(q, new_k, new_v, pools, page_table, seq_lens) ->
     (out, new_pools)`` with ``q`` ``(B, Hkv, group, dh)``, ``new_k`` /
@@ -930,123 +1022,137 @@ def lower_paged_decode(*, batch: int, kv_heads: int, group: int,
         return _lower_paged_decode_body(
             batch=batch, kv_heads=kv_heads, group=group,
             head_dim=head_dim, page_size=page_size,
-            n_pages_max=n_pages_max, layout=layout,
-            pages_per_step=pages_per_step)
+            n_pages_max=n_pages_max, layout=layout)
 
 
 def _lower_paged_decode_body(*, batch: int, kv_heads: int, group: int,
                              head_dim: int, page_size: int,
-                             n_pages_max: int, layout: str,
-                             pages_per_step: int) -> Callable:
+                             n_pages_max: int, layout: str) -> Callable:
+    from jax.experimental.pallas import tpu as pltpu
+
     fused = layout == "fused"
-    ps, npm = page_size, n_pages_max
-    if npm % pages_per_step != 0:
-        raise ValueError(
-            f"pages_per_step {pages_per_step} must divide the static "
-            f"page bound {n_pages_max}")
+    ps, dh = page_size, head_dim
+    n_pools = 1 if fused else 2
+    width = (2 if fused else 1) * kv_heads * dh   # lanes of one token row
     NEG = -1e30
     scale = head_dim ** -0.5
+    # f32 on the MXU: its default pass would round the probabilities to
+    # bf16, which the reference paged path does at other points
+    HIGHEST = jax.lax.Precision.HIGHEST
 
-    def kernel(q_ref, k_ref, v_ref, pt_ref, len_ref, *pool_refs):
-        n_pools = 1 if fused else 2
-        pools_in = pool_refs[:n_pools]
-        out_ref = pool_refs[n_pools]
-        pools_out = pool_refs[n_pools + 1:]
+    def kv_lanes(h):
+        """Lane offsets of head ``h``'s K and V in a token row: fused
+        rows interleave them per head (K at head 2h, V at 2h+1)."""
+        return (2 * h * dh, (2 * h + 1) * dh) if fused \
+            else (h * dh, h * dh)
+
+    def kernel(pt_ref, len_ref, q_ref, *refs):
+        new_rows = refs[:n_pools]              # (1, 1, width) VMEM
+        # refs[n_pools:2 * n_pools] are the input pools, the same HBM
+        # buffers as the aliased output pools used below
+        out_ref = refs[2 * n_pools]
+        pools = refs[2 * n_pools + 1:3 * n_pools + 1]
+        bufs = refs[3 * n_pools + 1:4 * n_pools + 1]    # VMEM page
+        sem = refs[-1]
         b = pl.program_id(0)
-        h = pl.program_id(1)
+        ln = len_ref[b]
 
-        @pl.when((b == 0) & (h == 0))
-        def _seed():
-            for src, dst in zip(pools_in, pools_out):
-                dst[...] = src[...]
+        def copy(src, dst):
+            cps = [pltpu.make_async_copy(s_, d_, sem.at[j])
+                   for j, (s_, d_) in enumerate(zip(src, dst))]
+            for cp in cps:
+                cp.start()
+            for cp in cps:
+                cp.wait()
 
-        ln = len_ref[0]
-        page = pt_ref[0, pl.ds(ln // ps, 1)][0]
-        slot = ln % ps
-        kv_dt = pools_out[0].dtype
-        newk = k_ref[0, 0].astype(kv_dt)[None, None, None, :]
-        newv = v_ref[0, 0].astype(kv_dt)[None, None, None, :]
-        if fused:
-            pool = pools_out[0]
-            pool[pl.ds(page, 1), pl.ds(slot, 1), pl.ds(2 * h, 1), :] = newk
-            pool[pl.ds(page, 1), pl.ds(slot, 1),
-                 pl.ds(2 * h + 1, 1), :] = newv
-        else:
-            kp_, vp_ = pools_out
-            kp_[pl.ds(page, 1), pl.ds(slot, 1), pl.ds(h, 1), :] = newk
-            vp_[pl.ds(page, 1), pl.ds(slot, 1), pl.ds(h, 1), :] = newv
+        # indices stay in bounds whatever the host wrote (a DMA off the
+        # pool is a fault, not a garbage read); gathers clamp the same
+        # way on the reference path
+        n_phys = pools[0].shape[0]
 
-        n_phys = pools_out[0].shape[0]
-        q = q_ref[0, 0].astype(jnp.float32)            # (group, dh)
+        def page_of(p):
+            return jnp.clip(pt_ref[b, jnp.minimum(p, n_pages_max - 1)],
+                            0, n_phys - 1)
 
-        def read_page(pid):
-            if fused:
-                pool = pools_out[0]
-                kpg = pool[pl.ds(pid, 1), :, pl.ds(2 * h, 1), :]
-                vpg = pool[pl.ds(pid, 1), :, pl.ds(2 * h + 1, 1), :]
-            else:
-                kpg = pools_out[0][pl.ds(pid, 1), :, pl.ds(h, 1), :]
-                vpg = pools_out[1][pl.ds(pid, 1), :, pl.ds(h, 1), :]
-            return (kpg.reshape(ps, head_dim).astype(jnp.float32),
-                    vpg.reshape(ps, head_dim).astype(jnp.float32))
+        # KV append in place: the token's page is read, its row at the
+        # token's slot replaced, and the page written back
+        page = page_of(ln // ps)
+        copy([pl_.at[page] for pl_ in pools], bufs)
+        hit = jax.lax.broadcasted_iota(jnp.int32, (ps, 1), 0) == ln % ps
+        for new, buf in zip(new_rows, bufs):
+            buf[...] = jnp.where(hit, new[0].astype(jnp.float32),
+                                 buf[...].astype(jnp.float32)
+                                 ).astype(buf.dtype)
+        copy(bufs, [pl_.at[page] for pl_ in pools])
 
-        def body(step, carry):
-            m, el, acc = carry
-            for j in range(pages_per_step):
-                p = step * pages_per_step + j
-                pid = jnp.clip(pt_ref[0, pl.ds(p, 1)][0], 0,
-                               n_phys - 1)
-                kpg, vpg = read_page(pid)
-                s_ = jnp.dot(q, kpg.T,
-                             preferred_element_type=jnp.float32) * scale
-                slotpos = p * ps + jax.lax.broadcasted_iota(
-                    jnp.int32, (1, ps), 1)
-                s_ = jnp.where(slotpos <= ln, s_, NEG)  # ragged predicate
-                m_new = jnp.maximum(m, s_.max(-1))
-                pexp = jnp.exp(s_ - m_new[:, None])
+        def fold_page(p, carry):
+            copy([pl_.at[page_of(p)] for pl_ in pools], bufs)
+            pages = [buf[...].astype(jnp.float32) for buf in bufs]
+            pos = p * ps + jax.lax.broadcasted_iota(jnp.int32, (1, ps), 1)
+            new = []
+            for h in range(kv_heads):
+                k0, v0 = kv_lanes(h)
+                kpg = pages[0][:, k0:k0 + dh]                 # (ps, dh)
+                vpg = pages[-1][:, v0:v0 + dh]
+                m, el, acc = carry[h]
+                s_ = jax.lax.dot_general(
+                    q_ref[0, h].astype(jnp.float32), kpg,
+                    (((1,), (1,)), ((), ())), precision=HIGHEST,
+                    preferred_element_type=jnp.float32) * scale
+                s_ = jnp.where(pos <= ln, s_, NEG)     # ragged predicate
+                m_new = jnp.maximum(m, s_.max(-1, keepdims=True))
+                pexp = jnp.exp(s_ - m_new)
                 alpha = jnp.exp(m - m_new)
-                el = el * alpha + pexp.sum(-1)
-                acc = acc * alpha[:, None] + jnp.dot(
-                    pexp, vpg, preferred_element_type=jnp.float32)
-                m = m_new
-            return m, el, acc
+                el = el * alpha + pexp.sum(-1, keepdims=True)
+                acc = acc * alpha + jnp.dot(
+                    pexp, vpg, precision=HIGHEST,
+                    preferred_element_type=jnp.float32)
+                new.append((m_new, el, acc))
+            return tuple(new)
 
-        m0 = jnp.full((group,), NEG, jnp.float32)
-        l0 = jnp.zeros((group,), jnp.float32)
-        a0 = jnp.zeros((group, head_dim), jnp.float32)
-        m, el, acc = jax.lax.fori_loop(0, npm // pages_per_step, body,
-                                       (m0, l0, a0))
+        init = tuple((jnp.full((group, 1), NEG, jnp.float32),
+                      jnp.zeros((group, 1), jnp.float32),
+                      jnp.zeros((group, dh), jnp.float32))
+                     for _ in range(kv_heads))
+        final = jax.lax.fori_loop(
+            0, jnp.minimum(ln // ps + 1, n_pages_max), fold_page, init)
         # the step's own token is always live, so el > 0
-        out_ref[0, 0] = acc / el[:, None]
-
-    def pool_specs(pools):
-        return [pl.BlockSpec(tuple(p.shape),
-                             lambda b, h, _nd=p.ndim: (0,) * _nd)
-                for p in pools]
+        for h, (_, el, acc) in enumerate(final):
+            out_ref[0, h] = acc / el
 
     def call(q, new_k, new_v, pools, page_table, seq_lens):
         pools = tuple(jnp.asarray(p) for p in pools)
-        in_specs = [
-            pl.BlockSpec((1, 1, group, head_dim),
-                         lambda b, h: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, head_dim), lambda b, h: (b, h, 0)),
-            pl.BlockSpec((1, 1, head_dim), lambda b, h: (b, h, 0)),
-            pl.BlockSpec((1, npm), lambda b, h: (b, 0)),
-            pl.BlockSpec((1,), lambda b, h: (b,)),
-        ] + pool_specs(pools)
-        out_specs = [
-            pl.BlockSpec((1, 1, group, head_dim),
-                         lambda b, h: (b, h, 0, 0)),
-        ] + pool_specs(pools)
-        out_shape = [jax.ShapeDtypeStruct(
-            (batch, kv_heads, group, head_dim), jnp.float32)] + \
-            [jax.ShapeDtypeStruct(p.shape, p.dtype) for p in pools]
+        kv_dt = pools[0].dtype
+        if fused:
+            new = (jnp.stack([new_k, new_v], axis=2),)
+        else:
+            new = (new_k, new_v)
+        new = tuple(t.reshape(batch, 1, width).astype(kv_dt) for t in new)
+        hbm = pl.BlockSpec(memory_space=pl.ANY)
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(batch,),
+            in_specs=[pl.BlockSpec((1, kv_heads, group, dh),
+                                   lambda b, pt, ln: (b, 0, 0, 0))]
+            + [pl.BlockSpec((1, 1, width), lambda b, pt, ln: (b, 0, 0))
+               for _ in new]
+            + [hbm] * n_pools,
+            out_specs=[pl.BlockSpec((1, kv_heads, group, dh),
+                                    lambda b, pt, ln: (b, 0, 0, 0))]
+            + [hbm] * n_pools,
+            scratch_shapes=[pltpu.VMEM((ps, width), kv_dt)
+                            for _ in range(n_pools)]
+            + [pltpu.SemaphoreType.DMA((n_pools,))])
+        first_pool = 2 + 1 + n_pools           # scalars, q, new rows
         outs = pl.pallas_call(
-            kernel, grid=(batch, kv_heads), in_specs=in_specs,
-            out_specs=out_specs, out_shape=out_shape,
-            interpret=INTERPRET)(
-                q, new_k, new_v, jnp.asarray(page_table, jnp.int32),
-                jnp.asarray(seq_lens, jnp.int32), *pools)
+            kernel, grid_spec=grid_spec,
+            out_shape=[jax.ShapeDtypeStruct(
+                (batch, kv_heads, group, dh), jnp.float32)]
+            + [jax.ShapeDtypeStruct(p.shape, p.dtype) for p in pools],
+            input_output_aliases={first_pool + j: 1 + j
+                                  for j in range(n_pools)},
+            interpret=backend.interpret())(
+                jnp.asarray(page_table, jnp.int32),
+                jnp.asarray(seq_lens, jnp.int32), q, *new, *pools)
         return outs[0], tuple(outs[1:])
 
     return call

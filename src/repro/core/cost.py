@@ -10,9 +10,10 @@ depends on*; loops deeper than that reuse the buffered value.  A copy
 with a constant base (``hoisted``) is loaded exactly once -- the Pipe-0
 preload of Fig. 6.
 
-Hardware constants are the TPU-v5e-class numbers used across the repo
-(197 TFLOP/s bf16, 819 GB/s HBM); the FPGA numbers of the paper map to
-the same two-term structure (compute vs. DRAM stream).
+Hardware constants come from one table of chips keyed by JAX's
+``device_kind`` (``CHIPS``); the DSE plans for the TPU v5e wherever it
+runs.  The FPGA numbers of the paper map to the same two-term structure
+(compute vs. DRAM stream).
 """
 from __future__ import annotations
 
@@ -23,9 +24,43 @@ from typing import Dict, List, Optional, Tuple
 from . import ir
 from .affine import AffineMap
 
-HBM_BYTES_PER_S = 819e9
-PEAK_FLOPS = 197e12
-VMEM_BYTES = 16 * 2 ** 20
+
+@dataclasses.dataclass(frozen=True)
+class Chip:
+    """Per-chip peaks the cost model and the roofline read."""
+
+    hbm_bytes_per_s: float
+    peak_flops: float      # bf16 matmul
+    vmem_bytes: int        # scoped VMEM one Mosaic kernel may use
+
+
+# Published peaks of one chip, from Google Cloud's "TPU v5e" page: 197
+# TFLOP/s bf16, 16 GB of HBM at 819 GB/s.  ``vmem_bytes`` is Mosaic's
+# default scoped-VMEM limit on that chip (the compiler's, not the page's).
+CHIPS: Dict[str, Chip] = {
+    "TPU v5 lite": Chip(hbm_bytes_per_s=819e9, peak_flops=197e12,
+                        vmem_bytes=16 * 2 ** 20),
+}
+DSE_TARGET = "TPU v5 lite"   # the chip the DSE plans for, wherever it runs
+
+
+def chip(kind: Optional[str] = None) -> Chip:
+    """Peaks of ``kind``, by default of the device JAX runs on.  A CPU
+    run plans against ``DSE_TARGET``; a device missing from ``CHIPS``
+    is an error, never a default."""
+    if kind is None:
+        import jax
+        d = jax.devices()[0]
+        kind = DSE_TARGET if d.platform == "cpu" else d.device_kind
+    if kind not in CHIPS:
+        raise KeyError(f"no peaks for device kind {kind!r}; add the chip "
+                       "to cost.CHIPS with its source")
+    return CHIPS[kind]
+
+
+HBM_BYTES_PER_S = CHIPS[DSE_TARGET].hbm_bytes_per_s
+PEAK_FLOPS = CHIPS[DSE_TARGET].peak_flops
+VMEM_BYTES = CHIPS[DSE_TARGET].vmem_bytes
 
 # Fixed per-grid-step DMA cost (issue + flight latency) that bandwidth
 # accounting misses: a tile's transfer can start at most ``depth - 1``

@@ -80,11 +80,11 @@ def dtype_sublane(dtype) -> int:
 # priced under older model semantics (e.g. the pre-PR-2 single-buffer
 # accounting for strided loads, the PR-2 chain-only pipeline pricing
 # superseded by the DAG accounting, the pre-calibration pricing that
-# ignored device identity and launch overhead, or the v4 fixed-depth-2
-# pricing that predates the searched metapipeline buffer depth) must
-# not be replayed as cache hits.  CI keys its persistent
-# REPRO_DSE_CACHE on this string too.
-MODEL_VERSION = 5
+# ignored device identity and launch overhead, the v4 fixed-depth-2
+# pricing that predates the searched metapipeline buffer depth, or the
+# v5 VMEM accounting in unpadded words) must not be replayed as cache
+# hits.  CI keys its persistent REPRO_DSE_CACHE on this string too.
+MODEL_VERSION = 6
 
 
 def _measure_mode(measure: Optional[str]) -> Optional[str]:

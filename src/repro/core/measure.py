@@ -41,22 +41,19 @@ from . import ir, resilience, telemetry
 
 
 def device_kind() -> str:
-    """Normalized device identity ("cpu", "tpu-v5e", ...) keying the
-    timing DB and the calibration profile."""
-    try:
-        import jax
-        d = jax.devices()[0]
-        kind = getattr(d, "device_kind", "") or d.platform
-        return str(kind).strip().lower().replace(" ", "-")
-    except Exception:
-        return "unknown"
+    """Normalized device identity ("cpu", "tpu-v5-lite", ...) keying
+    the timing DB and the calibration profile."""
+    import jax
+    d = jax.devices()[0]
+    kind = d.device_kind or d.platform
+    return str(kind).strip().lower().replace(" ", "-")
 
 
 def interpret_mode() -> bool:
-    """True when the repo's Pallas kernels run interpreted (CPU
-    container); mirrored into every timing-DB key."""
-    from .codegen_pallas import INTERPRET
-    return bool(INTERPRET)
+    """True when the repo's Pallas kernels run interpreted (on the
+    CPU); mirrored into every timing-DB key."""
+    from . import backend
+    return backend.interpret()
 
 
 # --------------------------------------------------------------------------

@@ -28,7 +28,7 @@ terminal set.
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, List, Sequence, Union
+from typing import Dict, List, Sequence, Tuple, Union
 
 import numpy as np
 
@@ -36,15 +36,37 @@ from . import ir
 from .cost import VMEM_BYTES
 
 
+def vmem_bytes(shape, dtype) -> int:
+    """Bytes one copy of a ``shape`` buffer takes in VMEM as Mosaic lays
+    it out: the last dim padded to 128 lanes, the one before to the
+    dtype's sublane tile (8 rows of 32-bit values, 16 of 16-bit, 32 of
+    8-bit).  A 1-D buffer lies in one lane row."""
+    shape = tuple(int(d) for d in shape) or (1,)
+    if len(shape) == 1:
+        shape = (1,) + shape
+    item = np.dtype(dtype).itemsize
+    sub = 8 * max(4 // item, 1)
+    *lead, rows, lanes = shape
+    padded = (-(-rows // sub) * sub) * (-(-lanes // 128) * 128)
+    return int(np.prod(lead, dtype=np.int64)) * padded * item
+
+
 @dataclasses.dataclass
 class BufferAlloc:
     name: str
-    kind: str          # buffer | double_buffer | cache | fifo | cam_dense
+    kind: str          # buffer | double_buffer | stream_out | cache | fifo
+                       # | cam_dense
     words: int
     dtype: str
     double_buffered: bool
     ports: int         # readers + writers (template parameterization)
     depth: int = 1     # buffer copies charged (2 = double buffer)
+    shape: Tuple[int, ...] = ()   # on-chip extent (padded by vmem_bytes)
+
+    @property
+    def bytes(self) -> int:
+        """VMEM bytes of all ``depth`` copies, tile padding included."""
+        return vmem_bytes(self.shape, self.dtype) * max(self.depth, 1)
 
 
 @dataclasses.dataclass
@@ -54,8 +76,7 @@ class MemoryPlan:
 
     @property
     def total_bytes(self) -> int:
-        return sum(b.words * np.dtype(b.dtype).itemsize * max(b.depth, 1)
-                   for b in self.buffers)
+        return sum(b.bytes for b in self.buffers)
 
     @property
     def fits(self) -> bool:
@@ -142,7 +163,7 @@ def _plan_memory_body(roots, vmem_budget_bytes: int, depth: int,
                 name=f"{tc.name}#{idx[0]}", kind=kind, words=tc.words,
                 dtype=tc.dtype, double_buffered=dbl,
                 ports=readers.get(k, 1) + 1,
-                depth=depth if dbl else 1))
+                depth=depth if dbl else 1, shape=tuple(tc.tile_shape)))
             idx[0] += 1
             if isinstance(tc.src, ir.Pattern):
                 visit(tc.src)
@@ -151,7 +172,8 @@ def _plan_memory_body(roots, vmem_budget_bytes: int, depth: int,
                 buffers.append(BufferAlloc(
                     name=f"{a.src.name}_cache#{idx[0]}", kind="cache",
                     words=a.words, dtype=a.src.dtype,
-                    double_buffered=False, ports=2))
+                    double_buffered=False, ports=2,
+                    shape=tuple(a.window)))
                 idx[0] += 1
             elif isinstance(a.src, ir.Pattern):
                 visit(a.src)
@@ -159,17 +181,27 @@ def _plan_memory_body(roots, vmem_budget_bytes: int, depth: int,
             buffers.append(BufferAlloc(
                 name=f"{q.name}_acc#{idx[0]}", kind="cam_dense",
                 words=int(np.prod(q.shape)), dtype=q.dtype,
-                double_buffered=False, ports=2))
+                double_buffered=False, ports=2, shape=tuple(q.shape)))
             idx[0] += 1
         if isinstance(q, ir.FlatMap) and not q.strided:
             buffers.append(BufferAlloc(
                 name=f"{q.name}_fifo#{idx[0]}", kind="fifo",
                 words=int(np.prod(q.shape)), dtype=q.dtype,
-                double_buffered=False, ports=2))
+                double_buffered=False, ports=2, shape=tuple(q.shape)))
             idx[0] += 1
         if q.inner is not None:
             visit(q.inner)
 
     for root in roots:
         visit(root)
+        if isinstance(root, ir.MultiFold) and root.strided \
+                and root.combine is None:
+            # a write-once (streamed) output block is double-buffered
+            # like an input: one copy drains to HBM while the next fills
+            buffers.append(BufferAlloc(
+                name=f"{root.name}_out#{idx[0]}", kind="stream_out",
+                words=int(np.prod(root.update_shape)), dtype=root.dtype,
+                double_buffered=True, ports=2, depth=2,
+                shape=tuple(root.update_shape)))
+            idx[0] += 1
     return MemoryPlan(buffers, vmem_budget_bytes)

@@ -14,7 +14,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-INTERPRET = True
+from repro.core import backend
 
 
 def _auto_blocks(t: int, measure: Optional[str] = None,
@@ -41,8 +41,7 @@ def _fr_kernel(x_ref, w_ref, lo_ref, hi_ref, o_ref):
 def filter_reduce(x: jax.Array, weight: jax.Array, lo, hi, *,
                   block_t: int = 1024, auto_tile: bool = False,
                   measure: Optional[str] = None, policy=None,
-                  options=None,
-                  interpret: Optional[bool] = None) -> jax.Array:
+                  options=None) -> jax.Array:
     """``auto_tile=True`` picks block_t by DSE on the fused filter+fold
     proxy (``repro.core.dse.filter_reduce_program``); ``measure="top_k"``
     backs the choice with real timings (hybrid DSE); ``policy`` (a
@@ -66,6 +65,6 @@ def filter_reduce(x: jax.Array, weight: jax.Array, lo, hi, *,
         ],
         out_specs=pl.BlockSpec((1, 1), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((1, 1), jnp.float32),
-        interpret=INTERPRET if interpret is None else interpret,
+        interpret=backend.interpret(),
     )(x, weight, lo, hi)
     return out[0, 0]

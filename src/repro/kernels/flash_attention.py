@@ -20,7 +20,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-INTERPRET = True
+from repro.core import backend
+
 NEG_INF = -1e30
 
 
@@ -80,8 +81,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     block_q: int = 128, block_k: int = 128,
                     auto_tile: bool = False,
                     measure: Optional[str] = None, policy=None,
-                    options=None,
-                    interpret: Optional[bool] = None) -> jax.Array:
+                    options=None) -> jax.Array:
     """q: (B, Hq, Sq, D); k, v: (B, Hkv, Sk, D) -> (B, Hq, Sq, D).
 
     GQA: the q-head group dim is folded into the grid so each kv head's
@@ -130,6 +130,6 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((block_q,), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
-        interpret=INTERPRET if interpret is None else interpret,
+        interpret=backend.interpret(),
     )(qg, kg, vg)
     return out.reshape(b, hq, sq, d)

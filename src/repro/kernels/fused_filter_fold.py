@@ -18,7 +18,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-INTERPRET = True
+from repro.core import backend
 
 
 def _auto_blocks(t: int, measure: Optional[str] = None,
@@ -48,8 +48,7 @@ def _ff_kernel(x_ref, w_ref, lo_ref, hi_ref, o_ref, mask_ref):
 def fused_filter_fold(x: jax.Array, weight: jax.Array, lo, hi, *,
                       block_t: int = 1024, auto_tile: bool = False,
                       measure: Optional[str] = None,
-                      policy=None, options=None,
-                      interpret: Optional[bool] = None) -> jax.Array:
+                      policy=None, options=None) -> jax.Array:
     """``sum(where(lo <= x < hi, x * weight, 0))`` as a fused two-stage
     megakernel.  ``auto_tile=True`` picks ``block_t`` by *joint* DSE on
     the filter+fold pipeline (``core.dse.select_fused_filter_fold_blocks``
@@ -77,6 +76,6 @@ def fused_filter_fold(x: jax.Array, weight: jax.Array, lo, hi, *,
         out_specs=pl.BlockSpec((1, 1), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((1, 1), jnp.float32),
         scratch_shapes=[pltpu.VMEM((block_t,), jnp.float32)],
-        interpret=INTERPRET if interpret is None else interpret,
+        interpret=backend.interpret(),
     )(x, weight, lo, hi)
     return out[0, 0]

@@ -24,7 +24,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-INTERPRET = True
+from repro.core import backend
 
 
 def _auto_blocks(n: int, k: int, d: int,
@@ -61,9 +61,7 @@ def _km_kernel(pts_ref, cents_ref, sums_ref, counts_ref, assign_ref):
 def fused_kmeans_step(points: jax.Array, centroids: jax.Array, *,
                       block_n: int = 128, auto_tile: bool = False,
                       measure: Optional[str] = None, policy=None,
-                      options=None,
-                      interpret: Optional[bool] = None
-                      ) -> Tuple[jax.Array, jax.Array]:
+                      options=None) -> Tuple[jax.Array, jax.Array]:
     """One k-means update step as a single two-output megakernel:
     returns ``(sums, counts)`` with ``sums[k] = sum of points assigned
     to centroid k`` and ``counts[k]`` their number.  ``auto_tile=True``
@@ -95,6 +93,6 @@ def fused_kmeans_step(points: jax.Array, centroids: jax.Array, *,
             jax.ShapeDtypeStruct((k, 1), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((block_n,), jnp.int32)],
-        interpret=INTERPRET if interpret is None else interpret,
+        interpret=backend.interpret(),
     )(points, centroids)
     return sums, counts[:, 0]

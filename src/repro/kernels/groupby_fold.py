@@ -15,7 +15,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-INTERPRET = True
+from repro.core import backend
 
 
 def _auto_blocks(t: int, num_keys: int, ew: int,
@@ -43,8 +43,7 @@ def _gbf_kernel(k_ref, v_ref, o_ref, *, num_keys: int):
 def groupby_fold(keys: jax.Array, values: jax.Array, num_keys: int, *,
                  block_t: int = 256, auto_tile: bool = False,
                  measure: Optional[str] = None, policy=None,
-                 options=None,
-                 interpret: Optional[bool] = None) -> jax.Array:
+                 options=None) -> jax.Array:
     """out[k] = sum over i with keys[i]==k of values[i].
 
     keys: (T,) int32; values: (T,) or (T, E) -> out (num_keys, E).
@@ -69,6 +68,6 @@ def groupby_fold(keys: jax.Array, values: jax.Array, num_keys: int, *,
         ],
         out_specs=pl.BlockSpec((num_keys, ew), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((num_keys, ew), jnp.float32),
-        interpret=INTERPRET if interpret is None else interpret,
+        interpret=backend.interpret(),
     )(keys, values)
     return out[:, 0] if squeeze else out

@@ -21,7 +21,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-INTERPRET = True  # CPU container; flip on real TPU
+from repro.core import backend
 
 
 def _auto_blocks(m: int, n: int, k: int,
@@ -50,8 +50,8 @@ def matmul(x: jax.Array, y: jax.Array, *,
            block_m: int = 128, block_n: int = 128, block_k: int = 128,
            out_dtype: Optional[jnp.dtype] = None,
            auto_tile: bool = False,
-           measure: Optional[str] = None, policy=None, options=None,
-           interpret: Optional[bool] = None) -> jax.Array:
+           measure: Optional[str] = None, policy=None,
+           options=None) -> jax.Array:
     """``x @ y`` with explicit VMEM tiling. Shapes must divide blocks.
 
     ``auto_tile=True`` replaces the block arguments with the DSE-selected
@@ -87,5 +87,5 @@ def matmul(x: jax.Array, y: jax.Array, *,
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
-        interpret=INTERPRET if interpret is None else interpret,
+        interpret=backend.interpret(),
     )(x, y)

@@ -20,7 +20,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-INTERPRET = True
+from repro.core import backend
 
 
 def _auto_blocks(seq: int, n: int, dh: int,
@@ -70,8 +70,8 @@ def _ssd_kernel(a_ref, x_ref, dt_ref, b_ref, c_ref, y_ref, h_ref, *,
 
 def ssd_scan(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array,
              C: jax.Array, *, chunk: int = 128, auto_tile: bool = False,
-             measure: Optional[str] = None, policy=None, options=None,
-             interpret: Optional[bool] = None) -> jax.Array:
+             measure: Optional[str] = None, policy=None,
+             options=None) -> jax.Array:
     """See ref.ssd_scan for semantics.  seq must divide ``chunk``.
 
     ``auto_tile=True`` picks the chunk length by DSE on the sequence-fold
@@ -101,5 +101,5 @@ def ssd_scan(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array,
                                lambda b, hh, c: (b, c, hh, 0)),
         out_shape=jax.ShapeDtypeStruct((bsz, seq, h, dh), x.dtype),
         scratch_shapes=[pltpu.VMEM((n, dh), jnp.float32)],
-        interpret=INTERPRET if interpret is None else interpret,
+        interpret=backend.interpret(),
     )(A, x, dt, B, C)

@@ -1,4 +1,6 @@
 import os
+# a CPU-only tool: it never takes a chip, even on a machine with one
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (os.environ.get("_REPRO_EXTRA_XLA_FLAGS", "")
                            + " --xla_force_host_platform_device_count=512")
 
@@ -116,11 +118,8 @@ def _scan_group(cfg: ModelConfig) -> int:
 
 
 def _cost_analysis_dict(ca) -> Dict[str, Any]:
-    """Normalize ``compiled.cost_analysis()`` across jax versions:
-    0.4.x returns a list with one dict per program, newer versions the
-    dict itself."""
-    if isinstance(ca, (list, tuple)):
-        return ca[0] if ca else {}
+    """``compiled.cost_analysis()`` as a dict ({} when the backend
+    reports none)."""
     return ca or {}
 
 

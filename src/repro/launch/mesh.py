@@ -11,15 +11,9 @@ from typing import Sequence
 
 import jax
 
-# jax >= 0.5 requires explicit axis types; older releases (the pinned
-# 0.4.x) have no ``jax.sharding.AxisType`` and reject the kwarg
-_AXIS_TYPE = getattr(jax.sharding, "AxisType", None)
-
-
 def _axis_type_kwargs(n_axes: int) -> dict:
-    if _AXIS_TYPE is None:
-        return {}
-    return {"axis_types": (_AXIS_TYPE.Auto,) * n_axes}
+    """Every mesh axis is an auto-sharded axis (jax asks explicitly)."""
+    return {"axis_types": (jax.sharding.AxisType.Auto,) * n_axes}
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:

@@ -22,8 +22,8 @@ pool (``models.paged``): requests are admitted into and evicted from a
 fixed set of decode slots every step, decode runs as one joint
 ``paged_decode_step`` (the fused ``decode_attention`` DAG), and the KV
 layout / page size come from the joint DSE plan.  The fused Pallas
-kernel is certified token-identical against the ``decode_step`` oracle
-before serving trusts it.
+kernel's logits are certified against the reference paged path on the
+first decode step; a failed certification stops the server.
 """
 from __future__ import annotations
 
@@ -36,7 +36,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import ARCHS, get_config
-from repro.core import telemetry
+from repro.core import backend, telemetry
 from repro.launch import steps as steps_mod
 from repro.models import model
 
@@ -186,60 +186,32 @@ def serve(arch: str, smoke: bool, batch: int, prompt_len: int,
     return out
 
 
-def _certify_paged_decode(cfg, params, *, layout: str, page_size: int,
-                          prompt_len: int = 5, gen: int = 4,
-                          seed: int = 0, policy=None
-                          ) -> Tuple[bool, str]:
-    """Certify the fused Pallas paged-decode kernel against the
-    ``model.decode_step`` oracle token-for-token: one short request is
-    decoded greedily through both paths (oracle dense cache sized to
-    the page-padded extent so the comparison is exact, not tolerance-
-    based).  Runs under the resilience policy's deadline/retry; any
-    expected failure or token mismatch returns ``(False, why)`` and
-    the caller falls back to the reference paged path."""
-    from repro.core import resilience
-    from repro.models import paged
+# Certification tolerance on logits, relative to the largest reference
+# logit.  Both paths read the same bf16 pool and compute attention in
+# f32 (HIGHEST precision on the MXU): the kernel's attention output is
+# within ~2e-6 of a float64 reference.  But each layer rounds that
+# output to bf16, and the logits themselves are bf16, whose one ulp
+# near the largest logit is 2**-8..2**-7 of it; a difference far below
+# bf16 precision still flips some roundings.  On a TPU v5e, granite-3-2b
+# with random weights differs by one such ulp (6.8e-3) at 1 layer and
+# by 1.7e-2 at its 40.  Kernel faults move the logits further: one
+# token off in the length by 8.4e-2, slots reading each other's pages
+# by 1.7.  4e-2 sits about a factor two from each side.
+CERTIFY_RTOL = 4e-2
 
-    def probe() -> Tuple[bool, str]:
-        ln = prompt_len
-        cmax = -(-(ln + gen) // page_size) * page_size
-        rng = np.random.RandomState(seed)
-        prompt = rng.randint(0, cfg.vocab, (1, ln))
-        oc = model.init_cache(cfg, 1, cmax)
-        pc = paged.PagedKVCache.init(cfg, 1, cmax, page_size=page_size,
-                                     layout=layout)
-        step_o = jax.jit(steps_mod.make_serve_step(cfg))
 
-        def pstep(params, cache, tok):
-            logits, cache = paged.paged_decode_step(
-                params, cfg, cache, tok, use_pallas=True)
-            logits = model.mask_vocab_pad(logits, cfg)
-            nxt = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
-            return nxt, cache
+class CertificationError(RuntimeError):
+    """The fused paged-decode kernel disagreed with the reference."""
 
-        step_p = jax.jit(pstep)
-        to = tp = None
-        for i in range(ln + gen - 1):
-            tok_o = (prompt[:, i:i + 1] if i < ln
-                     else np.asarray(to).reshape(1, 1))
-            tok_p = (prompt[:, i:i + 1] if i < ln
-                     else np.asarray(tp).reshape(1, 1))
-            to, oc = step_o(params, oc,
-                            jnp.asarray(tok_o, jnp.int32), jnp.int32(i))
-            tp, pc = step_p(params, pc, jnp.asarray(tok_p, jnp.int32))
-            if i >= ln - 1 and int(np.asarray(to)[0]) != \
-                    int(np.asarray(tp)[0]):
-                return False, (f"token mismatch at step {i - ln + 1}: "
-                               f"oracle {int(np.asarray(to)[0])} != "
-                               f"fused {int(np.asarray(tp)[0])}")
-        return True, f"token-identical over {gen} decode steps"
 
-    key = f"paged_decode/{layout}/p{page_size}"
-    try:
-        return resilience.call_guarded(probe, stage="certify", key=key,
-                                       policy=policy)
-    except resilience.CandidateFailure as exc:
-        return False, f"{exc.kind}: {exc.detail}"
+def _certify_logits(fused, ref) -> Tuple[bool, float]:
+    """``(ok, err)``: the largest logit difference relative to the
+    largest reference logit, checked against ``CERTIFY_RTOL``."""
+    fused = np.asarray(fused, np.float32)
+    ref = np.asarray(ref, np.float32)
+    err = float(np.max(np.abs(fused - ref))
+                / max(float(np.max(np.abs(ref))), 1e-30))
+    return bool(np.isfinite(err) and err <= CERTIFY_RTOL), err
 
 
 def serve_continuous(arch: str, smoke: bool, slots: int, gen: int,
@@ -259,15 +231,15 @@ def serve_continuous(arch: str, smoke: bool, slots: int, gen: int,
     allocated pages) and *evicts* finished ones (pages returned to the
     free list), then runs ONE joint ``paged_decode_step`` over all
     slots.  The KV layout and page size come from the joint DSE plan
-    (``ops.resolve_plan("paged_decode", ...)``) unless overridden; the
-    fused Pallas kernel is certified against the ``decode_step``
-    oracle first and serving falls back to the reference paged path on
-    any certification failure (recorded as a resilience event).
+    (``ops.resolve_plan("paged_decode", ...)``) unless overridden.
+    With ``certify``, the first decode step also runs through the
+    reference paged path and the fused kernel's logits must match it
+    within ``CERTIFY_RTOL``; otherwise ``CertificationError`` is raised
+    (there is no silent fallback to the reference path).
 
     Returns ``(tokens, stats)``: the (n_requests, gen) generated
     tokens in request order, and occupancy/latency/provenance stats.
     """
-    from repro.core import resilience
     from repro.core.options import Options
     from repro.kernels import ops
     from repro.models import paged
@@ -293,18 +265,6 @@ def serve_continuous(arch: str, smoke: bool, slots: int, gen: int,
     layout = layout or sel_layout
     page_size = int(page_size or sel_ps)
 
-    certified = None
-    if use_pallas and certify:
-        ok, why = _certify_paged_decode(cfg, params, layout=layout,
-                                        page_size=page_size)
-        certified = ok
-        if not ok:
-            resilience.record(
-                "certify", "numeric",
-                f"paged_decode/{layout}/p{page_size}",
-                "fallback-reference", why)
-            use_pallas = False
-
     npm = -(-max_ctx // page_size)
     cache = paged.PagedKVCache.init(cfg, slots, npm * page_size,
                                     page_size=page_size, layout=layout)
@@ -315,13 +275,18 @@ def serve_continuous(arch: str, smoke: bool, slots: int, gen: int,
     prefill_fn = jax.jit(steps_mod.make_cache_prefill_step(cfg),
                          donate_argnums=(1,))
 
-    def _step(params, cache, tok):
+    def _step(params, cache, tok, pallas):
         logits, cache = paged.paged_decode_step(params, cfg, cache, tok,
-                                                use_pallas=use_pallas)
-        logits = model.mask_vocab_pad(logits, cfg)
-        return jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32), cache
+                                                use_pallas=pallas)
+        last = logits[:, -1]
+        nxt = jnp.argmax(model.mask_vocab_pad(last, cfg), axis=-1)
+        return nxt.astype(jnp.int32), last[:, :cfg.vocab], cache
 
-    step_fn = jax.jit(_step, donate_argnums=(1,))
+    step_fn = jax.jit(lambda p, c, t: _step(p, c, t, use_pallas),
+                      donate_argnums=(1,))
+    certified, certify_err = None, None
+    if use_pallas and certify:
+        ref_fn = jax.jit(lambda p, c, t: _step(p, c, t, False))
 
     rng = np.random.RandomState(seed)
     prompt_pool = rng.randint(0, cfg.vocab, (n_req, max(lens)))
@@ -380,13 +345,26 @@ def serve_continuous(arch: str, smoke: bool, slots: int, gen: int,
             len(active), max_ctx, hkv, head_dim)
         paged_words += cfg.n_layers * cost_mod.paged_decode_traffic_words(
             live, page_size, hkv, head_dim)
+        tok = jnp.asarray(next_tok.reshape(slots, 1))
+        check = certified is None and use_pallas and certify
+        if check:   # the reference path reads the cache before the
+            # fused step donates it
+            with telemetry.span("serve.certify", layout=layout,
+                                page_size=page_size):
+                _, ref_logits, _ = ref_fn(params, cache, tok)
         t0 = time.time()
         with telemetry.span("serve.decode_step", step=steps,
                             active=len(active)):
-            nxt, cache = step_fn(params, cache,
-                                 jnp.asarray(next_tok.reshape(slots, 1)))
+            nxt, logits, cache = step_fn(params, cache, tok)
             nxt = np.asarray(nxt)
         dt = time.time() - t0
+        if check:
+            certified, certify_err = _certify_logits(logits, ref_logits)
+            if not certified:
+                raise CertificationError(
+                    f"paged_decode/{layout}/p{page_size}: fused logits "
+                    f"differ from the reference paged path by "
+                    f"{certify_err:.3e} of their scale (> {CERTIFY_RTOL})")
         decode_s += dt
         telemetry.observe("serve.decode_token_s",
                           dt / max(len(active), 1))
@@ -421,6 +399,7 @@ def serve_continuous(arch: str, smoke: bool, slots: int, gen: int,
         "layout": layout, "page_size": page_size, "block": int(blk),
         "depth": int(depth), "plan_sizes": dict(plan.sizes),
         "use_pallas": bool(use_pallas), "certified": certified,
+        "certify_err": certify_err,
         "slots": slots, "requests": n_req, "steps": steps,
         "occupancy": occupancy, "admitted": admitted,
         "evicted": evicted, "prefill_s": prefill_s,
@@ -471,6 +450,7 @@ def main():
                     help="use the reference paged attention instead of "
                          "the fused Pallas kernel (--continuous only)")
     args = ap.parse_args()
+    backend.enable_compile_cache()
     if args.continuous:
         toks, _ = serve_continuous(
             args.arch, args.smoke, args.batch, args.gen,

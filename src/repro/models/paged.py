@@ -13,10 +13,13 @@ predication).
 Two enumerable KV layouts (the DSE axis ``core.dse.
 select_paged_decode_blocks`` searches):
 
-  * ``split``  -- separate K and V pools, each ``(L, P, ps, Hkv, dh)``;
-  * ``fused``  -- one pool ``(L, P, ps, 2*Hkv, dh)`` with K and V
-    head-interleaved (K at even head index ``2h``, V at odd ``2h+1``),
+  * ``split``  -- separate K and V pools, each ``(L, P, ps, Hkv*dh)``;
+  * ``fused``  -- one pool ``(L, P, ps, 2*Hkv*dh)`` with K and V
+    head-interleaved (K at even head slot ``2h``, V at odd ``2h+1``),
     so a page streams both operands of one head in a single burst.
+
+A token is one lane-dense row of its heads, so a page is whole TPU
+tiles and the decode kernel reads and writes the pool in place.
 
 ``paged_decode_step`` mirrors ``model.decode_step`` structurally (same
 ``scan_layers`` over stacked params, same einsums and casts, only the
@@ -56,7 +59,7 @@ class PagedKVCache:
 
     def __init__(self, buffers: Tuple[jax.Array, ...],
                  page_table: jax.Array, seq_lens: jax.Array, *,
-                 layout: str, page_size: int):
+                 layout: str, page_size: int, head_dim: int):
         if layout not in LAYOUTS:
             raise ValueError(f"layout {layout!r}; one of {LAYOUTS}")
         self.buffers = tuple(buffers)
@@ -64,25 +67,27 @@ class PagedKVCache:
         self.seq_lens = seq_lens
         self.layout = layout
         self.page_size = page_size
+        self.head_dim = head_dim
 
     def tree_flatten(self):
         return ((self.buffers, self.page_table, self.seq_lens),
-                (self.layout, self.page_size))
+                (self.layout, self.page_size, self.head_dim))
 
     @classmethod
     def tree_unflatten(cls, aux, children):
         buffers, page_table, seq_lens = children
         return cls(buffers, page_table, seq_lens,
-                   layout=aux[0], page_size=aux[1])
+                   layout=aux[0], page_size=aux[1], head_dim=aux[2])
 
-    def replace(self, **kw) -> "PagedKVCache":
-        args = {"buffers": self.buffers, "page_table": self.page_table,
-                "seq_lens": self.seq_lens, "layout": self.layout,
-                "page_size": self.page_size}
-        args.update(kw)
-        return PagedKVCache(args["buffers"], args["page_table"],
-                            args["seq_lens"], layout=args["layout"],
-                            page_size=args["page_size"])
+    def replace(self, *, buffers=None, page_table=None,
+                seq_lens=None) -> "PagedKVCache":
+        """The same layout with some arrays swapped."""
+        return PagedKVCache(
+            self.buffers if buffers is None else buffers,
+            self.page_table if page_table is None else page_table,
+            self.seq_lens if seq_lens is None else seq_lens,
+            layout=self.layout, page_size=self.page_size,
+            head_dim=self.head_dim)
 
     # ------------------------------------------------------------ shapes
     @property
@@ -118,14 +123,14 @@ class PagedKVCache:
         npm = -(-max_len // page_size)
         pool = max(n_pages, 1 + batch * npm)   # + reserved page 0
         if layout == "fused":
-            buffers = (jnp.zeros((nl, pool, page_size, 2 * hkv, dh), dt),)
+            buffers = (jnp.zeros((nl, pool, page_size, 2 * hkv * dh), dt),)
         else:
-            buffers = (jnp.zeros((nl, pool, page_size, hkv, dh), dt),
-                       jnp.zeros((nl, pool, page_size, hkv, dh), dt))
+            buffers = (jnp.zeros((nl, pool, page_size, hkv * dh), dt),
+                       jnp.zeros((nl, pool, page_size, hkv * dh), dt))
         table = 1 + jnp.arange(batch * npm, dtype=jnp.int32
                                ).reshape(batch, npm)
         return cls(buffers, table, jnp.zeros((batch,), jnp.int32),
-                   layout=layout, page_size=page_size)
+                   layout=layout, page_size=page_size, head_dim=dh)
 
     # ------------------------------------------------- slot bookkeeping
     def assign_pages(self, slot: int, pages, length: int
@@ -153,12 +158,12 @@ class PagedKVCache:
             kv = jnp.stack([k, v], axis=2)          # (L, Hkv, 2, S, dh)
             kv = kv.reshape(nl, 2 * hkv, s, dh)     # head-interleaved
             kv = kv.transpose(0, 2, 1, 3)           # (L, S, 2Hkv, dh)
-            fl = _flat(self.buffers[0])
+            fl = _flat(self.buffers[0], dh)
             buffers[0] = fl.at[:, flat].set(kv.astype(fl.dtype)
                                             ).reshape(self.buffers[0].shape)
         else:
             for i, t in enumerate((k, v)):
-                fl = _flat(self.buffers[i])
+                fl = _flat(self.buffers[i], k.shape[3])
                 buffers[i] = fl.at[:, flat].set(
                     t.transpose(0, 2, 1, 3).astype(fl.dtype)
                 ).reshape(self.buffers[i].shape)
@@ -170,38 +175,39 @@ class PagedKVCache:
         mapped page holds and must be masked by the caller)."""
         pools = tuple(buf[li] for buf in self.buffers)
         return _gather_layer(pools, self.page_table, self.layout,
-                             self.page_size)
+                             self.page_size, self.head_dim)
 
 
-def _flat(buf: jax.Array) -> jax.Array:
-    """Pages flattened to one token axis: ``(..., P*ps, H, dh)``."""
-    *lead, p, ps, h, dh = buf.shape
-    return buf.reshape(*lead, p * ps, h, dh)
+def _flat(buf: jax.Array, head_dim: int) -> jax.Array:
+    """Pages flattened to one token axis of head rows:
+    ``(..., P*ps, H, dh)``."""
+    *lead, p, ps, width = buf.shape
+    return buf.reshape(*lead, p * ps, width // head_dim, head_dim)
 
 
 def _append_layer(pools, page_table, seq_lens, k, v, layout: str,
                   page_size: int) -> Tuple[jax.Array, ...]:
-    """One layer's pools (each ``(P, ps, H, dh)``) with the token K/V
+    """One layer's pools (each ``(P, ps, H*dh)``) with the token K/V
     (``(B, Hkv, dh)``) scattered at each request's ``seq_lens`` slot."""
     batch = page_table.shape[0]
     idx = page_table[jnp.arange(batch), seq_lens // page_size] \
         * page_size + seq_lens % page_size
+    b_, hkv, dh = k.shape
     if layout == "fused":
-        b_, hkv, dh = k.shape
         kv = jnp.stack([k, v], axis=2).reshape(b_, 2 * hkv, dh)
-        fl = _flat(pools[0])
+        fl = _flat(pools[0], dh)
         return (fl.at[idx].set(kv.astype(fl.dtype)
                                ).reshape(pools[0].shape),)
     out = []
     for pool, t in zip(pools, (k, v)):
-        fl = _flat(pool)
+        fl = _flat(pool, dh)
         out.append(fl.at[idx].set(t.astype(fl.dtype)
                                   ).reshape(pool.shape))
     return tuple(out)
 
 
-def _gather_layer(pools, page_table, layout: str, page_size: int
-                  ) -> Tuple[jax.Array, jax.Array]:
+def _gather_layer(pools, page_table, layout: str, page_size: int,
+                  head_dim: int) -> Tuple[jax.Array, jax.Array]:
     """Dense ``(B, Hkv, Cmax, dh)`` K/V views of one layer's pools."""
     npm = page_table.shape[1]
     cmax = npm * page_size
@@ -209,13 +215,13 @@ def _gather_layer(pools, page_table, layout: str, page_size: int
     gidx = page_table[:, pos // page_size] * page_size \
         + pos % page_size                                # (B, Cmax)
     if layout == "fused":
-        g = _flat(pools[0])[gidx]                        # (B, Cmax, 2H, dh)
+        g = _flat(pools[0], head_dim)[gidx]              # (B, Cmax, 2H, dh)
         b_, _, h2, dh = g.shape
         g = g.reshape(b_, cmax, h2 // 2, 2, dh)
         ck, cv = g[..., 0, :], g[..., 1, :]
     else:
-        ck = _flat(pools[0])[gidx]
-        cv = _flat(pools[1])[gidx]
+        ck = _flat(pools[0], head_dim)[gidx]
+        cv = _flat(pools[1], head_dim)[gidx]
     return (ck.transpose(0, 2, 1, 3), cv.transpose(0, 2, 1, 3))
 
 
@@ -253,17 +259,19 @@ def _paged_attn(p, x, cfg: ModelConfig, pools, page_table, seq_lens,
         new_pools = _append_layer(pools, page_table, seq_lens, k1, v1,
                                   layout, page_size)
         ck, cv = _gather_layer(new_pools, page_table, layout,
-                               page_size)                # (B,Hkv,Cmax,dh)
+                               page_size, dh)            # (B,Hkv,Cmax,dh)
+        hi = jax.lax.Precision.HIGHEST   # true f32, like the kernel
         scores = jnp.einsum("bskgh,bkch->bskgc",
                             qg.astype(jnp.float32),
-                            ck.astype(jnp.float32)) * dh ** -0.5
+                            ck.astype(jnp.float32),
+                            precision=hi) * dh ** -0.5
         slotpos = jnp.arange(ck.shape[2])
         valid = slotpos[None, :] <= seq_lens[:, None]    # (B, Cmax)
         scores = jnp.where(valid[:, None, None, None, :],
                            scores, -jnp.inf)
         probs = jax.nn.softmax(scores, axis=-1)
         out = jnp.einsum("bskgc,bkch->bskgh", probs,
-                         cv.astype(jnp.float32))
+                         cv.astype(jnp.float32), precision=hi)
     out = out.reshape(b, s, hq * dh).astype(x.dtype)
     return jnp.einsum("bsq,qd->bsd", out, p["wo"]), tuple(new_pools)
 

@@ -38,8 +38,7 @@ def test_plan_memory_charges_depth_times_scratch():
                 assert b.depth == ref.depth
     # one copy's worth of every rotating buffer: each +1 of depth
     # charges exactly this many extra bytes
-    per_copy = sum(b.words * np.dtype(b.dtype).itemsize
-                   for b in plans[2].buffers
+    per_copy = sum(b.bytes // b.depth for b in plans[2].buffers
                    if b.kind == "double_buffer")
     assert per_copy > 0
     for d in (3, 4):

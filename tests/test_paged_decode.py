@@ -4,10 +4,9 @@ The load-bearing claim: ``paged_decode_step`` -- page-scattered KV,
 per-request ragged lengths, both KV layouts, reference and fused
 Pallas paths -- is *token-identical* to the ``model.decode_step``
 oracle, across mixed lengths and page-boundary crossings.  Plus the
-regression tests for the three seam bugfixes this PR rode in on
-(mesh ``AxisType`` guard, ``resolve_plan`` unhashable-key memo,
-dry-run ``cost_analysis`` normalization) and the DSE provenance of
-the new joint layout x page_size x block axes.
+regression tests for the mesh axis types, the ``resolve_plan``
+unhashable-key memo and the dry-run ``cost_analysis`` normalization,
+and the DSE provenance of the joint layout x page_size x block axes.
 """
 import jax
 import jax.numpy as jnp
@@ -165,33 +164,24 @@ def test_resolve_plan_survives_unhashable_memo_key():
 
 
 def test_mesh_axis_type_guard():
-    """Regression (ISSUE 9 satellite): mesh construction works with
-    and without ``jax.sharding.AxisType`` (the jax-version seam that
-    broke the dry-run subprocess cell)."""
+    """Mesh construction names an axis type for every axis (the
+    installed jax asks for them explicitly)."""
     from repro.launch import mesh as mesh_mod
 
-    kw = mesh_mod._axis_type_kwargs(2)
-    if mesh_mod._AXIS_TYPE is None:
-        assert kw == {}
-    else:
-        assert len(kw["axis_types"]) == 2
-    old = mesh_mod._AXIS_TYPE
-    try:
-        mesh_mod._AXIS_TYPE = None
-        assert mesh_mod._axis_type_kwargs(3) == {}
-    finally:
-        mesh_mod._AXIS_TYPE = old
+    for n in (2, 3):
+        kw = mesh_mod._axis_type_kwargs(n)
+        assert kw["axis_types"] == (jax.sharding.AxisType.Auto,) * n
+    mesh = mesh_mod.make_elastic_mesh(jax.devices()[:1], model_parallel=1)
+    assert mesh.axis_names == ("data", "model")
 
 
 def test_dryrun_cost_analysis_normalization():
-    """Regression (ISSUE 9 satellite): ``cost_analysis()`` results are
-    normalized whether jax returns a per-program list (0.4.x) or the
-    dict itself (newer)."""
+    """``cost_analysis()`` comes back as the dict itself; a backend
+    that reports nothing normalizes to an empty dict."""
     from repro.launch.dryrun import _cost_analysis_dict
 
-    assert _cost_analysis_dict([{"flops": 1.0}]) == {"flops": 1.0}
-    assert _cost_analysis_dict([]) == {}
     assert _cost_analysis_dict({"flops": 2.0}) == {"flops": 2.0}
+    assert _cost_analysis_dict({}) == {}
     assert _cost_analysis_dict(None) == {}
 
 

@@ -228,16 +228,17 @@ def test_pipeline_key_is_topological():
 # ------------------------------------------------------- split fallback
 def test_split_fallback_when_vmem_tight():
     pipe, inputs, ref = _setup("gda")
-    # 80 KB: the fully fused kernel (~84 KB at the smallest candidate)
-    # busts VMEM but each stage alone fits -> cheapest-cut split
-    plan = dse.explore_pipeline(pipe, vmem_budget=80_000, cache=False)
+    # 270 KB: the fully fused kernel (274 KB of lane-padded VMEM at the
+    # smallest candidate) busts VMEM but each stage alone fits (262 KB,
+    # 143 KB) -> cheapest-cut split
+    plan = dse.explore_pipeline(pipe, vmem_budget=270_000, cache=False)
     assert not plan.fused
     assert plan.groups == ((0, 1), (1, 2))
     assert len(plan.group_blocks) == 2   # per-group block sizes
     # the split pays the intermediate round-trip the fused plan deletes
     full = dse.explore_pipeline(pipe, cache=False)
     assert plan.traffic_words > full.traffic_words
-    kern = lower_fused_pipeline(pipe, plan=plan, vmem_budget=80_000)
+    kern = lower_fused_pipeline(pipe, plan=plan, vmem_budget=270_000)
     _check(pipe, kern(**inputs), ref)
 
 
@@ -251,10 +252,10 @@ def test_group_lowerings_report_what_ran():
     pipe, _, _ = _setup("tpchq6")
     kern = lower_fused_pipeline(pipe, cache=False)
     assert kern.group_lowerings == (("q6_sum", "megakernel"),)
-    split = dse.explore_pipeline(_setup("gda")[0], vmem_budget=80_000,
+    split = dse.explore_pipeline(_setup("gda")[0], vmem_budget=270_000,
                                  cache=False)
     kern2 = lower_fused_pipeline(_setup("gda")[0], plan=split,
-                                 vmem_budget=80_000)
+                                 vmem_budget=270_000)
     assert len(kern2.group_lowerings) == 2
     # the bare-Map first group now lowers through the write-once
     # streaming template -- a megakernel, not a per-stage fallback

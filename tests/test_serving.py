@@ -155,3 +155,12 @@ def test_serve_continuous_matches_oracle_per_request():
             if i >= ln:
                 want.append(int(np.asarray(nxt)[0]))
         assert list(toks[r]) == want, f"request {r} diverged"
+
+
+def test_serve_continuous_raises_on_failed_certification(monkeypatch):
+    """A fused kernel that disagrees with the reference paged path
+    stops the server; it never falls back to the reference silently."""
+    monkeypatch.setattr(serve, "CERTIFY_RTOL", -1.0)
+    with pytest.raises(serve.CertificationError, match="reference"):
+        serve.serve_continuous("granite-3-2b", True, 2, 2,
+                               prompt_lens=(3, 5))

@@ -1,0 +1,107 @@
+"""Compile rehearsals for a TPU v5e, with no chip attached.
+
+Interpret mode never checks what Mosaic refuses on the chip: blocks
+and slices off the (sublane, 128-lane) tiling, gathers, and VMEM past
+the scoped limit.  These tests compile the main path's kernels at real
+widths for a described ``v5e:2x2`` topology (one of its chips), so that
+a kernel the chip would refuse fails here, at no chip time.  Interpret
+mode is switched off inside each test; the topology is described in a
+fixture and every test skips where it cannot be.
+"""
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from repro.core import backend, codegen_pallas
+from repro.core import pipeline as plmod
+from repro.core.dse import explore_pipeline
+from repro.patterns.analytics import PIPELINES
+
+# the chip_smoke.py extents: tpchq6 at TPC-H SF ~11, the others at 2**20
+SIZES = {"tpchq6": 2 ** 26, "gda": 2 ** 20, "kmeans": 2 ** 20,
+         "gda_moments": 2 ** 20, "normalize": 2 ** 20}
+# granite-3-2b decode: 4 requests, 8 KV heads of 64, 32 query heads
+GRANITE = dict(batch=4, kv_heads=8, group=4, head_dim=64, page_size=16,
+               n_pages_max=128)
+POOL_PAGES = 2048
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    # a compile for a described chip cannot be read back from the
+    # persistent cache without one: keep it out of the cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture
+def mosaic(monkeypatch):
+    """Kernels lower for Mosaic, as they do on a TPU backend."""
+    monkeypatch.setattr(backend, "interpret", lambda: False)
+
+
+def _spec(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(tuple(shape), jnp.dtype(dtype),
+                                sharding=sharding)
+
+
+@pytest.mark.parametrize("name", sorted(PIPELINES))
+def test_fused_dag_compiles_for_v5e(name, one_chip, mosaic):
+    """Every PIPELINE lowers as one Mosaic megakernel at the block the
+    DSE picks for it, within the scoped VMEM the DSE plans against."""
+    pipe, _, _ = PIPELINES[name](SIZES[name])
+    plan = explore_pipeline(pipe, cache=False)
+    assert plan.fused
+    (block,) = plan.group_blocks
+    fdag = plmod.fuse_dag(pipe, block)
+    call = codegen_pallas.lower_fused_dag(fdag.terminals, fdag.grid,
+                                          depth=plan.depths[0])
+    specs = {t.name: _spec(t.shape, t.dtype, one_chip)
+             for t in plmod.external_inputs(pipe)}
+    compiled = jax.jit(lambda **kw: call(**kw)).lower(**specs).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+
+
+@pytest.mark.parametrize("layout", ["split", "fused"])
+def test_paged_decode_compiles_for_v5e(layout, one_chip, mosaic):
+    """The paged-decode kernel at granite-3-2b widths over a 2048-page
+    pool: the pool stays in HBM and is updated in place (aliased, no
+    temporary copy of it)."""
+    g = GRANITE
+    kern = codegen_pallas.lower_paged_decode(layout=layout, **g)
+    heads = (2 if layout == "fused" else 1) * g["kv_heads"]
+    pool = _spec((POOL_PAGES, g["page_size"], heads * g["head_dim"]),
+                 jnp.bfloat16, one_chip)
+    pools = (pool,) if layout == "fused" else (pool, pool)
+    b, h, dh = g["batch"], g["kv_heads"], g["head_dim"]
+    step = jax.jit(lambda q, k, v, pools, pt, ln: kern(q, k, v, pools,
+                                                         pt, ln),
+                   donate_argnums=(3,))
+    compiled = step.lower(
+        _spec((b, h, g["group"], dh), jnp.bfloat16, one_chip),
+        _spec((b, h, dh), jnp.bfloat16, one_chip),
+        _spec((b, h, dh), jnp.bfloat16, one_chip), pools,
+        _spec((b, g["n_pages_max"]), jnp.int32, one_chip),
+        _spec((b,), jnp.int32, one_chip)).compile()
+    mem = compiled.memory_analysis()
+    pool_bytes = sum(int(np.prod(p.shape)) * 2 for p in pools)
+    assert mem.alias_size_in_bytes == pool_bytes
+    assert mem.temp_size_in_bytes < pool_bytes // 100
