@@ -28,6 +28,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
+import re
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
@@ -48,6 +49,13 @@ def _call_map(amap: "AffineMap", stack: Tuple) -> Tuple:
     if len(stack) < n:
         return amap(*((0,) * (n - len(stack)) + tuple(stack)))
     return amap(*stack[len(stack) - n:])
+
+
+def program_name(kind: str, name: str) -> str:
+    """A device program's name, ``<kind>_<name>`` in identifier
+    characters: the ``name=`` of its ``pallas_call`` and the function
+    name of its jitted wrapper, which a device trace shows."""
+    return re.sub(r"\W", "_", f"{kind}_{name}")
 
 
 def _block_index_map(copy_map: AffineMap, tile_shape: Tuple[int, ...],
@@ -257,7 +265,8 @@ def lower_tiled_map(p: ir.MultiFold) -> Callable:
             kernel, grid=grid, in_specs=in_specs, out_specs=out_spec,
             out_shape=jax.ShapeDtypeStruct(tuple(p.range_shape),
                                            jnp.dtype(p.dtype)),
-            interpret=backend.interpret())(*args)
+            interpret=backend.interpret(),
+            name=program_name("tiled_map", p.name))(*args)
 
     return call
 
@@ -317,7 +326,8 @@ def lower_tiled_gemm(p: ir.MultiFold) -> Callable:
             kernel, grid=grid, in_specs=in_specs, out_specs=out_spec,
             out_shape=jax.ShapeDtypeStruct(tuple(p.range_shape),
                                            jnp.dtype(p.dtype)),
-            interpret=backend.interpret())(x, y)
+            interpret=backend.interpret(),
+            name=program_name("tiled_gemm", p.name))(x, y)
 
     return call
 
@@ -366,7 +376,8 @@ def lower_tiled_groupby(p: ir.GroupByFold,
         return pl.pallas_call(
             kernel, grid=(g,), in_specs=in_specs, out_specs=out_spec,
             out_shape=jax.ShapeDtypeStruct(out_shape, jnp.dtype(p.dtype)),
-            interpret=backend.interpret())(*args)
+            interpret=backend.interpret(),
+            name=program_name("tiled_groupby", p.name))(*args)
 
     return call
 
@@ -433,7 +444,8 @@ def lower_tiled_flatmap(p: ir.FlatMap) -> Callable:
                 jax.ShapeDtypeStruct((cap,), jnp.dtype(p.dtype)),
                 jax.ShapeDtypeStruct((1,), jnp.int32),
             ],
-            interpret=backend.interpret())(*args)
+            interpret=backend.interpret(),
+            name=program_name("tiled_flatmap", p.name))(*args)
         return buf, cnt[0]
 
     return call
@@ -635,7 +647,8 @@ def _padded_out(range_shape: Tuple[int, ...]) -> Tuple[int, ...]:
     return (1, 1)
 
 
-def lower_fused_dag(terminals, grid_n: int, depth: int = 2) -> Callable:
+def lower_fused_dag(terminals, grid_n: int, depth: int = 2,
+                    name: str = "fused_dag") -> Callable:
     """ONE Pallas kernel for a fused pipeline DAG.
 
     ``terminals`` is a sequence of ``(output name, fused pattern)``
@@ -651,7 +664,10 @@ def lower_fused_dag(terminals, grid_n: int, depth: int = 2) -> Callable:
     then updates its own output block -- revisited accumulator / CAM
     blocks for folds, a streamed write-once block for Map terminals.
     HBM is touched solely at the pipeline edges (paper Fig. 6).
-    Returns ``call(**tensors) -> {name: array}``.
+    The kernel and its jitted wrapper are the device program ``name``.
+    Returns ``call(**tensors) -> {name: array}``; each call is a
+    ``pipeline.launch`` span (the kernel's dispatch) and a
+    ``pipeline.finish`` span (its outputs reshaped).
     """
     terminals = tuple(terminals)
     # the span times kernel *construction* (host side); the emitted
@@ -659,10 +675,11 @@ def lower_fused_dag(terminals, grid_n: int, depth: int = 2) -> Callable:
     with telemetry.span("codegen.lower_fused_dag",
                         terminals=len(terminals), grid=int(grid_n),
                         depth=int(depth)):
-        return _lower_fused_dag_body(terminals, grid_n, depth)
+        return _lower_fused_dag_body(terminals, grid_n, depth, name)
 
 
-def _lower_fused_dag_body(terminals, grid_n: int, depth: int) -> Callable:
+def _lower_fused_dag_body(terminals, grid_n: int, depth: int,
+                          name: str) -> Callable:
     from jax.experimental.pallas import tpu as pltpu
 
     if depth < 2:
@@ -746,20 +763,28 @@ def _lower_fused_dag_body(terminals, grid_n: int, depth: int) -> Callable:
 
             jax.lax.fori_loop(0, b // rows, body, 0)
 
-    run = jax.jit(pl.pallas_call(
+    kernel_call = pl.pallas_call(
         kernel, grid=(grid_n,), in_specs=in_specs,
         out_specs=[t.spec for t in emitters],
         out_shape=[jax.ShapeDtypeStruct(t.full, jnp.dtype(p.dtype))
                    for t, (_, p) in zip(emitters, terminals)],
-        scratch_shapes=scratch_shapes, interpret=backend.interpret()))
+        scratch_shapes=scratch_shapes, interpret=backend.interpret(),
+        name=name)
 
-    names = [name for name, _ in terminals]
+    def program(*args):
+        return kernel_call(*args)
+
+    program.__name__ = program.__qualname__ = name
+    run = jax.jit(program)
+    names = [out for out, _ in terminals]
 
     def call(**tensors):
         args = [jnp.asarray(tensors[tc.src.name]) for tc in reps]
-        outs = run(*args)
-        return {name: out.reshape(t.shape)
-                for name, t, out in zip(names, emitters, outs)}
+        with telemetry.span("pipeline.launch"):
+            outs = run(*args)
+        with telemetry.span("pipeline.finish"):
+            return {out_name: out.reshape(t.shape)
+                    for out_name, t, out in zip(names, emitters, outs)}
 
     return call
 
@@ -803,9 +828,13 @@ def lower_fused_pipeline(pipe, *, plan=None,
                               group_depths):
         sub = plmod.sub_pipeline(pipe, i0, i1)
         outs = plmod.output_names(sub)
+        # one group is the whole pipeline: its program takes its name
+        prog = program_name("fused_dag", pipe.name if len(plan.groups)
+                            == 1 else sub.name)
         try:
             fdag = plmod.fuse_dag(sub, b, vmem_budget_words=budget // 4)
-            runner = lower_fused_dag(fdag.terminals, fdag.grid, depth=d)
+            runner = lower_fused_dag(fdag.terminals, fdag.grid, depth=d,
+                                     name=prog)
             how = "megakernel"
         except NotImplementedError as e:
             runner = plmod.unfused_runner(sub)  # correctness first
@@ -827,12 +856,13 @@ def lower_fused_pipeline(pipe, *, plan=None,
     out_names = plmod.output_names(pipe)
 
     def call(**tensors):
-        env = {k: jnp.asarray(v) for k, v in tensors.items()}
-        for _, runner in runners:
-            env.update(runner(**env))
-        if len(out_names) == 1:
-            return env[out_names[0]]
-        return {n: env[n] for n in out_names}
+        with telemetry.span("pipeline.call"):
+            env = {k: jnp.asarray(v) for k, v in tensors.items()}
+            for _, runner in runners:
+                env.update(runner(**env))
+            if len(out_names) == 1:
+                return env[out_names[0]]
+            return {n: env[n] for n in out_names}
 
     call.pipeline_plan = plan
     call.group_lowerings = tuple(lowerings)
@@ -914,7 +944,10 @@ def lower_for_timing(p: ir.Pattern, sizes: Dict[str, Tuple[int, ...]], *,
                 f"{type(p).__name__}:{p.name}", "fallback",
                 f"pallas template unusable ({e}); codegen_jax oracle of "
                 "the tiled IR times instead")
-            run = jax.jit(lambda **kw: execute(t, kw))
+            def oracle(**kw):
+                return execute(t, kw)
+
+            run = jax.jit(oracle)
             sp.set(how="oracle")
             return (lambda: run(**inputs)), "oracle"
 
@@ -1150,7 +1183,7 @@ def _lower_paged_decode_body(*, batch: int, kv_heads: int, group: int,
             + [jax.ShapeDtypeStruct(p.shape, p.dtype) for p in pools],
             input_output_aliases={first_pool + j: 1 + j
                                   for j in range(n_pools)},
-            interpret=backend.interpret())(
+            interpret=backend.interpret(), name="paged_decode")(
                 jnp.asarray(page_table, jnp.int32),
                 jnp.asarray(seq_lens, jnp.int32), q, *new, *pools)
         return outs[0], tuple(outs[1:])

@@ -698,7 +698,10 @@ def certify_tile_plan(p, sizes: Dict[str, Tuple[int, ...]], *,
             sp.set(ok=True, how="oracle")
             return True, "oracle lowering is the reference"
         inputs = synth_inputs(ir.inputs_of(p), seed=seed)
-        want = jax.jit(lambda **kw: execute(p, kw))(**inputs)
+        def certify_oracle(**kw):
+            return execute(p, kw)
+
+        want = jax.jit(certify_oracle)(**inputs)
         got = fn()
         if isinstance(want, tuple):
             want = want[0]

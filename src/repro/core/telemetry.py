@@ -9,9 +9,15 @@ One subsystem, four faces:
   enabled (``REPRO_TRACE=1`` / ``Options(trace=True)``, resolved
   through ``Options.from_env`` like every other tuning option).  When
   disabled, ``span()`` returns a shared no-op singleton -- one global
-  check, no allocation, no string formatting -- so instrumentation
-  sites cost nothing in production.  Spans wrap host-side
-  orchestration only; nothing here may run inside jitted/pallas code.
+  check and one profiler check, no allocation, no string formatting --
+  so instrumentation sites cost nothing in production.  Every span
+  also opens a profiler annotation (``jax.profiler.TraceAnnotation``)
+  under its name while a profiler session is active, with or without
+  tracing: a ``jax.profiler`` trace then holds the program's spans on
+  its host plane, on the same clock as the runtime's program launches.
+  Span timestamps are microseconds after ``clock_origin()``, a
+  ``time.perf_counter`` reading.  Spans wrap host-side orchestration
+  only; nothing here may run inside jitted/pallas code.
 * **Metrics** -- ``count`` / ``gauge`` (always-on: they replace the
   ad-hoc stat dicts that used to live in ``buckets``/``serve``) and
   ``observe`` (latency histograms with fixed log-spaced bounds,
@@ -41,11 +47,13 @@ import threading
 import time
 from typing import Any, Dict, List, Optional, Tuple
 
+from jaxlib._profiler import TraceMe as _TraceMe
+
 __all__ = [
     "enabled", "enable", "disable", "reset", "span", "count", "gauge",
     "observe", "emit", "events", "clear_events", "put_record",
     "get_record", "log_bounds", "LATENCY_BOUNDS_S", "export_trace",
-    "metrics_snapshot", "span_log",
+    "metrics_snapshot", "span_log", "clock_origin",
 ]
 
 _LOCK = threading.RLock()
@@ -149,13 +157,22 @@ def _stack() -> list:
     return s
 
 
+class _Annotation(_TraceMe):
+    """Tracing off, a profiler session on: the span goes to the
+    profiler's trace alone and nothing is recorded here."""
+
+    def set(self, **kv):
+        return self
+
+
 class Span:
-    __slots__ = ("name", "args", "_ts")
+    __slots__ = ("name", "args", "_ts", "_note")
 
     def __init__(self, name: str, args: Dict[str, Any]):
         self.name = name
         self.args = args
         self._ts = 0.0
+        self._note = None
 
     def set(self, **kv):
         """Attach attributes discovered mid-span (e.g. the winner)."""
@@ -163,6 +180,9 @@ class Span:
         return self
 
     def __enter__(self):
+        if _TraceMe.is_enabled():
+            self._note = _TraceMe(self.name)
+            self._note.__enter__()
         self._ts = (time.perf_counter() - _T0) * 1e6
         _stack().append(self)
         return self
@@ -170,6 +190,8 @@ class Span:
     def __exit__(self, exc_type, exc, tb):
         global _dropped_spans
         dur = (time.perf_counter() - _T0) * 1e6 - self._ts
+        if self._note is not None:
+            self._note.__exit__(None, None, None)
         st = _stack()
         if st and st[-1] is self:
             st.pop()
@@ -194,10 +216,19 @@ class Span:
 
 
 def span(name: str, **attrs):
-    """A tracing span context manager.  Disabled -> shared no-op."""
+    """A tracing span context manager.  Disabled -> a profiler
+    annotation while a profiler session is active, else the shared
+    no-op."""
     if not (_enabled if _enabled is not None else _resolve_enabled()):
-        return NULL_SPAN
+        return _Annotation(name) if _TraceMe.is_enabled() else NULL_SPAN
     return Span(name, attrs)
+
+
+def clock_origin() -> float:
+    """The ``time.perf_counter()`` reading at which span timestamps are
+    0: a span of ``span_log()`` ran from ``clock_origin() + ts * 1e-6``
+    for ``dur * 1e-6`` seconds."""
+    return _T0
 
 
 def span_log() -> List[Dict[str, Any]]:

@@ -65,6 +65,6 @@ def filter_reduce(x: jax.Array, weight: jax.Array, lo, hi, *,
         ],
         out_specs=pl.BlockSpec((1, 1), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((1, 1), jnp.float32),
-        interpret=backend.interpret(),
+        interpret=backend.interpret(), name="filter_reduce",
     )(x, weight, lo, hi)
     return out[0, 0]

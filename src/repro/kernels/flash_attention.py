@@ -130,6 +130,6 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((block_q,), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
-        interpret=backend.interpret(),
+        interpret=backend.interpret(), name="flash_attention",
     )(qg, kg, vg)
     return out.reshape(b, hq, sq, d)

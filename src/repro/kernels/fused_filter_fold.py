@@ -76,6 +76,6 @@ def fused_filter_fold(x: jax.Array, weight: jax.Array, lo, hi, *,
         out_specs=pl.BlockSpec((1, 1), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((1, 1), jnp.float32),
         scratch_shapes=[pltpu.VMEM((block_t,), jnp.float32)],
-        interpret=backend.interpret(),
+        interpret=backend.interpret(), name="fused_filter_fold",
     )(x, weight, lo, hi)
     return out[0, 0]

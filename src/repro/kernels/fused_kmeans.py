@@ -93,6 +93,6 @@ def fused_kmeans_step(points: jax.Array, centroids: jax.Array, *,
             jax.ShapeDtypeStruct((k, 1), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((block_n,), jnp.int32)],
-        interpret=backend.interpret(),
+        interpret=backend.interpret(), name="fused_kmeans",
     )(points, centroids)
     return sums, counts[:, 0]

@@ -68,6 +68,6 @@ def groupby_fold(keys: jax.Array, values: jax.Array, num_keys: int, *,
         ],
         out_specs=pl.BlockSpec((num_keys, ew), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((num_keys, ew), jnp.float32),
-        interpret=backend.interpret(),
+        interpret=backend.interpret(), name="groupby_fold",
     )(keys, values)
     return out[:, 0] if squeeze else out

@@ -87,5 +87,5 @@ def matmul(x: jax.Array, y: jax.Array, *,
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
-        interpret=backend.interpret(),
+        interpret=backend.interpret(), name="matmul",
     )(x, y)

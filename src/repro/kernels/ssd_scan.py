@@ -101,5 +101,5 @@ def ssd_scan(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array,
                                lambda b, hh, c: (b, c, hh, 0)),
         out_shape=jax.ShapeDtypeStruct((bsz, seq, h, dh), x.dtype),
         scratch_shapes=[pltpu.VMEM((n, dh), jnp.float32)],
-        interpret=backend.interpret(),
+        interpret=backend.interpret(), name="ssd_scan",
     )(A, x, dt, B, C)

@@ -162,11 +162,9 @@ def serve(arch: str, smoke: bool, batch: int, prompt_len: int,
                 tok = nxt.reshape(gb, 1, cfg.n_codebooks)
             else:
                 tok = nxt.reshape(gb, 1)
-            ts = time.time()
             with telemetry.span("serve.decode_step", index=i, batch=gb):
                 nxt, cache = step_fn(params, cache, tok, jnp.int32(i))
                 group_out.append(np.asarray(nxt))
-            telemetry.observe("serve.decode_token_s", time.time() - ts)
         decode_s += time.time() - t0
 
         toks = np.stack(group_out, axis=1)        # (gb, gen[, ncb])
@@ -198,6 +196,14 @@ def serve(arch: str, smoke: bool, batch: int, prompt_len: int,
 # token off in the length by 8.4e-2, slots reading each other's pages
 # by 1.7.  4e-2 sits about a factor two from each side.
 CERTIFY_RTOL = 4e-2
+
+
+def _memory_in_use(array) -> Dict[str, int]:
+    """Bytes in use on ``array``'s device now and at its peak, where
+    the backend reports them."""
+    stats = next(iter(array.devices())).memory_stats() or {}
+    return {k: int(stats[k]) for k in ("bytes_in_use", "peak_bytes_in_use")
+            if k in stats}
 
 
 class CertificationError(RuntimeError):
@@ -239,6 +245,18 @@ def serve_continuous(arch: str, smoke: bool, slots: int, gen: int,
 
     Returns ``(tokens, stats)``: the (n_requests, gen) generated
     tokens in request order, and occupancy/latency/provenance stats.
+
+    Spans (``core.telemetry``): each admission is a ``serve.admit``
+    whose children are ``serve.admit.prefill`` (until its first token
+    is on the host) and ``serve.admit.scatter`` (page assignment and
+    the K/V scatter into the pool, until the pool is written; it
+    carries the device's ``bytes_in_use`` and ``peak_bytes_in_use``).
+    Each step is a ``serve.decode_step`` whose children are
+    ``serve.step.launch`` (the step program dispatched) and
+    ``serve.step.wait`` (its tokens on the host).  The bookkeeping
+    before and after a step is a ``serve.step.host`` each, and the
+    first step's reference run ``serve.certify``.  Every moment of
+    the loop lies in one of these leaves.
     """
     from repro.core.options import Options
     from repro.kernels import ops
@@ -282,11 +300,16 @@ def serve_continuous(arch: str, smoke: bool, slots: int, gen: int,
         nxt = jnp.argmax(model.mask_vocab_pad(last, cfg), axis=-1)
         return nxt.astype(jnp.int32), last[:, :cfg.vocab], cache
 
-    step_fn = jax.jit(lambda p, c, t: _step(p, c, t, use_pallas),
-                      donate_argnums=(1,))
+    def serve_step(p, c, t):
+        return _step(p, c, t, use_pallas)
+
+    def certify_reference(p, c, t):
+        return _step(p, c, t, False)
+
+    step_fn = jax.jit(serve_step, donate_argnums=(1,))
     certified, certify_err = None, None
     if use_pallas and certify:
-        ref_fn = jax.jit(lambda p, c, t: _step(p, c, t, False))
+        ref_fn = jax.jit(certify_reference)
 
     rng = np.random.RandomState(seed)
     prompt_pool = rng.randint(0, cfg.vocab, (n_req, max(lens)))
@@ -319,34 +342,39 @@ def serve_continuous(arch: str, smoke: bool, slots: int, gen: int,
             t0 = time.time()
             with telemetry.span("serve.admit", request=r, slot=s,
                                 prompt_len=ln, pages=need):
-                dcache = model.init_cache(cfg, 1, ln)
-                prompt = jnp.asarray(prompt_pool[r:r + 1, :ln],
-                                     jnp.int32)
-                first, dcache = _prefill(prefill_fn, params, dcache,
-                                         prompt, _ring_len(cfg, ln))
-                cache = cache.assign_pages(s, pages, ln)
-                cache = cache.write_tokens(s, dcache["k"][:, 0, :, :ln],
-                                           dcache["v"][:, 0, :, :ln], 0)
-                jax.block_until_ready(cache.buffers)
+                with telemetry.span("serve.admit.prefill"):
+                    dcache = model.init_cache(cfg, 1, ln)
+                    prompt = jnp.asarray(prompt_pool[r:r + 1, :ln],
+                                         jnp.int32)
+                    first, dcache = _prefill(prefill_fn, params, dcache,
+                                             prompt, _ring_len(cfg, ln))
+                    next_tok[s] = int(np.asarray(first)[0])
+                with telemetry.span("serve.admit.scatter") as sp:
+                    cache = cache.assign_pages(s, pages, ln)
+                    cache = cache.write_tokens(
+                        s, dcache["k"][:, 0, :, :ln],
+                        dcache["v"][:, 0, :, :ln], 0)
+                    jax.block_until_ready(cache.buffers)
+                    if telemetry.enabled():
+                        sp.set(**_memory_in_use(cache.buffers[0]))
             dt = time.time() - t0
             prefill_s += dt
             telemetry.observe("serve.admit_s", dt)
-            telemetry.observe("serve.prefill_s", dt)
             slot_req[s], slot_pages[s], slot_done[s] = r, pages, 0
-            next_tok[s] = int(np.asarray(first)[0])
             admitted += 1
 
-        active = [s for s in range(slots) if slot_req[s] is not None]
-        # modeled decode traffic for THIS step: a dense continuous
-        # server sizes every lane's cache to the longest possible
-        # context, the paged pool streams only live pages
-        live = [lens[slot_req[s]] + slot_done[s] for s in active]
-        dense_words += cfg.n_layers * cost_mod.dense_decode_traffic_words(
-            len(active), max_ctx, hkv, head_dim)
-        paged_words += cfg.n_layers * cost_mod.paged_decode_traffic_words(
-            live, page_size, hkv, head_dim)
-        tok = jnp.asarray(next_tok.reshape(slots, 1))
-        check = certified is None and use_pallas and certify
+        with telemetry.span("serve.step.host"):
+            active = [s for s in range(slots) if slot_req[s] is not None]
+            # modeled decode traffic for THIS step: a dense continuous
+            # server sizes every lane's cache to the longest possible
+            # context, the paged pool streams only live pages
+            live = [lens[slot_req[s]] + slot_done[s] for s in active]
+            dense_words += cfg.n_layers * cost_mod.dense_decode_traffic_words(
+                len(active), max_ctx, hkv, head_dim)
+            paged_words += cfg.n_layers * cost_mod.paged_decode_traffic_words(
+                live, page_size, hkv, head_dim)
+            tok = jnp.asarray(next_tok.reshape(slots, 1))
+            check = certified is None and use_pallas and certify
         if check:   # the reference path reads the cache before the
             # fused step donates it
             with telemetry.span("serve.certify", layout=layout,
@@ -355,43 +383,43 @@ def serve_continuous(arch: str, smoke: bool, slots: int, gen: int,
         t0 = time.time()
         with telemetry.span("serve.decode_step", step=steps,
                             active=len(active)):
-            nxt, logits, cache = step_fn(params, cache, tok)
-            nxt = np.asarray(nxt)
+            with telemetry.span("serve.step.launch"):
+                nxt, logits, cache = step_fn(params, cache, tok)
+            with telemetry.span("serve.step.wait"):
+                nxt = np.asarray(nxt)
         dt = time.time() - t0
-        if check:
-            certified, certify_err = _certify_logits(logits, ref_logits)
-            if not certified:
-                raise CertificationError(
-                    f"paged_decode/{layout}/p{page_size}: fused logits "
-                    f"differ from the reference paged path by "
-                    f"{certify_err:.3e} of their scale (> {CERTIFY_RTOL})")
-        decode_s += dt
-        telemetry.observe("serve.decode_token_s",
-                          dt / max(len(active), 1))
-        steps += 1
-        active_steps += len(active)
+        with telemetry.span("serve.step.host"):
+            if check:
+                certified, certify_err = _certify_logits(logits,
+                                                         ref_logits)
+                if not certified:
+                    raise CertificationError(
+                        f"paged_decode/{layout}/p{page_size}: fused "
+                        f"logits differ from the reference paged path "
+                        f"by {certify_err:.3e} of their scale "
+                        f"(> {CERTIFY_RTOL})")
+            decode_s += dt
+            steps += 1
+            active_steps += len(active)
 
-        # parked slots wrote their garbage token to reserved page 0;
-        # pin their lengths back to zero so they never walk off the
-        # page table
-        mask = np.zeros(slots, np.int32)
-        mask[active] = 1
-        cache = cache.replace(seq_lens=cache.seq_lens
-                              * jnp.asarray(mask))
+            # parked slots wrote their garbage token to reserved page
+            # 0; pin their lengths back to zero so they never walk off
+            # the page table
+            mask = np.zeros(slots, np.int32)
+            mask[active] = 1
+            cache = cache.replace(seq_lens=cache.seq_lens
+                                  * jnp.asarray(mask))
 
-        for s in active:
-            r = slot_req[s]
-            out[r, slot_done[s]] = int(nxt[s])
-            next_tok[s] = nxt[s]
-            slot_done[s] += 1
-            if slot_done[s] == gen:                          # evict
-                te = time.time()
-                with telemetry.span("serve.evict", request=r, slot=s):
+            for s in active:
+                r = slot_req[s]
+                out[r, slot_done[s]] = int(nxt[s])
+                next_tok[s] = nxt[s]
+                slot_done[s] += 1
+                if slot_done[s] == gen:                      # evict
                     free_pages.extend(slot_pages[s])
                     cache = cache.assign_pages(s, [0] * npm, 0)
                     slot_req[s], slot_pages[s] = None, []
-                telemetry.observe("serve.evict_s", time.time() - te)
-                evicted += 1
+                    evicted += 1
 
     occupancy = active_steps / max(steps * slots, 1)
     tokens_out = n_req * gen

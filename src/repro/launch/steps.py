@@ -171,7 +171,7 @@ def _make_cache_prefill_body(cfg: ModelConfig):
         logits = model.mask_vocab_pad(logits, cfg)
         return jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
 
-    def prefill_cache_step(params, cache, tokens, index):
+    def serve_prefill(params, cache, tokens, index):
         if block:
             logits, cache2 = model.decode_step(params, cfg, cache,
                                                tokens, index)
@@ -189,4 +189,4 @@ def _make_cache_prefill_body(cfg: ModelConfig):
             body, (cache, jnp.asarray(index, jnp.int32)), xs)
         return nxts[-1], cache2
 
-    return prefill_cache_step
+    return serve_prefill

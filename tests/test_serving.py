@@ -164,3 +164,55 @@ def test_serve_continuous_raises_on_failed_certification(monkeypatch):
     with pytest.raises(serve.CertificationError, match="reference"):
         serve.serve_continuous("granite-3-2b", True, 2, 2,
                                prompt_lens=(3, 5))
+
+
+SERVE_LEAVES = ("serve.admit.prefill", "serve.admit.scatter",
+                "serve.certify", "serve.step.launch", "serve.step.wait",
+                "serve.step.host")
+
+
+def _within(outer, inner):
+    return (outer["ts"] <= inner["ts"]
+            and inner["ts"] + inner["dur"] <= outer["ts"] + outer["dur"])
+
+
+def test_serve_continuous_leaf_spans_cover_the_loop():
+    """From the first admission to the last step, the loop's time lies
+    in its leaf spans, which do not overlap; every admission holds one
+    prefill and one scatter, every step one launch and one wait."""
+    from repro.core import telemetry
+
+    telemetry.enable()
+    lens, gen, slots = (3, 5, 9, 4), 3, 2
+    serve.serve_continuous("granite-3-2b", True, slots, gen,
+                           prompt_lens=lens)
+    spans = telemetry.span_log()
+    by = {}
+    for s in spans:
+        by.setdefault(s["name"], []).append(s)
+    admits, steps_ = by["serve.admit"], by["serve.decode_step"]
+    assert len(admits) == len(lens)
+    assert len(steps_) == len(by["serve.step.launch"]) \
+        == len(by["serve.step.wait"])
+    for a in admits:
+        for child in ("serve.admit.prefill", "serve.admit.scatter"):
+            assert sum(_within(a, c) for c in by[child]) == 1
+    for st in steps_:
+        for child in ("serve.step.launch", "serve.step.wait"):
+            assert sum(_within(st, c) for c in by[child]) == 1
+    assert len(by["serve.step.host"]) == 2 * len(steps_)
+    assert "serve.evict" not in by
+
+    lo = min(a["ts"] for a in admits)
+    hi = max(s["ts"] + s["dur"] for s in steps_)
+    leaves = sorted((s["ts"], s["ts"] + s["dur"]) for s in spans
+                    if s["name"] in SERVE_LEAVES)
+    for (_, end), (start, _) in zip(leaves, leaves[1:]):
+        assert start >= end - 1e-3          # microseconds: no overlap
+    covered = sum(min(b, hi) - max(a, lo) for a, b in leaves
+                  if b > lo and a < hi)
+    assert covered >= 0.95 * (hi - lo)
+    hists = telemetry.metrics_snapshot()["histograms"]
+    assert "serve.admit_s" in hists
+    assert not {"serve.decode_token_s", "serve.evict_s",
+                "serve.prefill_s"} & set(hists)

@@ -80,6 +80,78 @@ def test_env_enablement_via_options(monkeypatch):
     assert Options(trace=True).resolved().trace is True
 
 
+def _profiled_events(log_dir, run):
+    """``(name, duration_ns)`` of every host event of a CPU profiler
+    trace taken around ``run()``."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    with jax.profiler.trace(str(log_dir)):
+        run()
+    [path] = glob.glob(f"{log_dir}/**/*.xplane.pb", recursive=True)
+    pd = ProfileData.from_file(path)
+    return [(ev.name, ev.duration_ns) for plane in pd.planes
+            if not plane.name.startswith("/device:")
+            for line in plane.lines for ev in line.events]
+
+
+def test_span_lands_in_profiler_trace_with_telemetry_off(tmp_path):
+    """A profiler session sees the program's spans though telemetry is
+    off, and the registry records none of them."""
+    import time
+
+    telemetry.disable()
+
+    def run():
+        with telemetry.span("probe.outer", x=1) as sp:
+            sp.set(y=2)
+            with telemetry.span("probe.inner"):
+                time.sleep(0.002)
+
+    events = dict(_profiled_events(tmp_path, run))
+    assert events["probe.inner"] >= 2e6
+    assert events["probe.outer"] >= events["probe.inner"]
+    assert telemetry.span_log() == []
+
+
+def test_span_lands_in_profiler_trace_and_registry_with_telemetry_on(
+        tmp_path):
+    telemetry.enable()
+
+    def run():
+        with telemetry.span("probe.both"):
+            pass
+
+    assert "probe.both" in dict(_profiled_events(tmp_path, run))
+    assert [e["name"] for e in telemetry.span_log()] == ["probe.both"]
+
+
+def test_no_telemetry_and_no_profiler_is_the_shared_noop():
+    from jaxlib._profiler import TraceMe
+
+    telemetry.disable()
+    assert not TraceMe.is_enabled()
+    assert telemetry.span("a", x=1) is telemetry.NULL_SPAN
+    telemetry.enable()
+    assert telemetry.span("a") is not telemetry.NULL_SPAN
+
+
+def test_clock_origin_maps_span_log_onto_perf_counter():
+    import time
+
+    telemetry.enable()
+    before = time.perf_counter()
+    with telemetry.span("timed"):
+        inside = time.perf_counter()
+    after = time.perf_counter()
+    [e] = telemetry.span_log()
+    start = telemetry.clock_origin() + e["ts"] * 1e-6
+    end = start + e["dur"] * 1e-6
+    assert before <= start <= inside <= end <= after
+
+
 # ---------------------------------------------------------------- metrics
 
 

@@ -78,6 +78,8 @@ def test_fused_dag_compiles_for_v5e(name, one_chip, mosaic):
              for t in plmod.external_inputs(pipe)}
     compiled = jax.jit(lambda **kw: call(**kw)).lower(**specs).compile()
     assert compiled.as_text().count("tpu_custom_call") == 1
+    # the kernel is the named program, not an anonymous wrapper
+    assert "jit(fused_dag)/fused_dag/pallas_call" in compiled.as_text()
 
 
 @pytest.mark.parametrize("layout", ["split", "fused"])
@@ -101,6 +103,7 @@ def test_paged_decode_compiles_for_v5e(layout, one_chip, mosaic):
         _spec((b, h, dh), jnp.bfloat16, one_chip), pools,
         _spec((b, g["n_pages_max"]), jnp.int32, one_chip),
         _spec((b,), jnp.int32, one_chip)).compile()
+    assert "/paged_decode/pallas_call" in compiled.as_text()
     mem = compiled.memory_analysis()
     pool_bytes = sum(int(np.prod(p.shape)) * 2 for p in pools)
     assert mem.alias_size_in_bytes == pool_bytes
