@@ -1014,70 +1014,152 @@ def lower_auto(p: ir.Pattern, *, plan=None, vmem_budget: Optional[int] = None,
 # --------------------------------------------------------------------
 
 
+def paged_decode_blocks(*, block: Optional[int], depth: int,
+                        page_size: int, n_pages_max: int, kv_heads: int,
+                        head_dim: int, layout: str, dtype
+                        ) -> Tuple[int, int]:
+    """The ``(block, depth)`` the paged-decode kernel streams with.
+
+    ``block`` (tokens, ``None`` for one page) is cut to whole pages, to
+    the context bound ``n_pages_max * page_size``, and until ``depth``
+    buffers of it for every pool take at most three quarters of the
+    scoped VMEM (the rest holds the block's scores and probabilities).
+    The DSE prices a per-head row; the kernel's token row is every
+    head's K (and V, for ``fused``), so the cut depends on the widths
+    the kernel sees.  Pages are whole (sublane, lane) tiles, so a
+    buffer takes its bytes unpadded."""
+    from .cost import VMEM_BYTES
+
+    budget = VMEM_BYTES * 3 // 4
+    n_pools = 1 if layout == "fused" else 2
+    row = ((2 if layout == "fused" else 1) * kv_heads * head_dim
+           * jnp.dtype(dtype).itemsize)
+    depth = max(int(depth), 1)
+    pages = min(max(int(block or page_size) // page_size, 1), n_pages_max)
+    pages = max(min(pages, budget // (depth * n_pools * page_size * row)),
+                1)
+    return pages * page_size, depth
+
+
 def lower_paged_decode(*, batch: int, kv_heads: int, group: int,
                        head_dim: int, page_size: int, n_pages_max: int,
-                       layout: str = "split") -> Callable:
+                       layout: str = "split", block: Optional[int] = None,
+                       depth: int = 2, dtype=jnp.bfloat16) -> Callable:
     """Emit the fused decode megakernel over a paged KV cache.
 
     The ``decode_attention`` DAG lowered as one kernel per layer: the
-    KV-append producer writes the step's token into its page slot, then
+    KV-append producer writes the step's token into its page slot, and
     the flash-attention fold streams the request's pages with online
     softmax.  The streaming domain is *ragged* (``ir.RaggedExtent``):
-    each request folds only its live pages, ``seq_len // page_size +
-    1`` of the static bound ``n_pages_max``, and masks the slots of the
-    last page past its length to ``-1e30`` before the running-max
+    each request folds only the blocks that hold its live tokens, and
+    masks the slots past its length to ``-1e30`` before the running-max
     update, so the result never depends on what unassigned pages hold.
+
+    The fold streams blocks of ``block`` tokens (whole pages; see
+    ``paged_decode_blocks`` for the cut) through a ``depth``-deep
+    rotating VMEM buffer: while block ``i`` computes, the page DMAs of
+    blocks ``i+1 .. i+depth-1`` are in flight.  The blocks of all
+    requests form one stream -- the grid runs in order and the fetch
+    cursor lives in SMEM scratch across grid steps -- so the next
+    request's first blocks load while this one's last block computes.
+    Each block is scored for all heads in one matmul (the query heads
+    laid block-diagonally over the token row), its probabilities
+    weight V in a second.  Both take bf16 operands with f32
+    accumulation: K and V are bf16, and an f32 operand is split into
+    the three bf16 terms that sum to it, which is exactly what
+    ``Precision.HIGHEST`` computes from bf16 K/V.
+
+    The step's token reaches the pool in place: its page is in the
+    block that holds the token, so the row is merged into that VMEM
+    block (which may have been fetched before the append) and the page
+    is written back from it while the block computes.
 
     Layouts: ``split`` takes/returns two pools ``(P, ps, Hkv*dh)``;
     ``fused`` one head-interleaved pool ``(P, ps, 2*Hkv*dh)`` (K of
-    head ``h`` at head slot ``2h``, V at ``2h+1``) whose page streams
-    both operands of a head in one burst.  A token is one lane-dense
-    row, so a page is whole (sublane, lane) tiles in HBM and VMEM alike
-    and Mosaic needs no relayout of the pool.  The grid is one step per
-    request.  The page
+    head ``h`` at head slot ``2h``, V at ``2h+1``).  A token is one
+    lane-dense row, so a page is whole (sublane, lane) tiles.  The page
     table and the lengths are scalar-prefetched into SMEM; the pools
-    stay in HBM, aliased input to output, so the append writes one
-    token row in place and each live page is DMA'd into a VMEM page
-    buffer -- no step ever copies a whole pool.
+    stay in HBM, aliased input to output, and no step copies a pool.
 
     Returns ``call(q, new_k, new_v, pools, page_table, seq_lens) ->
     (out, new_pools)`` with ``q`` ``(B, Hkv, group, dh)``, ``new_k`` /
     ``new_v`` ``(B, Hkv, dh)`` (already rotated), ``out`` the f32
-    ``(B, Hkv, group, dh)`` attention output.
+    ``(B, Hkv, group, dh)`` attention output.  The call carries the
+    streamed ``block`` and ``depth`` as attributes.
     """
     if layout not in ("split", "fused"):
         raise ValueError(f"layout {layout!r}")
+    block, depth = paged_decode_blocks(
+        block=block, depth=depth, page_size=page_size,
+        n_pages_max=n_pages_max, kv_heads=kv_heads, head_dim=head_dim,
+        layout=layout, dtype=dtype)
     # span times host-side kernel construction; nothing lands in the
     # traced/jitted kernel body
     with telemetry.span("codegen.lower_paged_decode", layout=layout,
                         batch=int(batch), page_size=int(page_size),
-                        n_pages_max=int(n_pages_max)):
-        return _lower_paged_decode_body(
+                        n_pages_max=int(n_pages_max), block=block,
+                        depth=depth, pages_per_block=block // page_size):
+        call = _lower_paged_decode_body(
             batch=batch, kv_heads=kv_heads, group=group,
             head_dim=head_dim, page_size=page_size,
-            n_pages_max=n_pages_max, layout=layout)
+            n_pages_max=n_pages_max, layout=layout, block=block,
+            depth=depth)
+    call.block, call.depth = block, depth
+    return call
+
+
+def _bf16_terms(x):
+    """bf16 values whose sum is ``x`` exactly: ``x`` itself if bf16,
+    else (f32) the leading, middle and trailing 8 bits of its
+    significand."""
+    if x.dtype == jnp.bfloat16:
+        return [x]
+    terms = []
+    for _ in range(3):
+        t = x.astype(jnp.bfloat16)
+        terms.append(t)
+        x = x - t.astype(jnp.float32)
+    return terms
+
+
+def _exact_dot(a, b, dims):
+    """``a @ b`` (``dot_general`` over ``dims``) in f32 with ``a``'s
+    f32 values kept whole, as ``Precision.HIGHEST`` keeps them: a bf16
+    ``b`` meets the bf16 terms of ``a`` in one matmul (the terms
+    stacked as rows, their products summed after), anything else takes
+    the f32 matmul at HIGHEST."""
+    if b.dtype != jnp.bfloat16:
+        return jax.lax.dot_general(
+            a.astype(jnp.float32), b.astype(jnp.float32), dims,
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32)
+    terms = _bf16_terms(a)
+    r = a.shape[0]
+    out = jax.lax.dot_general(jnp.concatenate(terms, axis=0), b, dims,
+                              preferred_element_type=jnp.float32)
+    acc = out[:r]
+    for i in range(1, len(terms)):
+        acc = acc + out[i * r:(i + 1) * r]
+    return acc
 
 
 def _lower_paged_decode_body(*, batch: int, kv_heads: int, group: int,
                              head_dim: int, page_size: int,
-                             n_pages_max: int, layout: str) -> Callable:
+                             n_pages_max: int, layout: str, block: int,
+                             depth: int) -> Callable:
     from jax.experimental.pallas import tpu as pltpu
 
     fused = layout == "fused"
     ps, dh = page_size, head_dim
+    ppb = block // ps                      # pages per block
     n_pools = 1 if fused else 2
     width = (2 if fused else 1) * kv_heads * dh   # lanes of one token row
+    rows = kv_heads * group                # query rows, head-major
+    ctx = n_pages_max * ps                 # context bound
     NEG = -1e30
     scale = head_dim ** -0.5
-    # f32 on the MXU: its default pass would round the probabilities to
-    # bf16, which the reference paged path does at other points
-    HIGHEST = jax.lax.Precision.HIGHEST
-
-    def kv_lanes(h):
-        """Lane offsets of head ``h``'s K and V in a token row: fused
-        rows interleave them per head (K at head 2h, V at 2h+1)."""
-        return (2 * h * dh, (2 * h + 1) * dh) if fused \
-            else (h * dh, h * dh)
+    QK = (((1,), (1,)), ((), ()))          # contract the token row
+    PV = (((1,), (0,)), ((), ()))          # contract the block's tokens
 
     def kernel(pt_ref, len_ref, q_ref, *refs):
         new_rows = refs[:n_pools]              # (1, 1, width) VMEM
@@ -1085,73 +1167,119 @@ def _lower_paged_decode_body(*, batch: int, kv_heads: int, group: int,
         # buffers as the aliased output pools used below
         out_ref = refs[2 * n_pools]
         pools = refs[2 * n_pools + 1:3 * n_pools + 1]
-        bufs = refs[3 * n_pools + 1:4 * n_pools + 1]    # VMEM page
-        sem = refs[-1]
+        bufs = refs[3 * n_pools + 1:4 * n_pools + 1]  # (depth, ppb, ps, w)
+        sem, wsem, cur = refs[4 * n_pools + 1:]
         b = pl.program_id(0)
-        ln = len_ref[b]
-
-        def copy(src, dst):
-            cps = [pltpu.make_async_copy(s_, d_, sem.at[j])
-                   for j, (s_, d_) in enumerate(zip(src, dst))]
-            for cp in cps:
-                cp.start()
-            for cp in cps:
-                cp.wait()
+        n_phys = pools[0].shape[0]
 
         # indices stay in bounds whatever the host wrote (a DMA off the
         # pool is a fault, not a garbage read); gathers clamp the same
         # way on the reference path
-        n_phys = pools[0].shape[0]
-
-        def page_of(p):
-            return jnp.clip(pt_ref[b, jnp.minimum(p, n_pages_max - 1)],
+        def page_of(r, p):
+            return jnp.clip(pt_ref[r, jnp.minimum(p, n_pages_max - 1)],
                             0, n_phys - 1)
 
-        # KV append in place: the token's page is read, its row at the
-        # token's slot replaced, and the page written back
-        page = page_of(ln // ps)
-        copy([pl_.at[page] for pl_ in pools], bufs)
+        def last(r):
+            """The last position request ``r`` folds."""
+            return jnp.minimum(len_ref[r], ctx - 1)
+
+        def page_copy(k, slot, i, page):
+            return pltpu.make_async_copy(pools[k].at[page],
+                                         bufs[k].at[slot, i],
+                                         sem.at[k, slot])
+
+        # the fetch cursor in SMEM: request, block, blocks started
+        def fetch_next():
+            r, j, started = cur[0], cur[1], cur[2]
+
+            @pl.when(r < batch)
+            def _():
+                slot = started % depth
+
+                def start(i, c):
+                    page = page_of(r, j * ppb + i)
+                    for k in range(n_pools):
+                        page_copy(k, slot, i, page).start()
+                    return c
+
+                jax.lax.fori_loop(0, ppb, start, 0)
+                done = j + 1 > last(r) // block
+                cur[0] = jnp.where(done, r + 1, r)
+                cur[1] = jnp.where(done, 0, j + 1)
+                cur[2] = started + 1
+
+        @pl.when(b == 0)
+        def _prime():
+            for i in range(4):
+                cur[i] = 0
+            for _ in range(depth - 1):
+                fetch_next()
+
+        ln = len_ref[b]
+        lim = last(b)
+        # where the step's token goes: its logical page (clamped to the
+        # table, as the reference clamps), that page's block and slot
+        tok_page = jnp.minimum(ln // ps, n_pages_max - 1)
+        tok_block, tok_in = tok_page // ppb, tok_page % ppb
         hit = jax.lax.broadcasted_iota(jnp.int32, (ps, 1), 0) == ln % ps
-        for new, buf in zip(new_rows, bufs):
-            buf[...] = jnp.where(hit, new[0].astype(jnp.float32),
-                                 buf[...].astype(jnp.float32)
-                                 ).astype(buf.dtype)
-        copy(bufs, [pl_.at[page] for pl_ in pools])
 
-        def fold_page(p, carry):
-            copy([pl_.at[page_of(p)] for pl_ in pools], bufs)
-            pages = [buf[...].astype(jnp.float32) for buf in bufs]
-            pos = p * ps + jax.lax.broadcasted_iota(jnp.int32, (1, ps), 1)
-            new = []
-            for h in range(kv_heads):
-                k0, v0 = kv_lanes(h)
-                kpg = pages[0][:, k0:k0 + dh]                 # (ps, dh)
-                vpg = pages[-1][:, v0:v0 + dh]
-                m, el, acc = carry[h]
-                s_ = jax.lax.dot_general(
-                    q_ref[0, h].astype(jnp.float32), kpg,
-                    (((1,), (1,)), ((), ())), precision=HIGHEST,
-                    preferred_element_type=jnp.float32) * scale
-                s_ = jnp.where(pos <= ln, s_, NEG)     # ragged predicate
-                m_new = jnp.maximum(m, s_.max(-1, keepdims=True))
-                pexp = jnp.exp(s_ - m_new)
-                alpha = jnp.exp(m - m_new)
-                el = el * alpha + pexp.sum(-1, keepdims=True)
-                acc = acc * alpha + jnp.dot(
-                    pexp, vpg, precision=HIGHEST,
-                    preferred_element_type=jnp.float32)
-                new.append((m_new, el, acc))
-            return tuple(new)
+        qbd = q_ref[0]                          # (rows, width)
 
-        init = tuple((jnp.full((group, 1), NEG, jnp.float32),
-                      jnp.zeros((group, 1), jnp.float32),
-                      jnp.zeros((group, dh), jnp.float32))
-                     for _ in range(kv_heads))
-        final = jax.lax.fori_loop(
-            0, jnp.minimum(ln // ps + 1, n_pages_max), fold_page, init)
+        def fold_block(j, carry):
+            m, el, acc = carry
+            fetch_next()                # the block depth - 1 ahead
+            slot = (cur[3] + j) % depth
+
+            def wait(i, c):
+                for k in range(n_pools):
+                    page_copy(k, slot, i, 0).wait()
+                return c
+
+            jax.lax.fori_loop(0, ppb, wait, 0)
+
+            @pl.when(j == tok_block)
+            def _append():
+                page = page_of(b, tok_page)
+                for k in range(n_pools):
+                    buf = bufs[k].at[slot, tok_in]
+                    buf[...] = jnp.where(
+                        hit, new_rows[k][0].astype(jnp.float32),
+                        buf[...].astype(jnp.float32)).astype(buf.dtype)
+                    pltpu.make_async_copy(buf, pools[k].at[page],
+                                          wsem.at[k]).start()
+
+            kv = [buf[slot].reshape(block, width) for buf in bufs]
+            s_ = _exact_dot(qbd, kv[0], QK) * scale      # (rows, block)
+            pos = j * block + jax.lax.broadcasted_iota(
+                jnp.int32, (1, block), 1)
+            s_ = jnp.where(pos <= lim, s_, NEG)          # ragged predicate
+            m_new = jnp.maximum(m, s_.max(-1, keepdims=True))
+            pexp = jnp.exp(s_ - m_new)
+            alpha = jnp.exp(m - m_new)
+            el = el * alpha + pexp.sum(-1, keepdims=True)
+            acc = acc * alpha + _exact_dot(pexp, kv[-1], PV)
+
+            @pl.when(j == tok_block)
+            def _written():
+                for k in range(n_pools):
+                    pltpu.make_async_copy(bufs[k].at[slot, tok_in],
+                                          pools[k].at[0],
+                                          wsem.at[k]).wait()
+
+            return m_new, el, acc
+
+        init = (jnp.full((rows, 1), NEG, jnp.float32),
+                jnp.zeros((rows, 1), jnp.float32),
+                jnp.zeros((rows, width), jnp.float32))
+        n_blocks = lim // block + 1
+        _, el, acc = jax.lax.fori_loop(0, n_blocks, fold_block, init)
+        cur[3] = cur[3] + n_blocks
         # the step's own token is always live, so el > 0
-        for h, (_, el, acc) in enumerate(final):
-            out_ref[0, h] = acc / el
+        acc = acc / el
+        for h in range(kv_heads):
+            # head h's V lanes: fused rows interleave K and V per head
+            v0 = (2 * h + 1) * dh if fused else h * dh
+            out_ref[0, h] = acc[h * group:(h + 1) * group, v0:v0 + dh]
 
     def call(q, new_k, new_v, pools, page_table, seq_lens):
         pools = tuple(jnp.asarray(p) for p in pools)
@@ -1161,20 +1289,29 @@ def _lower_paged_decode_body(*, batch: int, kv_heads: int, group: int,
         else:
             new = (new_k, new_v)
         new = tuple(t.reshape(batch, 1, width).astype(kv_dt) for t in new)
+        # the query heads block-diagonal over the token row: row
+        # h*group+g holds q[h, g] on head h's K lanes, zeros elsewhere
+        diag = jnp.eye(kv_heads, dtype=bool)[None, :, None, :, None]
+        qbd = jnp.where(diag, q[:, :, :, None, :], 0).astype(q.dtype)
+        if fused:                            # (B, Hkv, g, Hkv, [K, V], dh)
+            qbd = jnp.stack([qbd, jnp.zeros_like(qbd)], axis=4)
+        qbd = qbd.reshape(batch, rows, width)
         hbm = pl.BlockSpec(memory_space=pl.ANY)
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2, grid=(batch,),
-            in_specs=[pl.BlockSpec((1, kv_heads, group, dh),
-                                   lambda b, pt, ln: (b, 0, 0, 0))]
+            in_specs=[pl.BlockSpec((1, rows, width),
+                                   lambda b, pt, ln: (b, 0, 0))]
             + [pl.BlockSpec((1, 1, width), lambda b, pt, ln: (b, 0, 0))
                for _ in new]
             + [hbm] * n_pools,
             out_specs=[pl.BlockSpec((1, kv_heads, group, dh),
                                     lambda b, pt, ln: (b, 0, 0, 0))]
             + [hbm] * n_pools,
-            scratch_shapes=[pltpu.VMEM((ps, width), kv_dt)
+            scratch_shapes=[pltpu.VMEM((depth, ppb, ps, width), kv_dt)
                             for _ in range(n_pools)]
-            + [pltpu.SemaphoreType.DMA((n_pools,))])
+            + [pltpu.SemaphoreType.DMA((n_pools, depth)),
+               pltpu.SemaphoreType.DMA((n_pools,)),
+               pltpu.SMEM((4,), jnp.int32)])
         first_pool = 2 + 1 + n_pools           # scalars, q, new rows
         outs = pl.pallas_call(
             kernel, grid_spec=grid_spec,
@@ -1183,9 +1320,12 @@ def _lower_paged_decode_body(*, batch: int, kv_heads: int, group: int,
             + [jax.ShapeDtypeStruct(p.shape, p.dtype) for p in pools],
             input_output_aliases={first_pool + j: 1 + j
                                   for j in range(n_pools)},
+            # the block stream and its cursor run across grid steps
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",)),
             interpret=backend.interpret(), name="paged_decode")(
                 jnp.asarray(page_table, jnp.int32),
-                jnp.asarray(seq_lens, jnp.int32), q, *new, *pools)
+                jnp.asarray(seq_lens, jnp.int32), qbd, *new, *pools)
         return outs[0], tuple(outs[1:])
 
     return call

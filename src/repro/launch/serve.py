@@ -258,6 +258,7 @@ def serve_continuous(arch: str, smoke: bool, slots: int, gen: int,
     first step's reference run ``serve.certify``.  Every moment of
     the loop lies in one of these leaves.
     """
+    from repro.core.codegen_pallas import paged_decode_blocks
     from repro.core.options import Options
     from repro.kernels import ops
     from repro.models import paged
@@ -286,6 +287,11 @@ def serve_continuous(arch: str, smoke: bool, slots: int, gen: int,
     npm = -(-max_ctx // page_size)
     cache = paged.PagedKVCache.init(cfg, slots, npm * page_size,
                                     page_size=page_size, layout=layout)
+    # the DSE's streaming block, cut as the kernel cuts it
+    blk, depth = paged_decode_blocks(
+        block=blk, depth=depth, page_size=page_size, n_pages_max=npm,
+        kv_heads=cfg.n_kv_heads, head_dim=head_dim, layout=layout,
+        dtype=cache.buffers[0].dtype)
     free_pages = list(range(cache.n_pages - 1, 0, -1))  # page 0 reserved
     for s in range(slots):                              # park every slot
         cache = cache.assign_pages(s, [0] * npm, 0)
@@ -295,7 +301,8 @@ def serve_continuous(arch: str, smoke: bool, slots: int, gen: int,
 
     def _step(params, cache, tok, pallas):
         logits, cache = paged.paged_decode_step(params, cfg, cache, tok,
-                                                use_pallas=pallas)
+                                                use_pallas=pallas,
+                                                block=blk, depth=depth)
         last = logits[:, -1]
         nxt = jnp.argmax(model.mask_vocab_pad(last, cfg), axis=-1)
         return nxt.astype(jnp.int32), last[:, :cfg.vocab], cache
@@ -324,6 +331,7 @@ def serve_continuous(arch: str, smoke: bool, slots: int, gen: int,
     steps = active_steps = admitted = evicted = 0
     prefill_s = decode_s = 0.0
     dense_words = paged_words = 0   # modeled HBM traffic over the trace
+    kv_blocks = 0                   # blocks the kernel streams, all steps
 
     from repro.core import cost as cost_mod
     hkv = cfg.n_kv_heads
@@ -373,6 +381,12 @@ def serve_continuous(arch: str, smoke: bool, slots: int, gen: int,
                 len(active), max_ctx, hkv, head_dim)
             paged_words += cfg.n_layers * cost_mod.paged_decode_traffic_words(
                 live, page_size, hkv, head_dim)
+            # each live slot folds the blocks that hold its tokens and
+            # the step's own, in every layer
+            n_blk = cfg.n_layers * sum(
+                min(n, npm * page_size - 1) // blk + 1 for n in live)
+            kv_blocks += n_blk
+            telemetry.count("serve.kv_blocks", n_blk)
             tok = jnp.asarray(next_tok.reshape(slots, 1))
             check = certified is None and use_pallas and certify
         if check:   # the reference path reads the cache before the
@@ -425,7 +439,8 @@ def serve_continuous(arch: str, smoke: bool, slots: int, gen: int,
     tokens_out = n_req * gen
     stats = {
         "layout": layout, "page_size": page_size, "block": int(blk),
-        "depth": int(depth), "plan_sizes": dict(plan.sizes),
+        "depth": int(depth), "kv_blocks": int(kv_blocks),
+        "plan_sizes": dict(plan.sizes),
         "use_pallas": bool(use_pallas), "certified": certified,
         "certify_err": certify_err,
         "slots": slots, "requests": n_req, "steps": steps,
@@ -438,8 +453,8 @@ def serve_continuous(arch: str, smoke: bool, slots: int, gen: int,
     }
     print(f"continuous serve: {n_req} requests over {slots} slots, "
           f"{steps} steps, occupancy {occupancy:.2f}; "
-          f"layout={layout} page_size={page_size} "
-          f"pallas={use_pallas} certified={certified}; "
+          f"layout={layout} page_size={page_size} block={blk} "
+          f"depth={depth} pallas={use_pallas} certified={certified}; "
           f"decode {decode_s:.2f}s "
           f"({stats['ms_per_token']:.1f} ms/token)")
     return out, stats

@@ -226,11 +226,36 @@ def _gather_layer(pools, page_table, layout: str, page_size: int,
 
 
 # -------------------------------------------------------------- decode
+def reference_attn(q, k, v, pools, page_table, seq_lens, layout: str,
+                   page_size: int) -> Tuple[jax.Array, Tuple[jax.Array, ...]]:
+    """The plain paged decode attention the kernel is held to: the
+    token K/V (``(B, Hkv, dh)``) scattered into its page, the pages
+    gathered dense, softmax over the live slots in f32 at HIGHEST.
+    ``q`` is ``(B, Hkv, group, dh)``; returns the f32 ``(B, Hkv, group,
+    dh)`` output and the new pools."""
+    dh = q.shape[-1]
+    new_pools = _append_layer(pools, page_table, seq_lens, k, v, layout,
+                              page_size)
+    ck, cv = _gather_layer(new_pools, page_table, layout, page_size,
+                           dh)                           # (B,Hkv,Cmax,dh)
+    hi = jax.lax.Precision.HIGHEST   # true f32, like the kernel
+    scores = jnp.einsum("bkgh,bkch->bkgc", q.astype(jnp.float32),
+                        ck.astype(jnp.float32), precision=hi) * dh ** -0.5
+    valid = jnp.arange(ck.shape[2])[None, :] <= seq_lens[:, None]
+    scores = jnp.where(valid[:, None, None, :], scores, -jnp.inf)
+    probs = jax.nn.softmax(scores, axis=-1)
+    out = jnp.einsum("bkgc,bkch->bkgh", probs, cv.astype(jnp.float32),
+                     precision=hi)
+    return out, new_pools
+
+
 def _paged_attn(p, x, cfg: ModelConfig, pools, page_table, seq_lens,
-                layout: str, page_size: int, use_pallas: bool):
+                layout: str, page_size: int, use_pallas: bool,
+                block=None, depth: int = 2):
     """One layer's decode attention over its page pools; the math and
     casts of ``transformer._attn``'s decode branch with per-request
-    positions.  Returns ``(attn_out, new_pools)``."""
+    positions.  ``block`` and ``depth`` are the kernel's streaming
+    block and buffer depth.  Returns ``(attn_out, new_pools)``."""
     b, s, _ = x.shape
     hq, hkv, dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     q = jnp.einsum("bsd,dq->bsq", x, p["wq"])
@@ -243,7 +268,7 @@ def _paged_attn(p, x, cfg: ModelConfig, pools, page_table, seq_lens,
     k = L.rope(k.reshape(b, s, hkv, dh), positions, cfg.rope_theta)
     v = v.reshape(b, s, hkv, dh)
     group = hq // hkv
-    qg = q.reshape(b, s, hkv, group, dh)
+    qg = q.reshape(b, s, hkv, group, dh)[:, 0]
     k1, v1 = k[:, 0], v[:, 0]                            # (B, Hkv, dh)
 
     if use_pallas:
@@ -251,41 +276,28 @@ def _paged_attn(p, x, cfg: ModelConfig, pools, page_table, seq_lens,
         kern = lower_paged_decode(
             batch=b, kv_heads=hkv, group=group, head_dim=dh,
             page_size=page_size, n_pages_max=page_table.shape[1],
-            layout=layout)
-        out, new_pools = kern(qg[:, 0], k1, v1, pools,
-                              page_table, seq_lens)
-        out = out[:, None]                               # (B, 1, Hkv, g, dh)
+            layout=layout, block=block, depth=depth,
+            dtype=pools[0].dtype)
+        out, new_pools = kern(qg, k1, v1, pools, page_table, seq_lens)
     else:
-        new_pools = _append_layer(pools, page_table, seq_lens, k1, v1,
-                                  layout, page_size)
-        ck, cv = _gather_layer(new_pools, page_table, layout,
-                               page_size, dh)            # (B,Hkv,Cmax,dh)
-        hi = jax.lax.Precision.HIGHEST   # true f32, like the kernel
-        scores = jnp.einsum("bskgh,bkch->bskgc",
-                            qg.astype(jnp.float32),
-                            ck.astype(jnp.float32),
-                            precision=hi) * dh ** -0.5
-        slotpos = jnp.arange(ck.shape[2])
-        valid = slotpos[None, :] <= seq_lens[:, None]    # (B, Cmax)
-        scores = jnp.where(valid[:, None, None, None, :],
-                           scores, -jnp.inf)
-        probs = jax.nn.softmax(scores, axis=-1)
-        out = jnp.einsum("bskgc,bkch->bskgh", probs,
-                         cv.astype(jnp.float32), precision=hi)
+        out, new_pools = reference_attn(qg, k1, v1, pools, page_table,
+                                        seq_lens, layout, page_size)
     out = out.reshape(b, s, hq * dh).astype(x.dtype)
     return jnp.einsum("bsq,qd->bsd", out, p["wo"]), tuple(new_pools)
 
 
 def paged_decode_step(params: Params, cfg: ModelConfig,
                       cache: PagedKVCache, tokens: jax.Array, *,
-                      use_pallas: bool = False):
+                      use_pallas: bool = False, block=None,
+                      depth: int = 2):
     """One decode step for every active request: tokens ``(B, 1)``,
     per-request positions from ``cache.seq_lens``.  Returns
     ``(logits, cache')`` with every request's length advanced by one.
     Dense/MoE attention families only (recurrent families have no KV
     cache to page).  Structured exactly like ``model.decode_step``
     (same layer scan over the same stacked params) so the two paths
-    stay bit-comparable."""
+    stay bit-comparable.  ``block`` and ``depth`` go to the fused
+    kernel (``codegen_pallas.lower_paged_decode``)."""
     if cfg.family not in ("dense", "moe"):
         raise NotImplementedError(
             f"paged decode supports dense/moe, not {cfg.family}")
@@ -310,7 +322,7 @@ def paged_decode_step(params: Params, cfg: ModelConfig,
             layer_pools = tuple(pp[i] for pp in pools_slc)
             a, lp = _paged_attn(sl, L.rms_norm(x, sl["ln1"]), cfg,
                                 layer_pools, table, lens, layout, ps,
-                                use_pallas)
+                                use_pallas, block, depth)
             x = x + a
             h = L.rms_norm(x, sl["ln2"])
             if is_moe:

@@ -114,6 +114,108 @@ def test_paged_decode_token_identical_to_oracle(layout):
         assert got_pl == want, f"pallas path diverged at ln={ln}"
 
 
+KERNEL_PAGES = 6          # logical pages per request in the kernel tests
+
+
+def _check_kernel_against_reference(layout, page_size, block_pages, depth,
+                                    kv_dtype=jnp.bfloat16,
+                                    q_dtype=jnp.bfloat16):
+    """The fused kernel (interpret mode) against ``reference_attn``,
+    the reference branch of ``_paged_attn``: the same output, and the
+    same pools after the append.  The batch holds a parked slot
+    (length 0, every page 0), lengths at a block boundary, and the
+    step's token in the first, a middle and the last block, over a
+    shuffled page table, so the requests stream different numbers of
+    blocks and the prefetch crosses from one request to the next."""
+    from repro.core.codegen_pallas import lower_paged_decode
+
+    hkv, group, dh = 2, 2, 16
+    ps, npm = page_size, KERNEL_PAGES
+    ctx, block = npm * ps, block_pages * ps
+    lens = [0, 1, block - 1, min(block, ctx - 1), ctx // 2 + 1, ctx - 1]
+    b = len(lens)
+    rng = np.random.RandomState(7)
+    n_phys = 1 + b * npm + 2
+    width = (2 if layout == "fused" else 1) * hkv * dh
+    pools = tuple(jnp.asarray(rng.randn(n_phys, ps, width), kv_dtype)
+                  for _ in range(1 if layout == "fused" else 2))
+    table = rng.permutation(np.arange(1, n_phys))[:b * npm].reshape(b, npm)
+    table[0] = 0                                    # the parked slot
+    table, lens = jnp.asarray(table, jnp.int32), jnp.asarray(lens, jnp.int32)
+    q = jnp.asarray(rng.randn(b, hkv, group, dh), q_dtype)
+    k = jnp.asarray(rng.randn(b, hkv, dh), kv_dtype)
+    v = jnp.asarray(rng.randn(b, hkv, dh), kv_dtype)
+
+    kern = lower_paged_decode(batch=b, kv_heads=hkv, group=group,
+                              head_dim=dh, page_size=ps, n_pages_max=npm,
+                              layout=layout, block=block, depth=depth,
+                              dtype=kv_dtype)
+    assert (kern.block, kern.depth) == (block, depth)
+    out, new_pools = jax.jit(kern)(q, k, v, pools, table, lens)
+    want, want_pools = paged.reference_attn(q, k, v, pools, table, lens,
+                                            layout, ps)
+    # f32 sums of at most 48 terms, in another order
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)
+    for got, ref in zip(new_pools, want_pools):
+        np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                      np.asarray(ref, np.float32))
+
+
+@pytest.mark.parametrize("depth", (2, 3))
+@pytest.mark.parametrize("block_pages", (1, 2, KERNEL_PAGES),
+                         ids=("one_page", "two_pages", "whole_context"))
+@pytest.mark.parametrize("page_size", (4, 8))
+@pytest.mark.parametrize("layout", paged.LAYOUTS)
+def test_paged_kernel_matches_reference_attention(layout, page_size,
+                                                  block_pages, depth):
+    _check_kernel_against_reference(layout, page_size, block_pages, depth)
+
+
+@pytest.mark.parametrize("kv_dtype", ("bfloat16", "float32"))
+@pytest.mark.parametrize("layout", paged.LAYOUTS)
+def test_paged_kernel_keeps_f32_operands_whole(layout, kv_dtype):
+    """An f32 query meets bf16 K/V as its three exact bf16 terms, and
+    f32 pools take the f32 matmul: both agree with the reference."""
+    _check_kernel_against_reference(layout, 4, 2, 2, kv_dtype=kv_dtype,
+                                    q_dtype=jnp.float32)
+
+
+# granite-3-2b widths: 8 KV heads of 64, bf16
+CLAMP_CASES = {
+    # the DSE's picks for the two serving cells stand as they are
+    "long_decode_dse": ("split", 8, 416, 1664, 3, 1664),
+    "chat_short_dse": ("fused", 8, 80, 640, 4, 640),
+    "one_page": ("split", 8, 416, None, 2, 8),
+    "off_page": ("split", 16, 208, 1000, 2, 992),
+    "past_context": ("fused", 8, 80, 4096, 2, 640),
+    "past_vmem_split": ("split", 8, 416, 3328, 4, None),
+    "past_vmem_fused": ("fused", 64, 52, 3328, 3, None),
+}
+
+
+@pytest.mark.parametrize("case", sorted(CLAMP_CASES))
+def test_paged_decode_block_clamp(case):
+    """The kernel's block is whole pages, at most the context, and its
+    ``depth`` buffers fit the scoped VMEM at granite widths."""
+    from repro.core.codegen_pallas import paged_decode_blocks
+    from repro.core.cost import VMEM_BYTES
+
+    layout, ps, npm, block, depth, want = CLAMP_CASES[case]
+    got, got_depth = paged_decode_blocks(
+        block=block, depth=depth, page_size=ps, n_pages_max=npm,
+        kv_heads=8, head_dim=64, layout=layout, dtype=jnp.bfloat16)
+    assert got_depth == depth
+    assert got % ps == 0 and ps <= got <= npm * ps
+    width = (2 if layout == "fused" else 1) * 8 * 64
+    pools = 1 if layout == "fused" else 2
+    assert depth * got * width * 2 * pools <= VMEM_BYTES * 3 // 4
+    if want is not None:
+        assert got == want
+    else:                       # cut: one more page would not fit
+        assert depth * (got + ps) * width * 2 * pools > VMEM_BYTES * 3 // 4
+
+
 def test_paged_decode_dse_axes_in_provenance():
     """KV layout, page size, streaming block and buffer depth are
     jointly searched axes, recorded in the plan's provenance."""

@@ -21,10 +21,22 @@ from repro.patterns.analytics import PIPELINES
 # the chip_smoke.py extents: tpchq6 at TPC-H SF ~11, the others at 2**20
 SIZES = {"tpchq6": 2 ** 26, "gda": 2 ** 20, "kmeans": 2 ** 20,
          "gda_moments": 2 ** 20, "normalize": 2 ** 20}
-# granite-3-2b decode: 4 requests, 8 KV heads of 64, 32 query heads
-GRANITE = dict(batch=4, kv_heads=8, group=4, head_dim=64, page_size=16,
-               n_pages_max=128)
-POOL_PAGES = 2048
+# granite-3-2b decode: 8 KV heads of 64, 32 query heads; 4 requests
+# over a 2048-page pool at the kernel's default block, and the serving
+# cells' shapes at the blocks the DSE picks for them
+GRANITE = dict(kv_heads=8, group=4, head_dim=64)
+PAGED_SHAPES = {
+    "split": dict(layout="split", batch=4, page_size=16, n_pages_max=128,
+                  pool_pages=2048),
+    "fused": dict(layout="fused", batch=4, page_size=16, n_pages_max=128,
+                  pool_pages=2048),
+    "split-long-decode": dict(layout="split", batch=8, page_size=8,
+                              n_pages_max=416, pool_pages=1 + 8 * 416,
+                              block=1664, depth=3),
+    "fused-chat-short": dict(layout="fused", batch=24, page_size=8,
+                             n_pages_max=80, pool_pages=1 + 24 * 80,
+                             block=640, depth=4),
+}
 
 
 @pytest.fixture(scope="module")
@@ -82,28 +94,35 @@ def test_fused_dag_compiles_for_v5e(name, one_chip, mosaic):
     assert "jit(fused_dag)/fused_dag/pallas_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("layout", ["split", "fused"])
-def test_paged_decode_compiles_for_v5e(layout, one_chip, mosaic):
-    """The paged-decode kernel at granite-3-2b widths over a 2048-page
-    pool: the pool stays in HBM and is updated in place (aliased, no
+@pytest.mark.parametrize("shape", list(PAGED_SHAPES))
+def test_paged_decode_compiles_for_v5e(shape, one_chip, mosaic):
+    """The paged-decode kernel at granite-3-2b widths, as one Mosaic
+    kernel named ``paged_decode``, with its blocks cut to the scoped
+    VMEM: the pool stays in HBM and is updated in place (aliased, no
     temporary copy of it)."""
-    g = GRANITE
-    kern = codegen_pallas.lower_paged_decode(layout=layout, **g)
-    heads = (2 if layout == "fused" else 1) * g["kv_heads"]
-    pool = _spec((POOL_PAGES, g["page_size"], heads * g["head_dim"]),
+    kw = dict(PAGED_SHAPES[shape])
+    n_pool_pages = kw.pop("pool_pages")
+    layout, b, h, dh = kw["layout"], kw["batch"], GRANITE["kv_heads"], \
+        GRANITE["head_dim"]
+    kern = codegen_pallas.lower_paged_decode(**GRANITE, **kw)
+    if "block" in kw:           # the DSE's picks fit as they are
+        assert (kern.block, kern.depth) == (kw["block"], kw["depth"])
+    heads = (2 if layout == "fused" else 1) * h
+    pool = _spec((n_pool_pages, kw["page_size"], heads * dh),
                  jnp.bfloat16, one_chip)
     pools = (pool,) if layout == "fused" else (pool, pool)
-    b, h, dh = g["batch"], g["kv_heads"], g["head_dim"]
     step = jax.jit(lambda q, k, v, pools, pt, ln: kern(q, k, v, pools,
                                                          pt, ln),
                    donate_argnums=(3,))
     compiled = step.lower(
-        _spec((b, h, g["group"], dh), jnp.bfloat16, one_chip),
+        _spec((b, h, GRANITE["group"], dh), jnp.bfloat16, one_chip),
         _spec((b, h, dh), jnp.bfloat16, one_chip),
         _spec((b, h, dh), jnp.bfloat16, one_chip), pools,
-        _spec((b, g["n_pages_max"]), jnp.int32, one_chip),
+        _spec((b, kw["n_pages_max"]), jnp.int32, one_chip),
         _spec((b,), jnp.int32, one_chip)).compile()
-    assert "/paged_decode/pallas_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert "/paged_decode/pallas_call" in text
     mem = compiled.memory_analysis()
     pool_bytes = sum(int(np.prod(p.shape)) * 2 for p in pools)
     assert mem.alias_size_in_bytes == pool_bytes
