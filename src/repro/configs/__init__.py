@@ -1,7 +1,7 @@
 """Architecture registry: --arch <id> resolves here."""
 from . import (granite3_2b, internvl2_1b, llama4_maverick, mamba2_370m,
-               mixtral_8x22b, musicgen_medium, nemotron4_15b, qwen2_72b,
-               starcoder2_15b, zamba2_2_7b)
+               mellum2_12b, mixtral_8x22b, musicgen_medium, nemotron4_15b,
+               qwen2_72b, starcoder2_15b, zamba2_2_7b)
 from .shapes import SHAPES, ShapeConfig, skip_reason, sub_quadratic
 
 ARCHS = {
@@ -15,6 +15,7 @@ ARCHS = {
     "llama4-maverick-400b-a17b": llama4_maverick,
     "mixtral-8x22b": mixtral_8x22b,
     "internvl2-1b": internvl2_1b,
+    "mellum2-12b": mellum2_12b,
 }
 
 

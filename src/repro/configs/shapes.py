@@ -31,7 +31,7 @@ SHAPES = {
 
 
 def sub_quadratic(cfg: ModelConfig) -> bool:
-    return cfg.family in ("ssm", "hybrid") or cfg.sliding_window is not None
+    return cfg.family in ("ssm", "hybrid") or cfg.attn_kinds == ("window",)
 
 
 def skip_reason(cfg: ModelConfig, shape: ShapeConfig) -> Optional[str]:

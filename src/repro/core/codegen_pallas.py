@@ -1041,10 +1041,19 @@ def paged_decode_blocks(*, block: Optional[int], depth: int,
     return pages * page_size, depth
 
 
+def window_block(block: int, page_size: int, window: int) -> int:
+    """The streaming block of a windowed layer's kernel: whole pages,
+    at most ``block`` and a quarter of the window, so the blocks that
+    meet the window hold little outside it."""
+    pages = max(min(block, window // 4) // page_size, 1)
+    return pages * page_size
+
+
 def lower_paged_decode(*, batch: int, kv_heads: int, group: int,
                        head_dim: int, page_size: int, n_pages_max: int,
                        layout: str = "split", block: Optional[int] = None,
-                       depth: int = 2, dtype=jnp.bfloat16) -> Callable:
+                       depth: int = 2, dtype=jnp.bfloat16,
+                       window: Optional[int] = None) -> Callable:
     """Emit the fused decode megakernel over a paged KV cache.
 
     The ``decode_attention`` DAG lowered as one kernel per layer: the
@@ -1086,6 +1095,13 @@ def lower_paged_decode(*, batch: int, kv_heads: int, group: int,
     ``new_v`` ``(B, Hkv, dh)`` (already rotated), ``out`` the f32
     ``(B, Hkv, group, dh)`` attention output.  The call carries the
     streamed ``block`` and ``depth`` as attributes.
+
+    With ``window`` (a sliding-window layer) each request's table row
+    is a ring of ``n_pages_max`` pages: logical page ``p`` lives in
+    column ``p % n_pages_max``, and the ring holds at least the
+    ``window`` newest tokens.  The request folds only the blocks that
+    meet ``(len - window, len]`` and masks every position outside it,
+    so slots that hold older or not yet written tokens never count.
     """
     if layout not in ("split", "fused"):
         raise ValueError(f"layout {layout!r}")
@@ -1098,12 +1114,13 @@ def lower_paged_decode(*, batch: int, kv_heads: int, group: int,
     with telemetry.span("codegen.lower_paged_decode", layout=layout,
                         batch=int(batch), page_size=int(page_size),
                         n_pages_max=int(n_pages_max), block=block,
-                        depth=depth, pages_per_block=block // page_size):
+                        depth=depth, pages_per_block=block // page_size,
+                        window=window):
         call = _lower_paged_decode_body(
             batch=batch, kv_heads=kv_heads, group=group,
             head_dim=head_dim, page_size=page_size,
             n_pages_max=n_pages_max, layout=layout, block=block,
-            depth=depth)
+            depth=depth, window=window)
     call.block, call.depth = block, depth
     return call
 
@@ -1146,7 +1163,8 @@ def _exact_dot(a, b, dims):
 def _lower_paged_decode_body(*, batch: int, kv_heads: int, group: int,
                              head_dim: int, page_size: int,
                              n_pages_max: int, layout: str, block: int,
-                             depth: int) -> Callable:
+                             depth: int, window: Optional[int] = None
+                             ) -> Callable:
     from jax.experimental.pallas import tpu as pltpu
 
     fused = layout == "fused"
@@ -1176,12 +1194,21 @@ def _lower_paged_decode_body(*, batch: int, kv_heads: int, group: int,
         # pool is a fault, not a garbage read); gathers clamp the same
         # way on the reference path
         def page_of(r, p):
-            return jnp.clip(pt_ref[r, jnp.minimum(p, n_pages_max - 1)],
-                            0, n_phys - 1)
+            col = (jnp.minimum(p, n_pages_max - 1) if window is None
+                   else p % n_pages_max)
+            return jnp.clip(pt_ref[r, col], 0, n_phys - 1)
 
         def last(r):
             """The last position request ``r`` folds."""
+            if window is not None:
+                return len_ref[r]
             return jnp.minimum(len_ref[r], ctx - 1)
+
+        def first_block(r):
+            """The first block request ``r`` folds."""
+            if window is None:
+                return 0
+            return jnp.maximum(len_ref[r] - window + 1, 0) // block
 
         def page_copy(k, slot, i, page):
             return pltpu.make_async_copy(pools[k].at[page],
@@ -1205,13 +1232,17 @@ def _lower_paged_decode_body(*, batch: int, kv_heads: int, group: int,
                 jax.lax.fori_loop(0, ppb, start, 0)
                 done = j + 1 > last(r) // block
                 cur[0] = jnp.where(done, r + 1, r)
-                cur[1] = jnp.where(done, 0, j + 1)
+                nxt0 = (0 if window is None
+                        else first_block(jnp.minimum(r + 1, batch - 1)))
+                cur[1] = jnp.where(done, nxt0, j + 1)
                 cur[2] = started + 1
 
         @pl.when(b == 0)
         def _prime():
             for i in range(4):
                 cur[i] = 0
+            if window is not None:
+                cur[1] = first_block(0)
             for _ in range(depth - 1):
                 fetch_next()
 
@@ -1219,16 +1250,20 @@ def _lower_paged_decode_body(*, batch: int, kv_heads: int, group: int,
         lim = last(b)
         # where the step's token goes: its logical page (clamped to the
         # table, as the reference clamps), that page's block and slot
-        tok_page = jnp.minimum(ln // ps, n_pages_max - 1)
+        tok_page = (jnp.minimum(ln // ps, n_pages_max - 1) if window is None
+                    else ln // ps)
         tok_block, tok_in = tok_page // ppb, tok_page % ppb
         hit = jax.lax.broadcasted_iota(jnp.int32, (ps, 1), 0) == ln % ps
 
         qbd = q_ref[0]                          # (rows, width)
 
+        j0 = first_block(b)
+
         def fold_block(j, carry):
             m, el, acc = carry
             fetch_next()                # the block depth - 1 ahead
-            slot = (cur[3] + j) % depth
+            slot = (cur[3] + j if window is None
+                    else cur[3] + j - j0) % depth
 
             def wait(i, c):
                 for k in range(n_pools):
@@ -1252,7 +1287,10 @@ def _lower_paged_decode_body(*, batch: int, kv_heads: int, group: int,
             s_ = _exact_dot(qbd, kv[0], QK) * scale      # (rows, block)
             pos = j * block + jax.lax.broadcasted_iota(
                 jnp.int32, (1, block), 1)
-            s_ = jnp.where(pos <= lim, s_, NEG)          # ragged predicate
+            live = pos <= lim                            # ragged predicate
+            if window is not None:
+                live &= pos > lim - window
+            s_ = jnp.where(live, s_, NEG)
             m_new = jnp.maximum(m, s_.max(-1, keepdims=True))
             pexp = jnp.exp(s_ - m_new)
             alpha = jnp.exp(m - m_new)
@@ -1272,8 +1310,9 @@ def _lower_paged_decode_body(*, batch: int, kv_heads: int, group: int,
                 jnp.zeros((rows, 1), jnp.float32),
                 jnp.zeros((rows, width), jnp.float32))
         n_blocks = lim // block + 1
-        _, el, acc = jax.lax.fori_loop(0, n_blocks, fold_block, init)
-        cur[3] = cur[3] + n_blocks
+        _, el, acc = jax.lax.fori_loop(j0, n_blocks, fold_block, init)
+        cur[3] = (cur[3] + n_blocks if window is None
+                  else cur[3] + n_blocks - j0)
         # the step's own token is always live, so el > 0
         acc = acc / el
         for h in range(kv_heads):
@@ -1327,5 +1366,143 @@ def _lower_paged_decode_body(*, batch: int, kv_heads: int, group: int,
                 jnp.asarray(page_table, jnp.int32),
                 jnp.asarray(seq_lens, jnp.int32), qbd, *new, *pools)
         return outs[0], tuple(outs[1:])
+
+    return call
+
+
+# --------------------------------------------------------------------
+# dropless MoE (serving): grouped SwiGLU matmul over the held experts
+# --------------------------------------------------------------------
+
+
+MOE_GMM_WEIGHT_VMEM = 32 * 2 ** 20   # two buffers of a step's weights
+
+
+def moe_gmm_ff_block(*, d_model: int, d_ff: int, dtype) -> int:
+    """Columns of the expert width one grid step streams: the whole
+    width where two buffers of its three weight blocks fit
+    ``MOE_GMM_WEIGHT_VMEM``, else the widest multiple of 128 that
+    divides it and fits."""
+    item = jnp.dtype(dtype).itemsize
+    cands = [d_ff] + [c for c in range(d_ff - d_ff % 128, 0, -128)
+                      if d_ff % c == 0]
+    for c in cands:
+        if 2 * 3 * d_model * c * item <= MOE_GMM_WEIGHT_VMEM:
+            return c
+    return cands[-1]
+
+
+def lower_moe_gmm(*, rows: int, d_model: int, d_ff: int, n_experts: int,
+                  row_block: int, dtype=jnp.bfloat16) -> Callable:
+    """Emit the grouped SwiGLU matmul of a dropless MoE share.
+
+    Token rows arrive sorted by expert, each expert's segment padded
+    to whole tiles of ``row_block`` rows, so tile ``t`` holds rows of
+    one expert, ``tile_expert[t]``.  The row domain is a ragged fold
+    over the experts' token counts (``ir.RaggedExtent``): the grid is
+    the static tile bound, and only the leading ``n_tiles`` tiles are
+    live.  Grid step ``(t, n)`` streams columns ``n`` of the expert's
+    ``w1``/``w3`` and rows ``n`` of its ``w2`` through the pipeline's
+    double buffer (the metapipeline: step ``(t, n+1)``'s weights load
+    while ``(t, n)`` computes) and accumulates
+    ``(silu(x w1) * (x w3)) w2`` for the tile in f32.  Steps past the
+    live tiles keep the last live tile's block indices, so the
+    pipeline fetches nothing for them and they compute nothing: the
+    weights of an expert no token chose are never read (unless no
+    tile is live, when one block of expert ``tile_expert[0]`` is).
+
+    Returns ``call(xs, w1, w3, w2, tile_expert, n_tiles, layer=0) ->
+    ys`` with ``xs``/``ys`` ``(rows, D)``, ``w1``/``w3`` ``(L, E, D,
+    F)``, ``w2`` ``(L, E, F, D)`` -- every layer's experts, of which the
+    kernel reads layer ``layer``'s, so no layer is copied out of the
+    stack -- ``tile_expert`` ``(rows // row_block,)`` and ``n_tiles``
+    ``(1,)`` int32.  Rows of tiles past ``n_tiles`` are left unwritten.
+    """
+    from jax.experimental.pallas import tpu as pltpu
+
+    rag = ir.RaggedExtent(max=rows, length_name="n_tiles",
+                          granularity=row_block)
+    n_t = rag.max_units
+    tf = moe_gmm_ff_block(d_model=d_model, d_ff=d_ff, dtype=dtype)
+    if rows % row_block:
+        raise ValueError(f"row_block {row_block} must divide rows {rows}")
+    n_f = d_ff // tf
+    item = jnp.dtype(dtype).itemsize
+    vmem = (2 * 3 * d_model * tf * item + 4 * row_block * d_model * item
+            + row_block * d_model * 4 + 3 * row_block * tf * 4)
+    with telemetry.span("codegen.lower_moe_gmm", rows=int(rows),
+                        row_block=int(row_block), ff_block=int(tf),
+                        experts=int(n_experts)):
+
+        # nt holds the live tile count and the layer
+        def live(t, nt):
+            ok = t < nt[0]
+            return ok, jnp.where(ok, t, jnp.maximum(nt[0] - 1, 0))
+
+        def x_map(t, n, te, nt):
+            return (live(t, nt)[1], 0)
+
+        def w_in_map(t, n, te, nt):
+            ok, tt = live(t, nt)
+            return (nt[1], te[tt], 0, jnp.where(ok, n, n_f - 1))
+
+        def w_out_map(t, n, te, nt):
+            ok, tt = live(t, nt)
+            return (nt[1], te[tt], jnp.where(ok, n, n_f - 1), 0)
+
+        if backend.interpret():
+            # the CPU has no bf16 x bf16 -> f32 dot; bf16 values are
+            # exact in f32, so the products and sums are the same
+            def dot(a, b):
+                return jnp.dot(a.astype(jnp.float32), b.astype(jnp.float32))
+        else:
+            def dot(a, b):
+                return jnp.dot(a, b, preferred_element_type=jnp.float32)
+
+        def kernel(te_ref, nt_ref, x_ref, w1_ref, w3_ref, w2_ref, o_ref,
+                   acc_ref):
+            t, n = pl.program_id(0), pl.program_id(1)
+
+            @pl.when(t < nt_ref[0])
+            def _():
+                x = x_ref[...]
+                h1 = dot(x, w1_ref[0, 0])
+                h3 = dot(x, w3_ref[0, 0])
+                g = (h1 * jax.nn.sigmoid(h1) * h3).astype(w2_ref.dtype)
+                part = dot(g, w2_ref[0, 0])
+
+                @pl.when(n == 0)
+                def _first():
+                    acc_ref[...] = part
+
+                @pl.when(n > 0)
+                def _more():
+                    acc_ref[...] += part
+
+                @pl.when(n == n_f - 1)
+                def _store():
+                    o_ref[...] = acc_ref[...].astype(o_ref.dtype)
+
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(n_t, n_f),
+            in_specs=[pl.BlockSpec((row_block, d_model), x_map),
+                      pl.BlockSpec((1, 1, d_model, tf), w_in_map),
+                      pl.BlockSpec((1, 1, d_model, tf), w_in_map),
+                      pl.BlockSpec((1, 1, tf, d_model), w_out_map)],
+            out_specs=pl.BlockSpec((row_block, d_model), x_map),
+            scratch_shapes=[pltpu.VMEM((row_block, d_model), jnp.float32)])
+        kern = pl.pallas_call(
+            kernel, grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((rows, d_model), dtype),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary", "arbitrary"),
+                vmem_limit_bytes=max(int(vmem * 1.25) + (4 << 20),
+                                     32 << 20)),
+            interpret=backend.interpret(), name="moe_gmm")
+
+    def call(xs, w1, w3, w2, tile_expert, n_tiles, layer=0):
+        nt = jnp.concatenate([jnp.asarray(n_tiles, jnp.int32).reshape(1),
+                              jnp.asarray(layer, jnp.int32).reshape(1)])
+        return kern(jnp.asarray(tile_expert, jnp.int32), nt, xs, w1, w3, w2)
 
     return call

@@ -39,6 +39,7 @@ from repro.configs import ARCHS, get_config
 from repro.core import backend, telemetry
 from repro.launch import steps as steps_mod
 from repro.models import model
+from repro.models import transformer as tr
 
 
 def _prefill(prefill_fn, params, cache, prompt, ring: int,
@@ -58,10 +59,11 @@ def _prefill(prefill_fn, params, cache, prompt, ring: int,
 
 
 def _ring_len(cfg, max_len: int) -> int:
-    """Slot count of the KV ring buffer (= prompt-chunk bound); the
-    recurrent scan path has no ring, so any chunk length works."""
+    """Prompt-chunk bound: the KV ring's slot count, or the window
+    where windowed and full layers mix; the recurrent scan path has no
+    ring, so any chunk length works."""
     if cfg.family in ("dense", "moe", "audio", "vlm"):
-        return model.cache_specs(cfg, 1, max_len)["k"].shape[3]
+        return tr.prefill_chunk(cfg, max_len)
     return max_len
 
 
@@ -243,6 +245,14 @@ def serve_continuous(arch: str, smoke: bool, slots: int, gen: int,
     within ``CERTIFY_RTOL``; otherwise ``CertificationError`` is raised
     (there is no silent fallback to the reference path).
 
+    A config with windowed layers keeps their K/V in a second pool of
+    per-slot rings (``paged.PagedKVCache``): an admission takes pages
+    of both kinds from their own free lists and writes the whole
+    prompt into its full-layer pages and the last ``window`` tokens
+    into its ring.  MoE layers run the dropless expert share, in
+    prefill and decode, through the grouped-matmul kernel where
+    ``use_pallas``.
+
     Returns ``(tokens, stats)``: the (n_requests, gen) generated
     tokens in request order, and occupancy/latency/provenance stats.
 
@@ -250,15 +260,19 @@ def serve_continuous(arch: str, smoke: bool, slots: int, gen: int,
     whose children are ``serve.admit.prefill`` (until its first token
     is on the host) and ``serve.admit.scatter`` (page assignment and
     the K/V scatter into the pool, until the pool is written; it
-    carries the device's ``bytes_in_use`` and ``peak_bytes_in_use``).
-    Each step is a ``serve.decode_step`` whose children are
+    carries the pages taken of each kind, ``pages_full`` and
+    ``pages_window``, and the device's ``bytes_in_use`` and
+    ``peak_bytes_in_use``).  Each step is a ``serve.decode_step``
+    (with MoE layers, carrying ``moe_tokens_held`` and
+    ``moe_experts_touched``, summed over the layers; the counters of
+    those names add them up) whose children are
     ``serve.step.launch`` (the step program dispatched) and
     ``serve.step.wait`` (its tokens on the host).  The bookkeeping
     before and after a step is a ``serve.step.host`` each, and the
     first step's reference run ``serve.certify``.  Every moment of
     the loop lies in one of these leaves.
     """
-    from repro.core.codegen_pallas import paged_decode_blocks
+    from repro.core.codegen_pallas import paged_decode_blocks, window_block
     from repro.core.options import Options
     from repro.kernels import ops
     from repro.models import paged
@@ -293,25 +307,39 @@ def serve_continuous(arch: str, smoke: bool, slots: int, gen: int,
         kv_heads=cfg.n_kv_heads, head_dim=head_dim, layout=layout,
         dtype=cache.buffers[0].dtype)
     free_pages = list(range(cache.n_pages - 1, 0, -1))  # page 0 reserved
+    window = cache.window
+    n_full = tr.layers_of_kind(cfg, "full")
+    if window is not None:
+        ring = cache.ring
+        free_ring = list(range(cache.win_buffers[0].shape[1] - 1, 0, -1))
+        # the block a windowed layer's kernel streams
+        win_blk, _ = paged_decode_blocks(
+            block=window_block(blk, page_size, window), depth=depth,
+            page_size=page_size, n_pages_max=ring, kv_heads=cfg.n_kv_heads,
+            head_dim=head_dim, layout=layout, dtype=cache.buffers[0].dtype)
     for s in range(slots):                              # park every slot
         cache = cache.assign_pages(s, [0] * npm, 0)
 
-    prefill_fn = jax.jit(steps_mod.make_cache_prefill_step(cfg),
+    moe_impl = "kernel" if use_pallas else "dropless"
+    prefill_fn = jax.jit(steps_mod.make_cache_prefill_step(cfg, moe_impl),
                          donate_argnums=(1,))
 
     def _step(params, cache, tok, pallas):
-        logits, cache = paged.paged_decode_step(params, cfg, cache, tok,
-                                                use_pallas=pallas,
-                                                block=blk, depth=depth)
+        logits, cache, *moe_stats = paged.paged_decode_step(
+            params, cfg, cache, tok, use_pallas=pallas, block=blk,
+            depth=depth, with_stats=bool(cfg.n_experts))
+        moe_stats = moe_stats[0] if moe_stats else None
         last = logits[:, -1]
         nxt = jnp.argmax(model.mask_vocab_pad(last, cfg), axis=-1)
-        return nxt.astype(jnp.int32), last[:, :cfg.vocab], cache
+        return nxt.astype(jnp.int32), last[:, :cfg.vocab], cache, moe_stats
 
     def serve_step(p, c, t):
         return _step(p, c, t, use_pallas)
 
     def certify_reference(p, c, t):
-        return _step(p, c, t, False)
+        # the logits alone: the reference's pools are never used, so
+        # they are never kept beside the served ones
+        return _step(p, c, t, False)[1]
 
     step_fn = jax.jit(serve_step, donate_argnums=(1,))
     certified, certify_err = None, None
@@ -325,6 +353,7 @@ def serve_continuous(arch: str, smoke: bool, slots: int, gen: int,
     queue = deque(range(n_req))
     slot_req: List[Optional[int]] = [None] * slots
     slot_pages: List[List[int]] = [[] for _ in range(slots)]
+    slot_ring: List[List[int]] = [[] for _ in range(slots)]
     slot_done = [0] * slots
     next_tok = np.zeros(slots, np.int32)
     out = np.zeros((n_req, gen), np.int64)
@@ -343,10 +372,13 @@ def serve_continuous(arch: str, smoke: bool, slots: int, gen: int,
             r = queue[0]
             ln = lens[r]
             need = -(-(ln + gen) // page_size)
-            if len(free_pages) < need:
+            need_ring = min(ring, need) if window is not None else 0
+            if len(free_pages) < need or (
+                    window is not None and len(free_ring) < need_ring):
                 break
             queue.popleft()
             pages = [free_pages.pop() for _ in range(need)]
+            rpages = [free_ring.pop() for _ in range(need_ring)]
             t0 = time.time()
             with telemetry.span("serve.admit", request=r, slot=s,
                                 prompt_len=ln, pages=need):
@@ -357,18 +389,20 @@ def serve_continuous(arch: str, smoke: bool, slots: int, gen: int,
                     first, dcache = _prefill(prefill_fn, params, dcache,
                                              prompt, _ring_len(cfg, ln))
                     next_tok[s] = int(np.asarray(first)[0])
-                with telemetry.span("serve.admit.scatter") as sp:
-                    cache = cache.assign_pages(s, pages, ln)
-                    cache = cache.write_tokens(
-                        s, dcache["k"][:, 0, :, :ln],
-                        dcache["v"][:, 0, :, :ln], 0)
-                    jax.block_until_ready(cache.buffers)
+                with telemetry.span("serve.admit.scatter",
+                                    pages_full=need if n_full else 0,
+                                    pages_window=need_ring) as sp:
+                    cache = cache.assign_pages(s, pages, ln, rpages)
+                    cache = _write_prompt(cfg, cache, s, dcache, ln)
+                    jax.block_until_ready((cache.buffers,
+                                           cache.win_buffers))
                     if telemetry.enabled():
                         sp.set(**_memory_in_use(cache.buffers[0]))
             dt = time.time() - t0
             prefill_s += dt
             telemetry.observe("serve.admit_s", dt)
             slot_req[s], slot_pages[s], slot_done[s] = r, pages, 0
+            slot_ring[s] = rpages
             admitted += 1
 
         with telemetry.span("serve.step.host"):
@@ -382,9 +416,14 @@ def serve_continuous(arch: str, smoke: bool, slots: int, gen: int,
             paged_words += cfg.n_layers * cost_mod.paged_decode_traffic_words(
                 live, page_size, hkv, head_dim)
             # each live slot folds the blocks that hold its tokens and
-            # the step's own, in every layer
-            n_blk = cfg.n_layers * sum(
+            # the step's own, in every full layer; in a windowed layer
+            # only the blocks that meet its window
+            n_blk = n_full * sum(
                 min(n, npm * page_size - 1) // blk + 1 for n in live)
+            if window is not None:
+                n_blk += (cfg.n_layers - n_full) * sum(
+                    n // win_blk - max(n - window + 1, 0) // win_blk + 1
+                    for n in live)
             kv_blocks += n_blk
             telemetry.count("serve.kv_blocks", n_blk)
             tok = jnp.asarray(next_tok.reshape(slots, 1))
@@ -393,14 +432,23 @@ def serve_continuous(arch: str, smoke: bool, slots: int, gen: int,
             # fused step donates it
             with telemetry.span("serve.certify", layout=layout,
                                 page_size=page_size):
-                _, ref_logits, _ = ref_fn(params, cache, tok)
+                ref_logits = ref_fn(params, cache, tok)
         t0 = time.time()
         with telemetry.span("serve.decode_step", step=steps,
-                            active=len(active)):
+                            active=len(active)) as step_sp:
             with telemetry.span("serve.step.launch"):
-                nxt, logits, cache = step_fn(params, cache, tok)
+                nxt, logits, cache, moe_stats = step_fn(params, cache, tok)
+                if moe_stats is not None:   # on its way while nxt is made
+                    for t in moe_stats:
+                        t.copy_to_host_async()
             with telemetry.span("serve.step.wait"):
                 nxt = np.asarray(nxt)
+                if moe_stats is not None:
+                    held, touched = (int(np.sum(t)) for t in moe_stats)
+                    telemetry.count("moe.tokens_held", held)
+                    telemetry.count("moe.experts_touched", touched)
+                    step_sp.set(moe_tokens_held=held,
+                                moe_experts_touched=touched)
         dt = time.time() - t0
         with telemetry.span("serve.step.host"):
             if check:
@@ -431,14 +479,17 @@ def serve_continuous(arch: str, smoke: bool, slots: int, gen: int,
                 slot_done[s] += 1
                 if slot_done[s] == gen:                      # evict
                     free_pages.extend(slot_pages[s])
+                    if window is not None:
+                        free_ring.extend(slot_ring[s])
                     cache = cache.assign_pages(s, [0] * npm, 0)
-                    slot_req[s], slot_pages[s] = None, []
+                    slot_req[s], slot_pages[s], slot_ring[s] = None, [], []
                     evicted += 1
 
     occupancy = active_steps / max(steps * slots, 1)
     tokens_out = n_req * gen
     stats = {
         "layout": layout, "page_size": page_size, "block": int(blk),
+        "ring_pages": int(ring) if window is not None else 0,
         "depth": int(depth), "kv_blocks": int(kv_blocks),
         "plan_sizes": dict(plan.sizes),
         "use_pallas": bool(use_pallas), "certified": certified,
@@ -458,6 +509,24 @@ def serve_continuous(arch: str, smoke: bool, slots: int, gen: int,
           f"decode {decode_s:.2f}s "
           f"({stats['ms_per_token']:.1f} ms/token)")
     return out, stats
+
+
+def _write_prompt(cfg, cache, slot: int, dcache: Dict, ln: int):
+    """The prefilled dense cache of one request into its pages: every
+    position into the full layers' pages, the newest ``window`` into
+    the windowed layers' ring (the dense ring holds position ``p`` at
+    slot ``p % C``)."""
+    for kind in tr.kinds_in_plan(cfg):
+        kk, vk = tr.kv_keys(cfg, kind)
+        if kind == "full":
+            cache = cache.write_tokens(slot, dcache[kk][:, 0, :, :ln],
+                                       dcache[vk][:, 0, :, :ln], 0)
+            continue
+        n = min(ln, cache.window)
+        idx = (ln - n + np.arange(n)) % dcache[kk].shape[3]
+        cache = cache.write_window(slot, dcache[kk][:, 0][:, :, idx],
+                                   dcache[vk][:, 0][:, :, idx], ln - n)
+    return cache
 
 
 def _parse_lens(text: Optional[str]) -> Optional[Tuple[int, ...]]:

@@ -147,10 +147,12 @@ def make_serve_step(cfg: ModelConfig):
         return serve_step
 
 
-def make_cache_prefill_step(cfg: ModelConfig):
+def make_cache_prefill_step(cfg: ModelConfig, moe_impl: str = "capacity"):
     """Prefill a whole prompt block into the decode cache in ONE jitted
     call: ``(params, cache, tokens(B, S[, ncb]), index) -> (next, cache)``
     with ``next`` the greedy token after the final prompt position.
+    ``moe_impl`` picks the MoE layer (``transformer._block``): serving
+    prefills through the dropless share.
 
     Attention families run the block through ``decode_step`` directly
     (S tokens written to the cache contiguously, causal within the
@@ -161,10 +163,10 @@ def make_cache_prefill_step(cfg: ModelConfig):
     at the ring boundary (``launch.serve`` does).
     """
     with telemetry.span("steps.build.cache_prefill", family=cfg.family):
-        return _make_cache_prefill_body(cfg)
+        return _make_cache_prefill_body(cfg, moe_impl)
 
 
-def _make_cache_prefill_body(cfg: ModelConfig):
+def _make_cache_prefill_body(cfg: ModelConfig, moe_impl: str):
     block = cfg.family in ("dense", "moe", "audio", "vlm")
 
     def _greedy(logits):
@@ -174,7 +176,7 @@ def _make_cache_prefill_body(cfg: ModelConfig):
     def serve_prefill(params, cache, tokens, index):
         if block:
             logits, cache2 = model.decode_step(params, cfg, cache,
-                                               tokens, index)
+                                               tokens, index, moe_impl)
             return _greedy(logits), cache2
 
         def body(carry, tok):

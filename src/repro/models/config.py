@@ -3,7 +3,7 @@ families (dense / ssm / hybrid / moe / audio / vlm backbones)."""
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional
+from typing import Optional, Tuple
 
 
 @dataclasses.dataclass(frozen=True)
@@ -23,6 +23,13 @@ class ModelConfig:
     qkv_bias: bool = False
     rope_theta: float = 1e4
     sliding_window: Optional[int] = None   # SWA (Mixtral)
+    # attention kind of each layer within a repeating period, "window"
+    # (sliding window, default RoPE) or "full" (global, with ``yarn``
+    # where set); () = every layer alike (windowed iff sliding_window)
+    layer_kinds: Tuple[str, ...] = ()
+    # YaRN RoPE of the full layers: (factor, original_max_positions,
+    # beta_fast, beta_slow, attention_factor)
+    yarn: Optional[Tuple[float, int, float, float, float]] = None
     # ffn
     d_ff: int = 0
     activation: str = "swiglu"    # swiglu | squared_relu | gelu
@@ -32,6 +39,9 @@ class ModelConfig:
     capacity_factor: float = 1.25
     moe_layer_period: int = 1     # every k-th layer is MoE (Llama-4: 2)
     shared_expert: bool = False   # Llama-4 shared expert
+    # experts whose weights this chip holds (its expert-parallel share,
+    # experts 0..n-1); the router keeps all n_experts outputs.  0 = all
+    n_experts_held: int = 0
     # ssm (mamba-2)
     ssm_state: int = 0
     ssm_heads: int = 0
@@ -62,6 +72,28 @@ class ModelConfig:
     @property
     def kv_dim(self) -> int:
         return self.n_kv_heads * self.head_dim
+
+    @property
+    def attn_kinds(self) -> Tuple[str, ...]:
+        """Attention kind of each layer of the repeating period."""
+        if self.layer_kinds:
+            return self.layer_kinds
+        return ("window",) if self.sliding_window is not None else ("full",)
+
+    @property
+    def mixed_attention(self) -> bool:
+        """Windowed and full layers side by side (two KV pool kinds)."""
+        return len(set(self.attn_kinds)) > 1
+
+    def window_of(self, kind: str) -> Optional[int]:
+        return self.sliding_window if kind == "window" else None
+
+    def yarn_of(self, kind: str):
+        return self.yarn if kind == "full" else None
+
+    @property
+    def experts_held(self) -> int:
+        return self.n_experts_held or self.n_experts
 
     @property
     def d_inner(self) -> int:

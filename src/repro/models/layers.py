@@ -1,10 +1,12 @@
 """Shared pure-JAX building blocks: norms, RoPE, activations, inits."""
 from __future__ import annotations
 
+import math
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 
 def rms_norm(x: jax.Array, w: jax.Array, eps: float = 1e-6) -> jax.Array:
@@ -24,15 +26,47 @@ def activation(name: str):
     raise KeyError(name)
 
 
+def yarn_inv_freq(dim: int, theta: float, yarn) -> np.ndarray:
+    """YaRN's per-pair rotation frequencies (arXiv:2309.00071, as
+    Hugging Face computes them): dimensions that turn fewer than
+    ``beta_slow`` times over the original context are interpolated by
+    ``factor``, those that turn more than ``beta_fast`` times keep
+    their frequency, with a linear ramp between."""
+    factor, orig, beta_fast, beta_slow, _ = yarn
+    half = dim // 2
+    pos_freqs = theta ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
+
+    def corr_dim(n_rot):
+        return (dim * math.log(orig / (n_rot * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(corr_dim(beta_fast)), 0)
+    high = min(math.ceil(corr_dim(beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(half) - low) / (high - low), 0.0, 1.0)
+    keep = 1.0 - ramp
+    return (1.0 / (factor * pos_freqs) * (1.0 - keep)
+            + 1.0 / pos_freqs * keep)
+
+
 def rope(x: jax.Array, positions: jax.Array,
-         theta: float = 1e4) -> jax.Array:
-    """x: (..., S, H, D) rotary over D; positions: (..., S)."""
+         theta: float = 1e4, yarn=None) -> jax.Array:
+    """x: (..., S, H, D) rotary over D; positions: (..., S).  With
+    ``yarn`` (factor, original_max_positions, beta_fast, beta_slow,
+    attention_factor) the frequencies are YaRN's and cos/sin carry its
+    attention factor."""
     d = x.shape[-1]
     half = d // 2
-    freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    if yarn is None:
+        freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    else:
+        freqs = jnp.asarray(yarn_inv_freq(d, theta, yarn), jnp.float32)
     ang = positions[..., None].astype(jnp.float32) * freqs      # (..,S,half)
     cos = jnp.cos(ang)[..., None, :]                            # (..,S,1,half)
     sin = jnp.sin(ang)[..., None, :]
+    if yarn is not None:
+        cos, sin = cos * yarn[4], sin * yarn[4]
     x1, x2 = x[..., :half], x[..., half:]
     out = jnp.concatenate([x1 * cos - x2 * sin,
                            x2 * cos + x1 * sin], axis=-1)

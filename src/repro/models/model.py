@@ -174,9 +174,12 @@ def _ssm_decode(params: Params, cfg: ModelConfig, cache: Dict,
 
 
 def decode_step(params: Params, cfg: ModelConfig, cache: Dict,
-                tokens: jax.Array, index: jax.Array):
+                tokens: jax.Array, index: jax.Array,
+                moe_impl: str = "capacity"):
+    """``moe_impl`` picks the MoE layer of attention families
+    (``transformer._block``); the other families have none."""
     if cfg.family in ("dense", "moe", "audio", "vlm"):
-        return tr.decode_step(params, cfg, cache, tokens, index)
+        return tr.decode_step(params, cfg, cache, tokens, index, moe_impl)
     if cfg.family == "ssm":
         return _ssm_decode(params, cfg, cache, tokens, index)
     if cfg.family == "hybrid":

@@ -6,15 +6,24 @@ consumed by ``jax.lax.scan`` so the lowered HLO stays small for 80-layer
 
   * GQA / MQA attention with RoPE, optional QKV bias (Qwen-2), optional
     sliding window (Mixtral), squared-ReLU FFN (Nemotron-4);
-  * MoE FFN layers (every ``moe_layer_period``-th layer);
+  * windowed and full attention layers side by side in a repeating
+    period (Mellum 2: three windowed layers with default RoPE to each
+    full layer with YaRN), each kind with its own decode cache;
+  * MoE FFN layers (every ``moe_layer_period``-th layer): the capacity
+    path for training, the dropless share (``moe.moe_dropless``) for
+    serving;
   * multi-codebook token embeddings / heads (MusicGen) and prefix
     embeddings from a stubbed modality frontend (InternVL);
   * full-sequence forward (training / prefill) and single-token decode
     with a preallocated KV cache (sliding-window configs keep a
-    ring-buffer cache of ``min(window, max_len)``).
+    ring-buffer cache of ``min(window, max_len)``; the windowed layers
+    of a mixed config one of ``min(2 * window, max_len)``, so a prompt
+    chunk of up to ``window`` tokens never overwrites keys the chunk's
+    own queries still see).
 """
 from __future__ import annotations
 
+import math
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -93,14 +102,44 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
     return out
 
 
+def layer_plan(cfg: ModelConfig) -> Tuple[Tuple[bool, str], ...]:
+    """The super-block the layer scans walk: for each layer of the
+    period, whether its FFN is MoE and its attention kind.  The period
+    is the least common multiple of the MoE period and the attention
+    kinds' period."""
+    mp = cfg.moe_layer_period if cfg.n_experts else 1
+    kinds = cfg.attn_kinds
+    period = mp * len(kinds) // math.gcd(mp, len(kinds))
+    return tuple((bool(cfg.n_experts) and i % mp == mp - 1,
+                  kinds[i % len(kinds)]) for i in range(period))
+
+
+def kinds_in_plan(cfg: ModelConfig) -> Tuple[str, ...]:
+    """The attention kinds present, full first."""
+    return tuple(k for k in ("full", "window") if k in cfg.attn_kinds)
+
+
+def layers_of_kind(cfg: ModelConfig, kind: str) -> int:
+    kinds = cfg.attn_kinds
+    return cfg.n_layers // len(kinds) * kinds.count(kind)
+
+
+def kv_keys(cfg: ModelConfig, kind: str) -> Tuple[str, str]:
+    """Decode-cache keys of a kind's K and V stacks."""
+    if cfg.mixed_attention and kind == "window":
+        return ("k_win", "v_win")
+    return ("k", "v")
+
+
 # -------------------------------------------------------------- attention
 def _attn(p: Dict, x: jax.Array, cfg: ModelConfig,
           positions: jax.Array,
           kv_cache: Optional[Tuple] = None,
-          cache_index: Optional[jax.Array] = None):
+          cache_index: Optional[jax.Array] = None, kind: str = "full"):
     """x: (B, S, D).  With kv_cache=(k,v) of (B, Hkv, C, dh), performs
     decode: writes this step's k/v at ``cache_index`` (mod C: ring
-    buffer for sliding windows) and attends over the cache."""
+    buffer for sliding windows) and attends over the cache.  ``kind``
+    picks the layer's window and RoPE."""
     b, s, d = x.shape
     hq, hkv, dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     q = jnp.einsum("bsd,dq->bsq", x, p["wq"])
@@ -111,8 +150,9 @@ def _attn(p: Dict, x: jax.Array, cfg: ModelConfig,
     q = q.reshape(b, s, hq, dh)
     k = k.reshape(b, s, hkv, dh)
     v = v.reshape(b, s, hkv, dh)
-    q = L.rope(q, positions, cfg.rope_theta)
-    k = L.rope(k, positions, cfg.rope_theta)
+    window, yarn = cfg.window_of(kind), cfg.yarn_of(kind)
+    q = L.rope(q, positions, cfg.rope_theta, yarn)
+    k = L.rope(k, positions, cfg.rope_theta, yarn)
     q = hint(q, "data", None, "model", None)
     k = hint(k, "data", None, "model", None)
 
@@ -120,7 +160,7 @@ def _attn(p: Dict, x: jax.Array, cfg: ModelConfig,
     qg = q.reshape(b, s, hkv, group, dh)
 
     if kv_cache is None:
-        out = _sdpa_chunked(q, k, v, positions, cfg)
+        out = _sdpa_chunked(q, k, v, positions, cfg, window)
         out = out.reshape(b, s, hq * dh)
         return jnp.einsum("bsq,qd->bsd", out, p["wo"]), None
 
@@ -150,8 +190,8 @@ def _attn(p: Dict, x: jax.Array, cfg: ModelConfig,
         abspos = last - (wlast - slotpos) % c
         qpos = cache_index + jnp.arange(s)
         valid = (abspos[None, :] <= qpos[:, None]) & (abspos >= 0)[None, :]
-        if cfg.sliding_window is not None:
-            valid &= abspos[None, :] > qpos[:, None] - cfg.sliding_window
+        if window is not None:
+            valid &= abspos[None, :] > qpos[:, None] - window
         scores = jnp.where(valid[None, :, None, None, :],
                            scores, -jnp.inf)
         probs = jax.nn.softmax(scores, axis=-1)
@@ -167,7 +207,8 @@ ATTN_CHUNK = 1024  # q-block size for the tiled softmax (XLA-level flash)
 
 
 def _sdpa_chunked(q: jax.Array, k: jax.Array, v: jax.Array,
-                  positions: jax.Array, cfg: ModelConfig) -> jax.Array:
+                  positions: jax.Array, cfg: ModelConfig,
+                  window: Optional[int] = None) -> jax.Array:
     """Tiled softmax attention: scan over query blocks so the (s x s)
     score tensor never materializes -- the paper's strip-mine +
     interchange applied to attention (the Pallas kernel in
@@ -235,8 +276,8 @@ def _sdpa_chunked(q: jax.Array, k: jax.Array, v: jax.Array,
             s_ = jnp.einsum("bshd,bthd->bhst", qb, kb,
                             preferred_element_type=jnp.float32) * scale
             mask = kp[None, :] <= pb[:, None]
-            if cfg.sliding_window is not None:
-                mask &= kp[None, :] > pb[:, None] - cfg.sliding_window
+            if window is not None:
+                mask &= kp[None, :] > pb[:, None] - window
             s_ = jnp.where(mask[None, None], s_, -1e30)
             m_new = jnp.maximum(m_run, s_.max(-1))
             p = jnp.exp(s_ - m_new[..., None])
@@ -283,14 +324,23 @@ def _dense_ffn(p: Dict, x: jax.Array, cfg: ModelConfig) -> jax.Array:
 
 
 def _block(slc: Dict, x, cfg: ModelConfig, positions, is_moe: bool,
-           kv_cache=None, cache_index=None):
+           kv_cache=None, cache_index=None, kind: str = "full",
+           moe_impl: str = "capacity"):
+    """One layer.  ``moe_impl``: ``capacity`` (``moe.moe_ffn``, the
+    GSPMD training path) or the dropless share, ``dropless`` in XLA or
+    ``kernel`` through the grouped-matmul kernel."""
     a, new_cache = _attn(slc, L.rms_norm(x, slc["ln1"]), cfg, positions,
-                         kv_cache, cache_index)
+                         kv_cache, cache_index, kind)
     x = x + a
     h = L.rms_norm(x, slc["ln2"])
     if is_moe:
         moe_p = {k[4:]: v for k, v in slc.items() if k.startswith("moe_")}
-        x = x + moe_mod.moe_ffn(moe_p, h, cfg)
+        if moe_impl == "capacity":
+            x = x + moe_mod.moe_ffn(moe_p, h, cfg)
+        else:
+            y, _ = moe_mod.moe_dropless(moe_p, h, cfg,
+                                        use_pallas=moe_impl == "kernel")
+            x = x + y
     else:
         x = x + _dense_ffn(slc, h, cfg)
     # sequence parallelism: the residual stream (and thus the per-layer
@@ -313,6 +363,49 @@ def _layer_stacks(params: Params, cfg: ModelConfig):
     return attn, dense, moe
 
 
+EXPERT_KEYS = ("moe_we1", "moe_we3", "moe_we2")
+
+
+def _super_stacks(params: Params, cfg: ModelConfig, plan,
+                  whole_experts: bool = False):
+    """The attention, dense-FFN and MoE stacks reshaped to
+    ``(n_super, layers of that kind per super-block, ...)``.  With
+    ``whole_experts`` the expert weights stay out of the scan (the
+    kernel reads them from the whole stack, ``expert_stacks``) and
+    the MoE stack carries each layer's index, ``moe_layer``."""
+    attn, dense, moe = _layer_stacks(params, cfg)
+    n_super = cfg.n_layers // len(plan)
+    n_moe = sum(m for m, _ in plan)
+    if whole_experts and moe:
+        moe = {k: v for k, v in moe.items() if k not in EXPERT_KEYS}
+        moe["moe_layer"] = jnp.arange(n_super * n_moe, dtype=jnp.int32)
+
+    def per_super(n):
+        return lambda t: t.reshape((n_super, n) + t.shape[1:])
+
+    return (jax.tree.map(per_super(len(plan)), attn),
+            jax.tree.map(per_super(len(plan) - n_moe), dense),
+            jax.tree.map(per_super(n_moe), moe))
+
+
+def expert_stacks(params: Params, whole_experts: bool) -> Dict:
+    """Every MoE layer's expert weights, for the layers to take whole
+    (``_super_stacks(..., whole_experts=True)``); else nothing."""
+    if not whole_experts:
+        return {}
+    return {k: params[k] for k in EXPERT_KEYS if k in params}
+
+
+def _layer_slice(plan, i: int, a_slc, d_slc, m_slc) -> Dict:
+    """Layer ``i`` of a super-block: its attention params and its dense
+    or MoE FFN params."""
+    is_moe = plan[i][0]
+    j = sum(m == is_moe for m, _ in plan[:i])
+    sl = {k: v[i] for k, v in a_slc.items()}
+    sl.update({k: v[j] for k, v in (m_slc if is_moe else d_slc).items()})
+    return sl
+
+
 def _embed_tokens(params: Params, cfg: ModelConfig,
                   tokens: jax.Array) -> jax.Array:
     if cfg.n_codebooks:
@@ -333,42 +426,21 @@ def forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
     b, s, d = x.shape
     x = hint(x, "data", None, None)
     positions = jnp.arange(s)
-    attn, dense, moe = _layer_stacks(params, cfg)
-    period = cfg.moe_layer_period if cfg.n_experts else 1
-    n_super = cfg.n_layers // period
+    plan = layer_plan(cfg)
 
     def super_block(x, slices):
         a_slc, d_slc, m_slc = slices
-        # (period-1) dense layers then 1 moe layer (period=1: moe only)
-        for i in range(period - 1 if moe else period):
-            sl = {k: v[i] for k, v in a_slc.items()}
-            sl.update({k: v[i] for k, v in d_slc.items()})
-            x, _ = _block(sl, x, cfg, positions, is_moe=False)
-        if moe:
-            sl = {k: v[period - 1] for k, v in a_slc.items()}
-            sl.update(m_slc)
-            x, _ = _block(sl, x, cfg, positions, is_moe=True)
+        for i, (is_moe, kind) in enumerate(plan):
+            x, _ = _block(_layer_slice(plan, i, a_slc, d_slc, m_slc), x,
+                          cfg, positions, is_moe, kind=kind)
         return x, None
 
     if cfg.remat:
         super_block = jax.checkpoint(
             super_block, policy=jax.checkpoint_policies.nothing_saveable)
 
-    def stack_reshape(t):
-        return t.reshape((n_super, period) + t.shape[1:])
-
-    a_stk = jax.tree.map(stack_reshape, attn)
-    if dense and moe:  # interleaved (Llama-4): dense stacks have
-        # n_layers - n_moe entries = n_super * (period - 1)
-        d_stk = jax.tree.map(
-            lambda t: t.reshape((n_super, period - 1) + t.shape[1:]),
-            dense)
-    else:
-        d_stk = jax.tree.map(stack_reshape, dense) if dense else {}
-    m_stk = jax.tree.map(lambda t: t, moe)  # already (n_moe, ...)
-
     x, _ = L.scan_layers(lambda c, sl: super_block(c, sl), x,
-                         (a_stk, d_stk, m_stk), cfg.unroll)
+                         _super_stacks(params, cfg, plan), cfg.unroll)
     x = L.rms_norm(x, params["final_norm"])
     if cfg.n_codebooks:
         logits = jnp.einsum("bsd,ndv->bsnv", x, params["lm_head"])
@@ -378,86 +450,97 @@ def forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
 
 
 # ------------------------------------------------------------------ decode
-def cache_len(cfg: ModelConfig, max_len: int) -> int:
+def cache_len(cfg: ModelConfig, max_len: int, kind: str = "full") -> int:
+    if cfg.mixed_attention:
+        return (min(2 * cfg.sliding_window, max_len) if kind == "window"
+                else max_len)
     if cfg.sliding_window is not None:
         return min(cfg.sliding_window, max_len)
     return max_len
 
 
-def init_cache(cfg: ModelConfig, batch: int, max_len: int,
-               dtype=None) -> Dict:
-    c = cache_len(cfg, max_len)
-    dt = dtype or jnp.dtype(cfg.dtype)
-    shp = (cfg.n_layers, batch, cfg.n_kv_heads, c, cfg.head_dim)
-    return {"k": jnp.zeros(shp, dt), "v": jnp.zeros(shp, dt)}
+def prefill_chunk(cfg: ModelConfig, max_len: int) -> int:
+    """Longest block one prefill call may write: it must not wrap a
+    ring (nor, with mixed kinds, exceed the window)."""
+    if cfg.mixed_attention:
+        return min(cfg.sliding_window, max_len)
+    return cache_len(cfg, max_len)
 
 
 def cache_specs(cfg: ModelConfig, batch: int, max_len: int) -> Dict:
-    c = cache_len(cfg, max_len)
+    """Shapes of the decode cache: one K and one V stack per attention
+    kind (``kv_keys``), over that kind's layers."""
     dt = jnp.dtype(cfg.dtype)
-    shp = (cfg.n_layers, batch, cfg.n_kv_heads, c, cfg.head_dim)
-    return {"k": jax.ShapeDtypeStruct(shp, dt),
-            "v": jax.ShapeDtypeStruct(shp, dt)}
+    out = {}
+    for kind in kinds_in_plan(cfg):
+        shp = (layers_of_kind(cfg, kind), batch, cfg.n_kv_heads,
+               cache_len(cfg, max_len, kind), cfg.head_dim)
+        for key in kv_keys(cfg, kind):
+            out[key] = jax.ShapeDtypeStruct(shp, dt)
+    return out
+
+
+def init_cache(cfg: ModelConfig, batch: int, max_len: int,
+               dtype=None) -> Dict:
+    return {k: jnp.zeros(s.shape, dtype or s.dtype)
+            for k, s in cache_specs(cfg, batch, max_len).items()}
 
 
 def decode_step(params: Params, cfg: ModelConfig, cache: Dict,
-                tokens: jax.Array, index: jax.Array):
+                tokens: jax.Array, index: jax.Array,
+                moe_impl: str = "capacity"):
     """One decode step.  tokens: (B, S[, n_codebooks]); index: scalar
     current position (number of tokens already in the cache).
 
     ``S > 1`` is block decode -- the whole-prompt prefill path: the S
     tokens are written to the cache contiguously at ``index`` and
     attend causally among themselves and over the cache.  The block
-    must not wrap the ring buffer (``index % C + S <= C``); serving
-    callers chunk prompts at the ring boundary.
+    must not wrap a ring buffer (``index % C + S <= C``) nor exceed
+    ``prefill_chunk``; serving callers chunk prompts at that bound.
+    ``moe_impl`` as in ``_block``.
     """
     x = _embed_tokens(params, cfg, tokens)
-    b = x.shape[0]
     s = x.shape[1]
     positions = index + jnp.arange(s, dtype=jnp.int32)
-    attn, dense, moe = _layer_stacks(params, cfg)
-    period = cfg.moe_layer_period if cfg.n_experts else 1
-    n_super = cfg.n_layers // period
+    plan = layer_plan(cfg)
+    n_super = cfg.n_layers // len(plan)
+    kinds = kinds_in_plan(cfg)
 
     def super_block(carry, slices):
         x = carry
-        a_slc, d_slc, m_slc, kc, vc = slices
-        new_k, new_v = [], []
-        for i in range(period):
-            is_moe = bool(moe) and i == period - 1
-            sl = {k: v[i] for k, v in a_slc.items()}
+        a_slc, d_slc, m_slc, kv = slices
+        new = {kind: ([], []) for kind in kinds}
+        for i, (is_moe, kind) in enumerate(plan):
+            j = len(new[kind][0])
+            kc, vc = kv[kind]
+            sl = _layer_slice(plan, i, a_slc, d_slc, m_slc)
             if is_moe:
-                sl.update(m_slc)
-            else:
-                sl.update({k: v[i if moe else i] for k, v in d_slc.items()})
+                sl.update(experts)
             x, (nk, nv) = _block(sl, x, cfg, positions, is_moe,
-                                 kv_cache=(kc[i], vc[i]),
-                                 cache_index=index)
-            new_k.append(nk)
-            new_v.append(nv)
-        return x, (jnp.stack(new_k), jnp.stack(new_v))
+                                 kv_cache=(kc[j], vc[j]),
+                                 cache_index=index, kind=kind,
+                                 moe_impl=moe_impl)
+            new[kind][0].append(nk)
+            new[kind][1].append(nv)
+        return x, {kind: (jnp.stack(ks), jnp.stack(vs))
+                   for kind, (ks, vs) in new.items()}
 
-    def stack_reshape(t):
-        return t.reshape((n_super, period) + t.shape[1:])
+    def per_super(t):
+        return t.reshape((n_super, -1) + t.shape[1:])
 
-    a_stk = jax.tree.map(stack_reshape, attn)
-    if dense and moe:
-        d_stk = jax.tree.map(
-            lambda t: t.reshape((n_super, period - 1) + t.shape[1:]),
-            dense)
-    else:
-        d_stk = jax.tree.map(stack_reshape, dense) if dense else {}
-    m_stk = moe
-    kc = stack_reshape(cache["k"])
-    vc = stack_reshape(cache["v"])
-
-    x, (nk, nv) = L.scan_layers(super_block, x,
-                                (a_stk, d_stk, m_stk, kc, vc), cfg.unroll)
+    whole = moe_impl == "kernel"
+    stacks = _super_stacks(params, cfg, plan, whole)
+    experts = expert_stacks(params, whole)
+    kv = {kind: tuple(per_super(cache[key]) for key in kv_keys(cfg, kind))
+          for kind in kinds}
+    x, new_kv = L.scan_layers(super_block, x, stacks + (kv,), cfg.unroll)
     x = L.rms_norm(x, params["final_norm"])
     if cfg.n_codebooks:
         logits = jnp.einsum("bsd,ndv->bsnv", x, params["lm_head"])
     else:
         logits = jnp.einsum("bsd,dv->bsv", x, params["lm_head"])
-    new_cache = {"k": nk.reshape(cache["k"].shape),
-                 "v": nv.reshape(cache["v"].shape)}
+    new_cache = {}
+    for kind in kinds:
+        for key, t in zip(kv_keys(cfg, kind), new_kv[kind]):
+            new_cache[key] = t.reshape(cache[key].shape)
     return logits, new_cache

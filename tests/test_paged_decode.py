@@ -288,11 +288,20 @@ def test_dryrun_cost_analysis_normalization():
 
 
 def test_paged_rejects_sliding_window_and_recurrent():
+    """A sliding-window config is no longer rejected: its layers get a
+    ring pool (a window of 4 over pages of 4 needs 2 pages per request)
+    beside an empty full-layer pool.  Recurrent families have no KV
+    cache to page and are still rejected."""
     cfg = get_config(ARCH, smoke=True)
     import dataclasses
     swcfg = dataclasses.replace(cfg, sliding_window=4)
+    cache = paged.PagedKVCache.init(swcfg, 1, 16, page_size=4)
+    assert cache.window == 4 and cache.ring == 2
+    assert cache.buffers[0].shape[0] == 0
+    assert cache.win_buffers[0].shape[0] == cfg.n_layers
+    ssm = get_config("mamba2-370m", smoke=True)
     with pytest.raises(NotImplementedError):
-        paged.PagedKVCache.init(swcfg, 1, 8, page_size=4)
+        paged.paged_decode_step({}, ssm, cache, jnp.zeros((1, 1), jnp.int32))
 
 
 def test_decode_traffic_model_prefers_live_pages():

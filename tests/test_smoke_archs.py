@@ -86,6 +86,7 @@ def test_param_counts_match_published():
         "zamba2-2.7b": (2.7e9, 0.40),
         "musicgen-medium": (1.5e9, 0.5),
         "internvl2-1b": (0.9e9, 0.5),     # LM backbone only
+        "mellum2-12b": (12e9, 0.05),      # all 64 experts counted
     }
     for arch, (want, tol) in expect.items():
         got = get_config(arch).param_count()
