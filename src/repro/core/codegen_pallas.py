@@ -1083,18 +1083,23 @@ def lower_paged_decode(*, batch: int, kv_heads: int, group: int,
     block (which may have been fetched before the append) and the page
     is written back from it while the block computes.
 
-    Layouts: ``split`` takes/returns two pools ``(P, ps, Hkv*dh)``;
-    ``fused`` one head-interleaved pool ``(P, ps, 2*Hkv*dh)`` (K of
-    head ``h`` at head slot ``2h``, V at ``2h+1``).  A token is one
-    lane-dense row, so a page is whole (sublane, lane) tiles.  The page
-    table and the lengths are scalar-prefetched into SMEM; the pools
-    stay in HBM, aliased input to output, and no step copies a pool.
+    Layouts: ``split`` takes/returns two pools ``(L, P, ps, Hkv*dh)``;
+    ``fused`` one head-interleaved pool ``(L, P, ps, 2*Hkv*dh)`` (K of
+    head ``h`` at head slot ``2h``, V at ``2h+1``).  Each pool is
+    stacked over the ``L`` layers of its kind and the kernel touches
+    only layer ``layer``'s pages, so a layer scan carries the stacks
+    whole and never slices a layer out.  A token is one lane-dense
+    row, so a page is whole (sublane, lane) tiles.  The page table,
+    the lengths and the layer are scalar-prefetched into SMEM; the
+    pools stay in HBM, aliased input to output, and no step copies a
+    pool.
 
-    Returns ``call(q, new_k, new_v, pools, page_table, seq_lens) ->
-    (out, new_pools)`` with ``q`` ``(B, Hkv, group, dh)``, ``new_k`` /
-    ``new_v`` ``(B, Hkv, dh)`` (already rotated), ``out`` the f32
-    ``(B, Hkv, group, dh)`` attention output.  The call carries the
-    streamed ``block`` and ``depth`` as attributes.
+    Returns ``call(q, new_k, new_v, pools, page_table, seq_lens, layer)
+    -> (out, new_pools)`` with ``q`` ``(B, Hkv, group, dh)``, ``new_k``
+    / ``new_v`` ``(B, Hkv, dh)`` (already rotated), ``layer`` an int32
+    scalar, ``out`` the f32 ``(B, Hkv, group, dh)`` attention output.
+    The call carries the streamed ``block`` and ``depth`` as
+    attributes.
 
     With ``window`` (a sliding-window layer) each request's table row
     is a ring of ``n_pages_max`` pages: logical page ``p`` lives in
@@ -1179,16 +1184,17 @@ def _lower_paged_decode_body(*, batch: int, kv_heads: int, group: int,
     QK = (((1,), (1,)), ((), ()))          # contract the token row
     PV = (((1,), (0,)), ((), ()))          # contract the block's tokens
 
-    def kernel(pt_ref, len_ref, q_ref, *refs):
+    def kernel(pt_ref, len_ref, ly_ref, q_ref, *refs):
         new_rows = refs[:n_pools]              # (1, 1, width) VMEM
         # refs[n_pools:2 * n_pools] are the input pools, the same HBM
         # buffers as the aliased output pools used below
         out_ref = refs[2 * n_pools]
-        pools = refs[2 * n_pools + 1:3 * n_pools + 1]
+        pools = refs[2 * n_pools + 1:3 * n_pools + 1]  # (L, P, ps, w)
         bufs = refs[3 * n_pools + 1:4 * n_pools + 1]  # (depth, ppb, ps, w)
         sem, wsem, cur = refs[4 * n_pools + 1:]
         b = pl.program_id(0)
-        n_phys = pools[0].shape[0]
+        layer = ly_ref[0]
+        n_phys = pools[0].shape[1]
 
         # indices stay in bounds whatever the host wrote (a DMA off the
         # pool is a fault, not a garbage read); gathers clamp the same
@@ -1211,7 +1217,7 @@ def _lower_paged_decode_body(*, batch: int, kv_heads: int, group: int,
             return jnp.maximum(len_ref[r] - window + 1, 0) // block
 
         def page_copy(k, slot, i, page):
-            return pltpu.make_async_copy(pools[k].at[page],
+            return pltpu.make_async_copy(pools[k].at[layer, page],
                                          bufs[k].at[slot, i],
                                          sem.at[k, slot])
 
@@ -1280,7 +1286,7 @@ def _lower_paged_decode_body(*, batch: int, kv_heads: int, group: int,
                     buf[...] = jnp.where(
                         hit, new_rows[k][0].astype(jnp.float32),
                         buf[...].astype(jnp.float32)).astype(buf.dtype)
-                    pltpu.make_async_copy(buf, pools[k].at[page],
+                    pltpu.make_async_copy(buf, pools[k].at[layer, page],
                                           wsem.at[k]).start()
 
             kv = [buf[slot].reshape(block, width) for buf in bufs]
@@ -1301,7 +1307,7 @@ def _lower_paged_decode_body(*, batch: int, kv_heads: int, group: int,
             def _written():
                 for k in range(n_pools):
                     pltpu.make_async_copy(bufs[k].at[slot, tok_in],
-                                          pools[k].at[0],
+                                          pools[k].at[layer, 0],
                                           wsem.at[k]).wait()
 
             return m_new, el, acc
@@ -1320,7 +1326,7 @@ def _lower_paged_decode_body(*, batch: int, kv_heads: int, group: int,
             v0 = (2 * h + 1) * dh if fused else h * dh
             out_ref[0, h] = acc[h * group:(h + 1) * group, v0:v0 + dh]
 
-    def call(q, new_k, new_v, pools, page_table, seq_lens):
+    def call(q, new_k, new_v, pools, page_table, seq_lens, layer):
         pools = tuple(jnp.asarray(p) for p in pools)
         kv_dt = pools[0].dtype
         if fused:
@@ -1337,21 +1343,21 @@ def _lower_paged_decode_body(*, batch: int, kv_heads: int, group: int,
         qbd = qbd.reshape(batch, rows, width)
         hbm = pl.BlockSpec(memory_space=pl.ANY)
         grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2, grid=(batch,),
+            num_scalar_prefetch=3, grid=(batch,),
             in_specs=[pl.BlockSpec((1, rows, width),
-                                   lambda b, pt, ln: (b, 0, 0))]
-            + [pl.BlockSpec((1, 1, width), lambda b, pt, ln: (b, 0, 0))
+                                   lambda b, pt, ln, ly: (b, 0, 0))]
+            + [pl.BlockSpec((1, 1, width), lambda b, pt, ln, ly: (b, 0, 0))
                for _ in new]
             + [hbm] * n_pools,
             out_specs=[pl.BlockSpec((1, kv_heads, group, dh),
-                                    lambda b, pt, ln: (b, 0, 0, 0))]
+                                    lambda b, pt, ln, ly: (b, 0, 0, 0))]
             + [hbm] * n_pools,
             scratch_shapes=[pltpu.VMEM((depth, ppb, ps, width), kv_dt)
                             for _ in range(n_pools)]
             + [pltpu.SemaphoreType.DMA((n_pools, depth)),
                pltpu.SemaphoreType.DMA((n_pools,)),
                pltpu.SMEM((4,), jnp.int32)])
-        first_pool = 2 + 1 + n_pools           # scalars, q, new rows
+        first_pool = 3 + 1 + n_pools           # scalars, q, new rows
         outs = pl.pallas_call(
             kernel, grid_spec=grid_spec,
             out_shape=[jax.ShapeDtypeStruct(
@@ -1364,7 +1370,8 @@ def _lower_paged_decode_body(*, batch: int, kv_heads: int, group: int,
                 dimension_semantics=("arbitrary",)),
             interpret=backend.interpret(), name="paged_decode")(
                 jnp.asarray(page_table, jnp.int32),
-                jnp.asarray(seq_lens, jnp.int32), qbd, *new, *pools)
+                jnp.asarray(seq_lens, jnp.int32),
+                jnp.asarray(layer, jnp.int32).reshape(1), qbd, *new, *pools)
         return outs[0], tuple(outs[1:])
 
     return call

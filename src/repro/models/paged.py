@@ -19,7 +19,10 @@ select_paged_decode_blocks`` searches):
     so a page streams both operands of one head in a single burst.
 
 A token is one lane-dense row of its heads, so a page is whole TPU
-tiles and the decode kernel reads and writes the pool in place.
+tiles and the decode kernel reads and writes the pool in place.  The
+decode step carries each kind's pools, stacked over its layers, through
+the layer scan: the kernel takes the whole stacks and a layer index, so
+no step slices a layer's pool out of the stack or stacks it back.
 
 ``paged_decode_step`` mirrors ``model.decode_step`` structurally (same
 ``scan_layers`` over stacked params, same einsums and casts, only the
@@ -349,15 +352,17 @@ def reference_attn(q, k, v, pools, page_table, seq_lens, layout: str,
     return out, new_pools
 
 
-def _paged_attn(p, x, cfg: ModelConfig, pools, page_table, seq_lens,
-                layout: str, page_size: int, use_pallas: bool,
+def _paged_attn(p, x, cfg: ModelConfig, pools, found, layer, page_table,
+                seq_lens, layout: str, page_size: int, use_pallas: bool,
                 block=None, depth: int = 2, kind: str = "full"):
     """One layer's decode attention over its page pools; the math and
     casts of ``transformer._attn``'s decode branch with per-request
-    positions.  ``block`` and ``depth`` are the kernel's streaming
-    block and buffer depth; ``kind`` the layer's attention kind (a
-    windowed layer's ``page_table`` holds rings).  Returns
-    ``(attn_out, new_pools)``."""
+    positions.  ``pools`` are stacked over the layers of the layer's
+    attention ``kind`` and ``layer`` indexes them (a windowed layer's
+    ``page_table`` holds rings); ``found`` are the stacks as the step
+    received them.  ``block`` and ``depth`` are the kernel's streaming
+    block and buffer depth.  Returns ``(attn_out, new_pools)``, the
+    stacks with the layer's token appended."""
     b, s, _ = x.shape
     hq, hkv, dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     window, yarn = cfg.window_of(kind), cfg.yarn_of(kind)
@@ -384,11 +389,18 @@ def _paged_attn(p, x, cfg: ModelConfig, pools, page_table, seq_lens,
             page_size=page_size, n_pages_max=page_table.shape[1],
             layout=layout, block=block, depth=depth,
             dtype=pools[0].dtype, window=window)
-        out, new_pools = kern(qg, k1, v1, pools, page_table, seq_lens)
+        out, new_pools = kern(qg, k1, v1, pools, page_table, seq_lens,
+                              layer)
     else:
-        out, new_pools = reference_attn(qg, k1, v1, pools, page_table,
-                                        seq_lens, layout, page_size,
-                                        window)
+        # only this layer writes its pages, so the step's input holds
+        # them as the carry does; reading it leaves the carried stacks
+        # unread, and a caller that drops the new cache (certification)
+        # keeps no copy of them
+        out, new = reference_attn(qg, k1, v1,
+                                  tuple(t[layer] for t in found),
+                                  page_table, seq_lens, layout, page_size,
+                                  window)
+        new_pools = tuple(t.at[layer].set(n) for t, n in zip(pools, new))
     out = out.reshape(b, s, hq * dh).astype(x.dtype)
     return jnp.einsum("bsq,qd->bsd", out, p["wo"]), tuple(new_pools)
 
@@ -404,7 +416,9 @@ def paged_decode_step(params: Params, cfg: ModelConfig,
     cache to page).  Structured exactly like ``model.decode_step``
     (same layer scan over the same stacked params, the same
     super-block of windowed/full and dense/MoE layers) so the two
-    paths stay comparable.  ``block`` and ``depth`` go to the fused
+    paths stay comparable.  The pools of each attention kind ride in
+    the scan's carry, stacked, and each layer reads and writes its own
+    layer of them in place.  ``block`` and ``depth`` go to the fused
     kernel (``codegen_pallas.lower_paged_decode``); ``use_pallas`` also
     runs the MoE layers' grouped-matmul kernel.  ``with_stats`` adds a
     third output: ``(pairs, touched)``, per MoE layer the token-expert
@@ -419,20 +433,24 @@ def paged_decode_step(params: Params, cfg: ModelConfig,
     lens = cache.seq_lens
     layout, ps = cache.layout, cache.page_size
     tables = {"full": cache.page_table, "window": cache.win_table}
+    bufs = {"full": cache.buffers, "window": cache.win_buffers}
     kinds = kinds_in_plan(cfg)
+    per_super = {kind: sum(k == kind for _, k in plan) for kind in kinds}
 
     def super_block(carry, slices):
-        x = carry
-        a_slc, d_slc, m_slc, pools_slc = slices
-        new_pools = {kind: [[] for _ in pools_slc[kind]] for kind in kinds}
+        x, pools = carry
+        pools = dict(pools)
+        a_slc, d_slc, m_slc, s = slices
+        seen = dict.fromkeys(kinds, 0)
         stats = []
         for i, (is_moe, kind) in enumerate(plan):
             sl = _layer_slice(plan, i, a_slc, d_slc, m_slc)
-            j = len(new_pools[kind][0])
-            layer_pools = tuple(pp[j] for pp in pools_slc[kind])
-            a, lp = _paged_attn(sl, L.rms_norm(x, sl["ln1"]), cfg,
-                                layer_pools, tables[kind], lens, layout,
-                                ps, use_pallas, block, depth, kind)
+            layer = s * per_super[kind] + seen[kind]
+            seen[kind] += 1
+            a, pools[kind] = _paged_attn(
+                sl, L.rms_norm(x, sl["ln1"]), cfg, pools[kind], bufs[kind],
+                layer, tables[kind], lens, layout, ps, use_pallas, block,
+                depth, kind)
             x = x + a
             h = L.rms_norm(x, sl["ln2"])
             if is_moe:
@@ -445,29 +463,17 @@ def paged_decode_step(params: Params, cfg: ModelConfig,
                 stats.append(st)
             else:
                 x = x + _dense_ffn(sl, h, cfg)
-            for n, npool in enumerate(lp):
-                new_pools[kind][n].append(npool)
-        out = {kind: tuple(jnp.stack(nps) for nps in pools)
-               for kind, pools in new_pools.items()}
-        return x, (out, tuple(jnp.stack(c) for c in zip(*stats)))
-
-    def per_super(t):
-        return t.reshape((n_super, -1) + t.shape[1:])
+        return (x, pools), tuple(jnp.stack(c) for c in zip(*stats))
 
     stacks = _super_stacks(params, cfg, plan, use_pallas)
     experts = expert_stacks(params, use_pallas)
-    bufs = {"full": cache.buffers, "window": cache.win_buffers}
-    pools_stk = {kind: tuple(per_super(b) for b in bufs[kind])
-                 for kind in kinds}
-    x, (new_stk, stats) = L.scan_layers(super_block, x,
-                                        stacks + (pools_stk,), cfg.unroll)
+    (x, pools), stats = L.scan_layers(
+        super_block, (x, {kind: bufs[kind] for kind in kinds}),
+        stacks + (jnp.arange(n_super, dtype=jnp.int32),), cfg.unroll)
     x = L.rms_norm(x, params["final_norm"])
     logits = jnp.einsum("bsd,dv->bsv", x, params["lm_head"])
-    new = {kind: tuple(nb.reshape(b.shape)
-                       for nb, b in zip(new_stk[kind], bufs[kind]))
-           for kind in kinds}
-    cache = cache.replace(buffers=new.get("full"),
-                          win_buffers=new.get("window"), seq_lens=lens + 1)
+    cache = cache.replace(buffers=pools.get("full"),
+                          win_buffers=pools.get("window"), seq_lens=lens + 1)
     if not with_stats:
         return logits, cache
     return logits, cache, (tuple(t.reshape(-1) for t in stats)

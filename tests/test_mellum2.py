@@ -26,6 +26,7 @@ from repro.configs import get_config
 from repro.core.codegen_pallas import lower_paged_decode
 from repro.launch import serve, steps
 from repro.models import model, moe, paged
+from test_paged_decode import check_stacked_pools
 
 ROOT = Path(__file__).resolve().parents[1]
 CFG = get_config("mellum2-12b", smoke=True)
@@ -136,12 +137,14 @@ def test_paged_decode_logits_match_the_reference():
     assert diff.max() <= LOGIT_TOL and diff.mean() <= MEAN_TOL
 
 
+@pytest.mark.parametrize("layer", [0, 2])
 @pytest.mark.parametrize("layout", paged.LAYOUTS)
 @pytest.mark.parametrize("block", [PS, 2 * PS])
-def test_windowed_kernel_matches_reference_attn(layout, block):
+def test_windowed_kernel_matches_reference_attn(layout, block, layer):
     """The windowed ``lower_paged_decode`` (interpreted) against
     ``reference_attn`` with the window: ragged lengths below, at and
-    past the window, rings wrapped several times."""
+    past the window, rings wrapped several times, in layer ``layer`` of
+    rings stacked over three layers (the others left untouched)."""
     window, ring = 8, paged.ring_pages(8, PS, 100)
     lens = jnp.asarray([0, 3, 7, 8, 9, 15, 23, 40], jnp.int32)
     b, hkv, group, dh = len(lens), 2, 2, 16
@@ -149,7 +152,7 @@ def test_windowed_kernel_matches_reference_attn(layout, block):
     width = (2 if layout == "fused" else 1) * hkv * dh
     n_pools = 1 if layout == "fused" else 2
     n_phys = 1 + b * ring
-    pools = tuple(jax.random.normal(keys[i], (n_phys, PS, width)
+    pools = tuple(jax.random.normal(keys[i], (3, n_phys, PS, width)
                                     ).astype(jnp.bfloat16)
                   for i in range(n_pools))
     table = jax.random.permutation(keys[2], jnp.arange(1, n_phys)
@@ -160,14 +163,13 @@ def test_windowed_kernel_matches_reference_attn(layout, block):
     kern = lower_paged_decode(batch=b, kv_heads=hkv, group=group,
                               head_dim=dh, page_size=PS, n_pages_max=ring,
                               layout=layout, block=block, window=window)
-    out, new = kern(q, k, v, pools, table, lens)
-    want, want_pools = paged.reference_attn(q, k, v, pools, table, lens,
-                                            layout, PS, window)
+    out, new = kern(q, k, v, pools, table, lens, layer)
+    want, want_pools = paged.reference_attn(
+        q, k, v, tuple(p[layer] for p in pools), table, lens, layout, PS,
+        window)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                rtol=1e-5, atol=1e-5)
-    for a, e in zip(new, want_pools):
-        assert np.array_equal(np.asarray(a, np.float32),
-                              np.asarray(e, np.float32))
+    check_stacked_pools(new, pools, want_pools, layer)
 
 
 def _moe_layer(seed: int, held: int):

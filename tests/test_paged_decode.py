@@ -115,14 +115,29 @@ def test_paged_decode_token_identical_to_oracle(layout):
 
 
 KERNEL_PAGES = 6          # logical pages per request in the kernel tests
+KERNEL_LAYERS = 3         # layers stacked in the kernel tests' pools
+
+
+def check_stacked_pools(new_pools, pools, want_pools, layer):
+    """Layer ``layer`` of the kernel's stacked pools holds the
+    reference's pools after the append, and every other layer is
+    bit-identical to its input: a DMA into the wrong layer fails."""
+    for got, before, ref in zip(new_pools, pools, want_pools):
+        got, before = np.asarray(got, np.float32), np.asarray(before,
+                                                              np.float32)
+        np.testing.assert_array_equal(got[layer],
+                                      np.asarray(ref, np.float32))
+        others = [li for li in range(got.shape[0]) if li != layer]
+        np.testing.assert_array_equal(got[others], before[others])
 
 
 def _check_kernel_against_reference(layout, page_size, block_pages, depth,
-                                    kv_dtype=jnp.bfloat16,
+                                    layer, kv_dtype=jnp.bfloat16,
                                     q_dtype=jnp.bfloat16):
     """The fused kernel (interpret mode) against ``reference_attn``,
     the reference branch of ``_paged_attn``: the same output, and the
-    same pools after the append.  The batch holds a parked slot
+    same pools after the append, in layer ``layer`` of pools stacked
+    over ``KERNEL_LAYERS`` layers.  The batch holds a parked slot
     (length 0, every page 0), lengths at a block boundary, and the
     step's token in the first, a middle and the last block, over a
     shuffled page table, so the requests stream different numbers of
@@ -137,7 +152,8 @@ def _check_kernel_against_reference(layout, page_size, block_pages, depth,
     rng = np.random.RandomState(7)
     n_phys = 1 + b * npm + 2
     width = (2 if layout == "fused" else 1) * hkv * dh
-    pools = tuple(jnp.asarray(rng.randn(n_phys, ps, width), kv_dtype)
+    pools = tuple(jnp.asarray(rng.randn(KERNEL_LAYERS, n_phys, ps, width),
+                              kv_dtype)
                   for _ in range(1 if layout == "fused" else 2))
     table = rng.permutation(np.arange(1, n_phys))[:b * npm].reshape(b, npm)
     table[0] = 0                                    # the parked slot
@@ -151,34 +167,36 @@ def _check_kernel_against_reference(layout, page_size, block_pages, depth,
                               layout=layout, block=block, depth=depth,
                               dtype=kv_dtype)
     assert (kern.block, kern.depth) == (block, depth)
-    out, new_pools = jax.jit(kern)(q, k, v, pools, table, lens)
-    want, want_pools = paged.reference_attn(q, k, v, pools, table, lens,
-                                            layout, ps)
+    out, new_pools = jax.jit(kern)(q, k, v, pools, table, lens,
+                                   jnp.int32(layer))
+    want, want_pools = paged.reference_attn(
+        q, k, v, tuple(p[layer] for p in pools), table, lens, layout, ps)
     # f32 sums of at most 48 terms, in another order
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                rtol=1e-5, atol=1e-5)
-    for got, ref in zip(new_pools, want_pools):
-        np.testing.assert_array_equal(np.asarray(got, np.float32),
-                                      np.asarray(ref, np.float32))
+    check_stacked_pools(new_pools, pools, want_pools, layer)
 
 
+@pytest.mark.parametrize("layer", (0, KERNEL_LAYERS - 1))
 @pytest.mark.parametrize("depth", (2, 3))
 @pytest.mark.parametrize("block_pages", (1, 2, KERNEL_PAGES),
                          ids=("one_page", "two_pages", "whole_context"))
 @pytest.mark.parametrize("page_size", (4, 8))
 @pytest.mark.parametrize("layout", paged.LAYOUTS)
 def test_paged_kernel_matches_reference_attention(layout, page_size,
-                                                  block_pages, depth):
-    _check_kernel_against_reference(layout, page_size, block_pages, depth)
+                                                  block_pages, depth, layer):
+    _check_kernel_against_reference(layout, page_size, block_pages, depth,
+                                    layer)
 
 
+@pytest.mark.parametrize("layer", (0, KERNEL_LAYERS - 1))
 @pytest.mark.parametrize("kv_dtype", ("bfloat16", "float32"))
 @pytest.mark.parametrize("layout", paged.LAYOUTS)
-def test_paged_kernel_keeps_f32_operands_whole(layout, kv_dtype):
+def test_paged_kernel_keeps_f32_operands_whole(layout, kv_dtype, layer):
     """An f32 query meets bf16 K/V as its three exact bf16 terms, and
     f32 pools take the f32 matmul: both agree with the reference."""
-    _check_kernel_against_reference(layout, 4, 2, 2, kv_dtype=kv_dtype,
-                                    q_dtype=jnp.float32)
+    _check_kernel_against_reference(layout, 4, 2, 2, layer,
+                                    kv_dtype=kv_dtype, q_dtype=jnp.float32)
 
 
 # granite-3-2b widths: 8 KV heads of 64, bf16

@@ -8,6 +8,8 @@ a kernel the chip would refuse fails here, at no chip time.  Interpret
 mode is switched off inside each test; the topology is described in a
 fixture and every test skips where it cannot be.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -21,10 +23,11 @@ from repro.patterns.analytics import PIPELINES
 # the chip_smoke.py extents: tpchq6 at TPC-H SF ~11, the others at 2**20
 SIZES = {"tpchq6": 2 ** 26, "gda": 2 ** 20, "kmeans": 2 ** 20,
          "gda_moments": 2 ** 20, "normalize": 2 ** 20}
-# granite-3-2b decode: 8 KV heads of 64, 32 query heads; 4 requests
-# over a 2048-page pool at the kernel's default block, and the serving
-# cells' shapes at the blocks the DSE picks for them
+# granite-3-2b decode: 8 KV heads of 64, 32 query heads, 40 layers; 4
+# requests over a 2048-page pool at the kernel's default block, and the
+# serving cells' shapes at the blocks the DSE picks for them
 GRANITE = dict(kv_heads=8, group=4, head_dim=64)
+GRANITE_LAYERS = 40
 PAGED_SHAPES = {
     "split": dict(layout="split", batch=4, page_size=16, n_pages_max=128,
                   pool_pages=2048),
@@ -98,8 +101,8 @@ def test_fused_dag_compiles_for_v5e(name, one_chip, mosaic):
 def test_paged_decode_compiles_for_v5e(shape, one_chip, mosaic):
     """The paged-decode kernel at granite-3-2b widths, as one Mosaic
     kernel named ``paged_decode``, with its blocks cut to the scoped
-    VMEM: the pool stays in HBM and is updated in place (aliased, no
-    temporary copy of it)."""
+    VMEM: the pools, stacked over every layer, stay in HBM and are
+    updated in place (aliased, no temporary copy of them)."""
     kw = dict(PAGED_SHAPES[shape])
     n_pool_pages = kw.pop("pool_pages")
     layout, b, h, dh = kw["layout"], kw["batch"], GRANITE["kv_heads"], \
@@ -108,18 +111,19 @@ def test_paged_decode_compiles_for_v5e(shape, one_chip, mosaic):
     if "block" in kw:           # the DSE's picks fit as they are
         assert (kern.block, kern.depth) == (kw["block"], kw["depth"])
     heads = (2 if layout == "fused" else 1) * h
-    pool = _spec((n_pool_pages, kw["page_size"], heads * dh),
+    pool = _spec((GRANITE_LAYERS, n_pool_pages, kw["page_size"], heads * dh),
                  jnp.bfloat16, one_chip)
     pools = (pool,) if layout == "fused" else (pool, pool)
-    step = jax.jit(lambda q, k, v, pools, pt, ln: kern(q, k, v, pools,
-                                                         pt, ln),
+    step = jax.jit(lambda q, k, v, pools, pt, ln, ly: kern(q, k, v, pools,
+                                                             pt, ln, ly),
                    donate_argnums=(3,))
     compiled = step.lower(
         _spec((b, h, GRANITE["group"], dh), jnp.bfloat16, one_chip),
         _spec((b, h, dh), jnp.bfloat16, one_chip),
         _spec((b, h, dh), jnp.bfloat16, one_chip), pools,
         _spec((b, kw["n_pages_max"]), jnp.int32, one_chip),
-        _spec((b,), jnp.int32, one_chip)).compile()
+        _spec((b,), jnp.int32, one_chip),
+        _spec((), jnp.int32, one_chip)).compile()
     text = compiled.as_text()
     assert text.count("tpu_custom_call") == 1
     assert "/paged_decode/pallas_call" in text
@@ -127,3 +131,93 @@ def test_paged_decode_compiles_for_v5e(shape, one_chip, mosaic):
     pool_bytes = sum(int(np.prod(p.shape)) * 2 for p in pools)
     assert mem.alias_size_in_bytes == pool_bytes
     assert mem.temp_size_in_bytes < pool_bytes // 100
+
+
+# an instruction's name, result shape and opcode in the compiled HLO
+HLO_OP = re.compile(r"%([\w.-]+) = \w+\[([\d,]*)\]\S* ([\w-]+)\(")
+POOL_MOVES = ("copy", "dynamic-slice", "dynamic-update-slice")
+
+
+def _pool_moves(text, pool_shape):
+    """Copies, slices and update-slices (alone or fused) whose result
+    is a whole pool stack or one layer's pool, unit dimensions aside."""
+    def squeeze(dims):
+        return tuple(d for d in dims if d != 1)
+
+    shapes = {squeeze(pool_shape), squeeze(pool_shape[1:])}
+    moves = []
+    for name, dims, op in HLO_OP.findall(text):
+        if op not in POOL_MOVES and not any(m in name for m in POOL_MOVES):
+            continue
+        if squeeze(int(d) for d in dims.split(",") if d) in shapes:
+            moves.append(name)
+    return moves
+
+
+SERVING_SHAPES = ["split-long-decode", "fused-chat-short"]
+
+
+def _granite_step(shape, sharding, pallas):
+    """The whole granite-3-2b decode step compiled at a serving cell's
+    shapes: with the kernel and the cache donated, as the server runs
+    it, or through the reference returning the logits alone, as its
+    certification runs it.  Returns the compiled step and the bytes of
+    the pools and of one pool stack."""
+    from repro.configs import get_config
+    from repro.models import model, paged
+
+    kw = PAGED_SHAPES[shape]
+    cfg = get_config("granite-3-2b", smoke=False)
+    assert cfg.n_layers == GRANITE_LAYERS
+    cache = jax.eval_shape(lambda: paged.PagedKVCache.init(
+        cfg, kw["batch"], kw["n_pages_max"] * kw["page_size"],
+        page_size=kw["page_size"], layout=kw["layout"]))
+    assert cache.n_pages == kw["pool_pages"]
+
+    def step(p, c, t):
+        logits, c = paged.paged_decode_step(
+            p, cfg, c, t, use_pallas=pallas, block=kw["block"],
+            depth=kw["depth"])
+        return (logits[:, -1], c) if pallas else logits[:, -1]
+
+    def specs(tree):
+        return jax.tree.map(lambda a: _spec(a.shape, a.dtype, sharding),
+                            tree)
+
+    compiled = jax.jit(step, donate_argnums=(1,) if pallas else ()).lower(
+        specs(model.param_specs(cfg)), specs(cache),
+        _spec((kw["batch"], 1), jnp.int32, sharding)).compile()
+    pool_bytes = sum(int(np.prod(b.shape)) * b.dtype.itemsize
+                     for b in cache.buffers)
+    return compiled, pool_bytes, pool_bytes // len(cache.buffers)
+
+
+@pytest.mark.parametrize("shape", SERVING_SHAPES)
+def test_paged_decode_step_keeps_pools_in_place(shape, one_chip, mosaic):
+    """The served step: the layer scan carries the stacked pools and
+    the kernel indexes them by layer, so no pool, stacked or one
+    layer's, is copied, sliced out or written back, and the step's
+    temporaries stay far below the pools."""
+    compiled, pool_bytes, stack = _granite_step(shape, one_chip, True)
+    text = compiled.as_text()
+    assert "/paged_decode/pallas_call" in text
+    kw = PAGED_SHAPES[shape]
+    width = (2 if kw["layout"] == "fused" else 1) * GRANITE["kv_heads"] \
+        * GRANITE["head_dim"]
+    assert _pool_moves(text, (GRANITE_LAYERS, kw["pool_pages"],
+                              kw["page_size"], width)) == []
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool_bytes
+    # what stays is the attention weights' re-layout (0.3-0.5 GB), not
+    # a pool: a scan that moved them needed more than a whole stack
+    assert mem.temp_size_in_bytes < stack // 2
+
+
+@pytest.mark.parametrize("shape", SERVING_SHAPES)
+def test_certify_step_copies_no_pool(shape, one_chip, mosaic):
+    """The certification step reads the served cache and keeps only
+    the logits: it must not copy the pools it leaves undonated (the
+    reference's own temporaries, its dense f32 views of a layer, stay
+    well under a stack)."""
+    compiled, _, stack = _granite_step(shape, one_chip, False)
+    assert compiled.memory_analysis().temp_size_in_bytes < stack
