@@ -510,7 +510,7 @@ def measured():
                                     repeat=repeat, cache=None)
         tables.append((f"kernel/{name}", type(p).__name__,
                        [(t.analytic_seconds, t.steps,
-                         t.measurement.median_s, str(dict(t.sizes)))
+                         t.measurement.median_s, str(dict(t.plan.sizes)))
                         for t in ts], {}))
     for name, builder in PIPELINES.items():
         pipe, _, _ = builder()
@@ -524,8 +524,8 @@ def measured():
         extra = {}
         if ts:
             best_t = min(ts, key=lambda t: t.measurement.median_s)
-            d2 = [t for t in ts if t.depth == 2]
-            if d2 and best_t.depth != 2:
+            d2 = [t for t in ts if t.plan.depth == 2]
+            if d2 and best_t.plan.depth != 2:
                 d2_s = min(t.measurement.median_s for t in d2)
                 extra["measured_d2_vs_best"] = round(
                     (d2_s - best_t.measurement.median_s)
@@ -533,7 +533,7 @@ def measured():
         tables.append((f"pipeline/{name}", "Pipeline",
                        [(t.analytic_seconds, t.steps,
                          t.measurement.median_s,
-                         f"block={t.block},depth={t.depth}")
+                         f"block={t.plan.block},depth={t.plan.depth}")
                         for t in ts], extra))
 
     # rank correlations against the FINAL profile (fitted on exactly

@@ -39,6 +39,7 @@ Enabled per call via ``Options(bucketing=True)`` (or fleet-wide with
 """
 from __future__ import annotations
 
+import dataclasses
 import hashlib
 import math
 import threading
@@ -136,27 +137,16 @@ def pipeline_buckets(pipe) -> Dict[str, Tuple[int, ...]]:
 # ------------------------------------------------------------ bucket index
 
 
-def record_tile(p: ir.Pattern, plan, tc, *, vmem_budget: int,
-                align: int) -> None:
-    """Register ``plan`` as the donor for its bucket (idempotent: an
-    identical existing entry skips the disk write; a newer tuned plan
-    for the same bucket overwrites -- latest wins)."""
-    doms = tile_buckets(p, align=align)
-    if not doms:
-        return
-    fam = tile_family(p, vmem_budget=vmem_budget, align=align)
-    _put(tc, fam, doms, plan, "tile")
-
-
-def record_pipeline(pipe, plan, tc, *, vmem_budget: int,
-                    align: int) -> None:
-    """Register a *fused* pipeline plan as its bucket's donor (split
-    plans are not warm-start donors: their cut structure is priced for
-    one extent and does not transfer)."""
-    if not plan.fused:
-        return
-    fam = pipeline_family(pipe, vmem_budget=vmem_budget, align=align)
-    _put(tc, fam, pipeline_buckets(pipe), plan, "pipeline")
+def record(space, plan, tc) -> None:
+    """Register ``plan``, explored over ``space`` (a ``dse`` search
+    space), as the donor for its bucket (idempotent: an identical
+    existing entry skips the disk write; a newer tuned plan for the
+    same bucket overwrites -- latest wins).  Only a one-kernel plan is
+    a donor: a split pipeline's cut structure is priced for one extent
+    and does not transfer."""
+    doms = space.bucket_domains()
+    if doms and space.one_kernel(plan):
+        _put(tc, space.family(), doms, plan, space.kind)
 
 
 def _put(tc, family: str, doms: Dict[str, Tuple[int, ...]], plan,
@@ -199,87 +189,27 @@ def _nearest(entries: Dict[str, Dict],
 # -------------------------------------------------------------- warm start
 
 
-def warm_start_tile(p: ir.Pattern, tc, *, vmem_budget: int, align: int):
-    """A ``TilePlan`` adapted from the nearest tuned bucket, or None.
+def warm_start(space, tc):
+    """A plan for ``space``'s shape adapted from the nearest tuned
+    bucket, or None.
 
-    The donor's per-domain tile is mapped onto the cold shape's own
-    candidate grid: the largest ``axis_candidates`` divisor <= the
-    donor tile (the ragged tail falls out of the divisor enumeration),
-    at the donor's buffer depth, re-priced analytically.  Zero
-    lowering, zero measurement; the plan is flagged ``warm_start`` and
-    never persisted."""
-    from . import dse
-    want = tile_buckets(p, align=align)
+    The donor's tiles are mapped onto the cold shape's own candidate
+    grid (``space.refit``: the largest ``axis_candidates`` divisor <=
+    the donor tile -- the ragged tail falls out of the divisor
+    enumeration), at the donor's buffer depth, re-priced analytically.
+    Zero lowering, zero measurement; the plan is flagged
+    ``warm_start`` and never persisted."""
+    want = space.bucket_domains()
     if not want:
         return None
-    fam = tile_family(p, vmem_budget=vmem_budget, align=align)
-    entry = _nearest(tc.bucket_entries(fam), want, "tile")
+    entry = _nearest(tc.bucket_entries(space.family()), want, space.kind)
     if entry is None:
         return None
-    donor = dse.TilePlan.from_json(entry["plan"])
-    sizes: Dict[str, Tuple[int, ...]] = {}
-    for q in ir.walk(p):
-        if q.strided or not q.domain or q.name in sizes:
-            continue
-        dt = donor.sizes.get(q.name)
-        if dt is None or len(dt) != len(q.domain):
-            return None
-        sub = dse.dtype_sublane(q.dtype)
-        fitted = []
-        for extent, want_tile in zip(q.domain, dt):
-            cands = dse.axis_candidates(extent, align, sublane=sub)
-            le = [c for c in cands if c <= want_tile]
-            fitted.append(max(le) if le else min(cands))
-        sizes[q.name] = tuple(fitted)
-    priced = dse.price(p, sizes, vmem_budget=vmem_budget,
-                       profile=False, depth=donor.depth)
-    if priced is None:
+    plan = space.refit(space.plan_cls.from_json(entry["plan"]))
+    if plan is None:
         return None
-    return dse.TilePlan(
-        sizes=sizes, depths={k: int(donor.depth) for k in sizes},
-        traffic_words=priced.traffic_words,
-        vmem_bytes=priced.vmem_bytes,
-        modeled_seconds=priced.calibrated_seconds,
-        warm_start=True,
-        bucket=_bucket_sig({k: tuple(v) for k, v
-                            in entry["domains"].items()}))
-
-
-def warm_start_pipeline(pipe, tc, *, vmem_budget: int, align: int,
-                        max_points: int):
-    """A fully fused ``PipelinePlan`` adapted from the nearest tuned
-    bucket (donor block re-fitted to the cold extent's divisors,
-    donor depth kept, re-priced analytically), or None."""
-    from . import dse
-    from . import pipeline as plmod
-    fam = pipeline_family(pipe, vmem_budget=vmem_budget, align=align)
-    entry = _nearest(tc.bucket_entries(fam), pipeline_buckets(pipe),
-                     "pipeline")
-    if entry is None:
-        return None
-    donor = dse.PipelinePlan.from_json(entry["plan"])
-    cands = dse._pipeline_candidates(pipe, align, max_points)
-    le = [c for c in cands if c <= donor.block]
-    b = max(le) if le else min(cands)
-    n_stages = len(plmod.topo_stages(pipe))
-    try:
-        whole = plmod.sub_pipeline(pipe, 0, n_stages)
-    except (ValueError, NotImplementedError):
-        return None
-    # profile=None -> uncalibrated analytic pricing; _price_pipeline_group
-    # takes a pre-resolved profile (unlike dse.price, which resolves)
-    res = dse._price_pipeline_group(
-        whole, b, vmem_budget=vmem_budget, profile=None,
-        counters={"explored": 0, "pruned": 0}, depth=donor.depth)
-    if res is None:
-        return None
-    words, vmem, _s_ana, s_cal, _steps = res
-    return dse.PipelinePlan(
-        block=int(b), groups=((0, n_stages),), group_blocks=(int(b),),
-        depths=(int(donor.depth),), traffic_words=int(words),
-        unfused_traffic_words=plmod.unfused_traffic_words(pipe),
-        vmem_bytes=int(vmem), modeled_seconds=float(s_cal),
-        warm_start=True,
+    return dataclasses.replace(
+        plan, warm_start=True,
         bucket=_bucket_sig({k: tuple(v) for k, v
                             in entry["domains"].items()}))
 

@@ -45,13 +45,15 @@ kernel shares one exploration engine and one tuning cache.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import hashlib
 import itertools
 import os
 from typing import Dict, List, Optional, Tuple, Union
 
 
-from . import calibrate, ir, resilience, telemetry
+from . import buckets, calibrate, ir, resilience, telemetry
+from . import pipeline as plmod
 from . import measure as measure_mod
 from .cost import HBM_BYTES_PER_S, VMEM_BYTES, stream_seconds, traffic
 from .memory import plan_memory
@@ -85,17 +87,6 @@ def dtype_sublane(dtype) -> int:
 # v5 VMEM accounting in unpadded words) must not be replayed as cache
 # hits.  CI keys its persistent REPRO_DSE_CACHE on this string too.
 MODEL_VERSION = 6
-
-
-def _measure_mode(measure: Optional[str]) -> Optional[str]:
-    """Validate a resolved ``measure`` value.  The ``REPRO_MEASURE``
-    env opt-in is no longer consulted here: ``Options.from_env`` is the
-    single env reader, merged by ``_resolve_options``."""
-    if measure in (None, False, ""):
-        return None
-    if measure != "top_k":
-        raise ValueError(f"measure={measure!r}; supported: None, 'top_k'")
-    return measure
 
 
 # legacy kwargs whose ``None`` default means "unset" (merged below
@@ -576,15 +567,9 @@ def price(p: ir.Pattern, sizes: Dict[str, Tuple[int, ...]], *,
                   calibrated, steps, depth=depth)
 
 
-def _better(a: Priced, b: Optional[Priced]) -> bool:
+def _rank_key(a: Priced) -> Tuple:
     """Lexicographic: traffic, then (calibrated) modeled time, then
     shallowest depth, then prefer reuse."""
-    if b is None:
-        return True
-    return _rank_key(a) < _rank_key(b)
-
-
-def _rank_key(a: Priced) -> Tuple:
     # depth breaks seconds ties BEFORE the -vmem reuse term: once the
     # exposed-latency term saturates, deeper variants tie on seconds
     # and their larger footprint must not win via the reuse preference
@@ -639,52 +624,47 @@ def shortlist(p: ir.Pattern, *,
 
 @dataclasses.dataclass(frozen=True)
 class CandidateTiming:
-    """One shortlisted candidate, actually lowered and timed."""
+    """One shortlisted candidate, actually lowered and timed.  ``plan``
+    is the candidate as the plan it would ship as: a ``TilePlan``, or a
+    fully fused one-group ``PipelinePlan``."""
 
-    sizes: Dict[str, Tuple[int, ...]]
-    traffic_words: int
-    vmem_bytes: int
+    plan: Union[TilePlan, "PipelinePlan"]
     analytic_seconds: float      # uncalibrated model prediction
-    calibrated_seconds: float    # profile-adjusted model prediction
     steps: int
     measurement: measure_mod.Measurement
     lowering: str                # "pallas" | "oracle" | "cached"
-    depth: int = 2               # metapipeline buffer depth
+
+    @property
+    def calibrated_seconds(self) -> float:
+        """The profile-adjusted model prediction."""
+        return self.plan.modeled_seconds
 
 
-def _workload_tag(p: ir.Pattern) -> str:
-    shapes = "+".join(f"{t.name}:{'x'.join(map(str, t.shape))}"
-                      for t in ir.inputs_of(p))
-    return f"{type(p).__name__}:{p.name}:{shapes}"
-
-
-def _top_distinct_sizes(cands: List[Priced], k: int) -> List[Priced]:
-    """Best-first prefix of ``cands`` with at most one entry per tile
-    assignment.  Depth variants of one tile execute identically under
-    the single-pattern templates (the Mosaic pipeliner owns the
-    BlockSpec buffering), so timing them separately would spend the
-    whole top-k on copies of a single measurement; keeping the best-
-    ranked depth per sizes times ``k`` genuinely distinct kernels."""
-    out: List[Priced] = []
+def _timing_shortlist(space, ranked: List, k: int) -> List[Tuple]:
+    """Best-first prefix of the analytic search's ``ranked`` candidates
+    with at most ``k`` entries and one per timing identity
+    (``space.ident``), as ``(plan, analytic seconds, grid steps)``."""
+    out: List[Tuple] = []
     seen = set()
-    for c in cands:
-        sig = tuple(sorted((n, tuple(v)) for n, v in c.sizes.items()))
-        if sig in seen:
+    for c in ranked:
+        point = space.point(c)
+        ident = space.ident(point[0])
+        if ident in seen:
             continue
-        seen.add(sig)
-        out.append(c)
+        seen.add(ident)
+        out.append(point)
         if len(out) >= k:
             break
     return out
 
 
-def _time_candidates(p: ir.Pattern, top: List[Priced], *,
-                     vmem_budget: int, align: int,
+def _time_candidates(space, points: List[Tuple], *,
                      timing_db, warmup: int, repeat: int,
                      policy: Optional[resilience.Policy] = None,
-                     cache: Optional[TuningCache] = None
-                     ) -> List[CandidateTiming]:
-    """Lower + time shortlisted candidates (timing-DB memoized).
+                     cache: Optional[TuningCache] = None,
+                     observe: bool = True) -> List[CandidateTiming]:
+    """Lower + time shortlisted candidates (timing-DB memoized), and
+    with ``observe`` fold the samples into the calibration profile.
 
     Each lower+time runs under the resilience policy's deadline with
     transient retry; an expected failure (no template, numeric blowup,
@@ -693,21 +673,13 @@ def _time_candidates(p: ir.Pattern, top: List[Priced], *,
     so no later exploration re-attempts the same crash.  Unexpected
     exceptions still propagate: a real bug must surface.
     """
-    from .codegen_pallas import lower_for_timing
-
     pol = resilience.resolve_policy(policy)
     out: List[CandidateTiming] = []
-    for cand in top:
-        sizes_sig = tuple(sorted((k, tuple(v))
-                                 for k, v in cand.sizes.items()))
+    for plan, analytic_s, steps in points:
         # identifies the computation, not its pricing: no device /
-        # profile-hash component (TimingDB adds the device itself).
-        # Depth is deliberately absent: single-pattern lowerings
-        # delegate buffering to the Pallas pipeliner, so every depth
-        # variant of one tile assignment is the same executable.
-        key = pattern_key(p, vmem_budget=vmem_budget, align=align,
-                          extra=("timing", sizes_sig),
-                          device="", profile_hash="")
+        # profile-hash component (TimingDB adds the device itself)
+        key = space.key(("timing",) + space.ident(plan), device="",
+                        profile_hash="")
         qkey = "time|" + measure_mod.TimingDB.full_key(key)
         if cache is not None:
             q = cache.quarantined(qkey)
@@ -718,9 +690,8 @@ def _time_candidates(p: ir.Pattern, top: List[Priced], *,
                 continue
         how = ["cached"]
 
-        def make_fn(sizes=cand.sizes, how=how):
-            fn, how[0] = lower_for_timing(p, sizes,
-                                          vmem_budget=vmem_budget)
+        def make_fn(plan=plan, how=how):
+            fn, how[0] = space.lower(plan)
             return fn
 
         try:
@@ -734,47 +705,37 @@ def _time_candidates(p: ir.Pattern, top: List[Priced], *,
             if cache is not None:
                 cache.quarantine(qkey, e.kind, e.detail)
             continue
-        out.append(CandidateTiming(
-            sizes=dict(cand.sizes), traffic_words=cand.traffic_words,
-            vmem_bytes=cand.vmem_bytes,
-            analytic_seconds=cand.modeled_seconds,
-            calibrated_seconds=cand.calibrated_seconds,
-            steps=cand.steps, measurement=m, lowering=how[0],
-            depth=cand.depth))
+        out.append(CandidateTiming(plan, analytic_s, steps, m, how[0]))
+    if observe:
+        _observe(space, out)
     return out
 
 
-def _accuracy_gauges(kind: str, pairs: List[Tuple[float, float]]) -> None:
-    """Model-accuracy gauges per pattern family, from one measured
-    shortlist's (calibrated prediction, measured median) pairs:
+def _observe(space, timings: List[CandidateTiming]) -> None:
+    """Fold one measured shortlist into the device calibration profile,
+    and set the model-accuracy gauges of its pattern family from the
+    (calibrated prediction, measured median) pairs:
     ``model.drift.<kind>`` the mean relative |predicted - measured| /
     measured, ``model.spearman.<kind>`` the rank correlation of the
     analytic ordering against the measured one.  Always-on (gauges are
     cheap scalars): ``benchmarks/check_regression.py`` prints them next
     to the gate output without needing ``REPRO_TRACE``."""
-    if not pairs:
+    if not timings:
         return
-    drift = sum(abs(p - m) / max(m, 1e-12) for p, m in pairs) / len(pairs)
-    telemetry.gauge(f"model.drift.{kind}", drift)
-    if len(pairs) >= 2:
-        telemetry.gauge(f"model.spearman.{kind}",
-                        measure_mod.spearman([p for p, _ in pairs],
-                                             [m for _, m in pairs]))
-
-
-def _observe(p_kind: str, workload: str,
-             timings: List[CandidateTiming]) -> None:
-    samples = [calibrate.Sample(
-        workload=workload, kind=p_kind,
+    calibrate.observe([calibrate.Sample(
+        workload=space.workload, kind=space.calib_kind,
         stream_bytes=t.analytic_seconds * HBM_BYTES_PER_S,
         steps=t.steps, measured_s=t.measurement.median_s,
-        key=f"{workload}|{sorted(t.sizes.items())}")
-        for t in timings]
-    if samples:
-        calibrate.observe(samples)
-    _accuracy_gauges(p_kind, [(t.calibrated_seconds,
-                               t.measurement.median_s)
-                              for t in timings])
+        key=f"{space.workload}|{space.sample_id(t.plan)}")
+        for t in timings])
+    pairs = [(t.calibrated_seconds, t.measurement.median_s)
+             for t in timings]
+    drift = sum(abs(p - m) / max(m, 1e-12) for p, m in pairs) / len(pairs)
+    telemetry.gauge(f"model.drift.{space.calib_kind}", drift)
+    if len(pairs) >= 2:
+        telemetry.gauge(f"model.spearman.{space.calib_kind}",
+                        measure_mod.spearman([p for p, _ in pairs],
+                                             [m for _, m in pairs]))
 
 
 def _record_plan(plan, *, source: str, **extra) -> None:
@@ -816,18 +777,16 @@ def measured_shortlist(p: ir.Pattern, *,
     retry; ``cache`` (default off for the library call) enables the
     persistent candidate quarantine shared with ``explore``.
     """
+    s = _TileSpace(p, space, Options(vmem_budget=vmem_budget, align=align,
+                                     max_points=max_points).resolved())
     cands, _, _, _ = shortlist(p, vmem_budget=vmem_budget, align=align,
-                               space=space, max_points=max_points,
+                               space=s.space, max_points=max_points,
                                profile=profile)
-    timings = _time_candidates(p, _top_distinct_sizes(cands,
-                                                      max(top_k, 1)),
-                               vmem_budget=vmem_budget, align=align,
-                               timing_db=timing_db, warmup=warmup,
-                               repeat=repeat, policy=policy,
-                               cache=_resolve_cache(cache))
-    if calibrate_update:
-        _observe(type(p).__name__, _workload_tag(p), timings)
-    return timings
+    return _time_candidates(s, _timing_shortlist(s, cands, max(top_k, 1)),
+                            timing_db=timing_db, warmup=warmup,
+                            repeat=repeat, policy=policy,
+                            cache=_resolve_cache(cache),
+                            observe=calibrate_update)
 
 
 def explore(p: ir.Pattern, *,
@@ -901,211 +860,294 @@ def explore(p: ir.Pattern, *,
         telemetry.enable()
     with telemetry.span("dse.explore", kind=type(p).__name__,
                         pattern=p.name) as sp:
-        return _explore_body(p, space, o, sp)
+        return _explore(_TileSpace(p, space, o), o, sp)
 
 
-def _explore_body(p: ir.Pattern, space, o: Options, sp) -> TilePlan:
-    vmem_budget, align = o.vmem_budget, o.align
-    max_points, measure, top_k = o.max_points, o.measure, o.top_k
-    timing_db, profile = o.timing_db, o.profile
-    warmup, repeat, depths, policy = (o.warmup, o.repeat, o.depths,
-                                      o.policy)
+# --------------------------------------------------------------------------
+# The exploration engine: one skeleton over two search spaces
+# --------------------------------------------------------------------------
+
+
+def _explore(space, o: Options, sp):
+    """One exploration over ``space`` (a ``_TileSpace`` or a
+    ``_PipelineSpace``, which hold everything the two engines do
+    differently): tuning-cache lookup; bucketed warm start with a
+    background re-tune; the analytic search; in measured mode the top-k
+    timed and the fastest *certified* one promoted; then the plan is
+    cached under its post-calibration key, registered as its bucket's
+    donor and its provenance recorded.  ``sp`` is the caller's span."""
     tc = _resolve_cache(o.cache)
-
-    space_was_default = space is None
-    if space is None:
-        space = tile_space(p, align=align)
-    space, thinned = _thin(space, max_points)
-    names = sorted(space)
-
-    # the key covers the *resolved* candidate space: a caller-restricted
-    # or thinned exploration must not share cache entries with a full
-    # one, nor a measured exploration with a purely analytic one, nor
-    # a depth-restricted exploration with the default-depths one
-    space_sig = tuple((n, tuple(space[n])) for n in names)
-    extra = space_sig + (("depths",) + tuple(int(d) for d in depths),) \
-        + ((("measure", measure, int(top_k)),) if measure else ())
-
-    def key_now() -> str:
-        return pattern_key(p, vmem_budget=vmem_budget, align=align,
-                           extra=extra)
-
-    # explicit ``space=`` pins the candidate set to the caller's shape:
-    # a donor bucket's plan would not be comparable, so bucketing only
-    # engages for the default space
-    bucketing_on = o.bucketing and tc is not None and space_was_default
-    if bucketing_on:
-        from . import buckets as buckets_mod
-
+    bucketing_on = o.bucketing and tc is not None and space.bucketable
+    key = space.key()
     if tc is not None:
-        hit = tc.get(key_now())
+        hit = tc.get(key, space.plan_cls)
         if hit is not None:
             if bucketing_on:
-                buckets_mod.note("exact_hits")
+                buckets.note("exact_hits")
             telemetry.count("dse.cache_hits")
-            hit = dataclasses.replace(hit, key=key_now())
+            hit = dataclasses.replace(hit, key=key)
             sp.set(source="cache")
             _record_plan(hit, source="cache")
             return hit
 
     if bucketing_on:
-        warm = buckets_mod.warm_start_tile(p, tc, vmem_budget=vmem_budget,
-                                           align=align)
+        warm = buckets.warm_start(space, tc)
         if warm is not None:
-            buckets_mod.note("warm_hits")
-            pol = resilience.resolve_policy(policy)
+            buckets.note("warm_hits")
+            pol = resilience.resolve_policy(o.policy)
             # cache=False: the re-tune must not write the cache itself
             # -- only its *certified* winner is promoted, below
             retune_opts = dataclasses.replace(o, bucketing=False,
                                               cache=False)
-            tag = "tile|" + key_now()
+            tag = f"{space.tag}|{key}"
 
-            def _retune() -> TilePlan:
-                return explore(p, options=retune_opts)
-
-            def _certify(plan: TilePlan):
-                return resilience.certify_guarded(
-                    lambda: resilience.certify_tile_plan(
-                        p, plan.sizes, vmem_budget=vmem_budget),
-                    key="retune|" + tag, policy=pol)
-
-            def _promote(plan: TilePlan) -> None:
+            def _promote(plan) -> None:
                 # key recomputed at promotion time: the background
                 # explore may have refreshed the calibration profile
-                tc.put(key_now(), plan)
-                buckets_mod.record_tile(p, plan, tc,
-                                        vmem_budget=vmem_budget,
-                                        align=align)
+                tc.put(space.key(), plan)
+                buckets.record(space, plan, tc)
 
-            buckets_mod.schedule_retune(tag, _retune, certify=_certify,
-                                        promote=_promote, policy=pol)
-            warm = dataclasses.replace(warm, key=key_now())
+            buckets.schedule_retune(
+                tag, lambda: space.retune(retune_opts),
+                certify=lambda plan: resilience.certify_guarded(
+                    lambda: space.certify(plan), key="retune|" + tag,
+                    policy=pol),
+                promote=_promote, policy=pol)
+            warm = dataclasses.replace(warm, key=key)
             sp.set(source="warm_start", bucket=warm.bucket)
             _record_plan(warm, source="warm_start", bucket=warm.bucket,
                          retune_tag=tag)
             return warm
-        buckets_mod.note("misses")
+        buckets.note("misses")
 
-    # space already thinned above: keep the outer flag (re-thinning an
-    # already-thinned space is a no-op and would report False)
-    with telemetry.span("dse.shortlist", thinned=thinned) as ssp:
-        cands, _, explored, pruned = shortlist(
-            p, vmem_budget=vmem_budget, align=align, space=space,
-            max_points=max_points, profile=profile, depths=depths)
-        ssp.set(explored=explored, pruned=pruned, feasible=len(cands))
-    if not cands:
-        raise ValueError(
-            f"DSE: no tile candidate fits VMEM budget {vmem_budget} B "
-            f"({explored} candidates over {names})")
-
-    measured_s = 0.0
-    timed_n = 0
-    best = cands[0]
+    plan, ranked = space.search()
     prov_measured: List[Dict] = []
     prov_cert: List[Dict] = []
     n_short = n_timed = 0
-    if measure == "top_k":
-        pol = resilience.resolve_policy(policy)
-        with telemetry.span("dse.measure", top_k=int(top_k)) as msp:
-            top = _top_distinct_sizes(cands, max(top_k, 1))
+    if o.measure == "top_k" and space.one_kernel(plan):
+        pol = resilience.resolve_policy(o.policy)
+        with telemetry.span("dse.measure", top_k=int(o.top_k)) as msp:
+            top = _timing_shortlist(space, ranked, max(o.top_k, 1))
             n_short = len(top)
-            timings = _time_candidates(p, top, vmem_budget=vmem_budget,
-                                       align=align, timing_db=timing_db,
-                                       warmup=warmup, repeat=repeat,
+            timings = _time_candidates(space, top, timing_db=o.timing_db,
+                                       warmup=o.warmup, repeat=o.repeat,
                                        policy=pol, cache=tc)
-            _observe(type(p).__name__, _workload_tag(p), timings)
-            ranked = sorted(timings,
-                            key=lambda t: (t.measurement.median_s,
-                                           t.traffic_words, t.depth,
-                                           -t.vmem_bytes))
-            prov_measured = [
-                {"sizes": {k: list(v) for k, v in t.sizes.items()},
-                 "depth": int(t.depth),
-                 "median_s": float(t.measurement.median_s),
-                 "lowering": t.lowering} for t in ranked]
             n_timed = len(timings)
             msp.set(shortlisted=n_short, timed=n_timed)
-            for win in ranked:
+            by_time = sorted(timings,
+                             key=lambda t: (t.measurement.median_s,
+                                            t.plan.traffic_words,
+                                            t.plan.depth,
+                                            -t.plan.vmem_bytes))
+            prov_measured = [space.timing_row(t) for t in by_time]
+            for win in by_time:
                 if pol.certify:
-                    sig = tuple(sorted((k, tuple(v))
-                                       for k, v in win.sizes.items()))
                     ckey = "certify|" + measure_mod.TimingDB.full_key(
-                        pattern_key(p, vmem_budget=vmem_budget,
-                                    align=align, extra=("certify", sig),
-                                    device="", profile_hash=""))
+                        space.key(("certify",) + space.ident(win.plan),
+                                  device="", profile_hash=""))
+                    row = space.row(win.plan)
                     if tc is not None \
                             and tc.quarantined(ckey) is not None:
                         # failed certification in a past run
-                        prov_cert.append(
-                            {"sizes": {k: list(v)
-                                       for k, v in win.sizes.items()},
-                             "ok": False, "reason": "quarantined"})
+                        prov_cert.append({**row, "ok": False,
+                                          "reason": "quarantined"})
                         continue
                     ok, reason = resilience.certify_guarded(
-                        lambda w=win: resilience.certify_tile_plan(
-                            p, w.sizes, vmem_budget=vmem_budget),
+                        lambda w=win: space.certify(w.plan),
                         key=ckey, policy=pol)
-                    prov_cert.append(
-                        {"sizes": {k: list(v)
-                                   for k, v in win.sizes.items()},
-                         "ok": bool(ok), "reason": reason})
+                    prov_cert.append({**row, "ok": bool(ok),
+                                      "reason": reason})
                     if not ok:
                         resilience.record("certify", "certify-failed",
                                           ckey, "quarantined", reason)
                         if tc is not None:
                             tc.quarantine(ckey, "certify-failed", reason)
                         continue
-                best = Priced(win.sizes, win.traffic_words,
-                              win.vmem_bytes, win.analytic_seconds,
-                              win.calibrated_seconds, win.steps,
-                              depth=win.depth)
-                measured_s = win.measurement.median_s
-                timed_n = len(timings)
+                plan = dataclasses.replace(
+                    win.plan, measured=True,
+                    measured_seconds=win.measurement.median_s,
+                    timed=n_timed)
                 break
             else:
                 # every shortlisted candidate failed timing or
-                # certification: the analytic argmin ships, uncertified
+                # certification: the analytic plan ships, uncertified
                 # measured data never does
                 resilience.record(
-                    "explore", "no-measured-winner", _workload_tag(p),
+                    "explore", "no-measured-winner", space.workload,
                     "fallback",
-                    f"{len(timings)} timed, 0 certified; analytic "
-                    "argmin promoted instead")
+                    f"{n_timed} timed, 0 certified; analytic plan "
+                    "promoted instead")
 
     # key recomputed AFTER the calibration update: the next call
     # prices under the new profile hash and must hit this entry
-    final_key = key_now()
-    plan = TilePlan(sizes={k: tuple(v) for k, v in best.sizes.items()},
-                    depths={k: int(best.depth) for k in best.sizes},
-                    traffic_words=best.traffic_words,
-                    vmem_bytes=best.vmem_bytes,
-                    modeled_seconds=best.calibrated_seconds,
-                    explored=explored, pruned=pruned, thinned=thinned,
-                    measured=timed_n > 0, measured_seconds=measured_s,
-                    timed=timed_n, key=final_key)
+    plan = dataclasses.replace(plan, key=space.key())
     if tc is not None:
-        tc.put(final_key, plan)
+        tc.put(plan.key, plan)
         if bucketing_on:
-            buckets_mod.record_tile(p, plan, tc, vmem_budget=vmem_budget,
-                                    align=align)
-    sp.set(source="explored", explored=explored, pruned=pruned,
-           timed=timed_n)
+            buckets.record(space, plan, tc)
+    sp.set(source="explored", explored=plan.explored, pruned=plan.pruned,
+           **space.span_attrs(plan), timed=plan.timed)
     _record_plan(
         plan, source="explored",
-        enumerated=explored,
-        pruned={"vmem": pruned,
-                "dominated": (max(len(cands) - n_short, 0)
-                              if measure == "top_k" else 0),
-                "measure_failures": max(n_short - n_timed, 0)},
+        enumerated=plan.explored,
+        pruned={"vmem": plan.pruned,
+                **space.pruned_reasons(len(ranked), n_short, n_timed,
+                                       plan)},
         analytic_ranks=[
-            {"sizes": {k: list(v) for k, v in c.sizes.items()},
-             "depth": int(c.depth),
+            {**space.row(c), "depth": int(c.depth),
              "traffic_words": int(c.traffic_words),
-             "calibrated_seconds": float(c.calibrated_seconds)}
-            for c in cands[:max(int(top_k), 3)]],
+             "calibrated_seconds": float(c.modeled_seconds)}
+            for c, _, _ in map(space.point, ranked[:max(int(o.top_k), 3)])],
         measured_ranks=prov_measured,
         certification=prov_cert)
     return plan
+
+
+def _search_extra(o: Options) -> Tuple:
+    """Key components both spaces add after their candidate set: a
+    depth-restricted exploration must not share cache entries with the
+    default-depths one, nor a measured exploration with a purely
+    analytic one."""
+    return ((("depths",) + tuple(int(d) for d in o.depths),)
+            + ((("measure", o.measure, int(o.top_k)),) if o.measure
+               else ()))
+
+
+def _fit(cands: List[int], want: int) -> int:
+    """The largest candidate <= ``want`` (else the smallest): a donor
+    bucket's tile re-fitted onto a cold shape's own candidate grid."""
+    le = [c for c in cands if c <= want]
+    return max(le) if le else min(cands)
+
+
+class _TileSpace:
+    """The pattern engine's search space: a tile per named domain axis,
+    crossed with the buffer depths and ranked by ``_rank_key``.  Every
+    plan is one kernel."""
+
+    kind = tag = "tile"      # bucket-index kind, re-tune tag prefix
+    plan_cls = TilePlan
+
+    def __init__(self, p: ir.Pattern, space, o: Options):
+        self.p, self.o = p, o
+        # explicit ``space=`` pins the candidate set to the caller's
+        # shape: a donor bucket's plan would not be comparable, so
+        # bucketing only engages for the default space
+        self.bucketable = space is None
+        self.space, self.thinned = _thin(
+            tile_space(p, align=o.align) if space is None else space,
+            o.max_points)
+        # the key covers the *resolved* candidate space: a caller-
+        # restricted or thinned exploration must not share cache
+        # entries with a full one
+        self.extra = tuple((n, tuple(self.space[n]))
+                           for n in sorted(self.space)) + _search_extra(o)
+        self.counts: Dict = {}   # the search's accounting, for its plans
+        self.calib_kind = type(p).__name__
+        shapes = "+".join(f"{t.name}:{'x'.join(map(str, t.shape))}"
+                          for t in ir.inputs_of(p))
+        self.workload = f"{self.calib_kind}:{p.name}:{shapes}"
+
+    def key(self, extra: Optional[Tuple] = None, **ctx) -> str:
+        return pattern_key(self.p, vmem_budget=self.o.vmem_budget,
+                           align=self.o.align,
+                           extra=self.extra if extra is None else extra,
+                           **ctx)
+
+    def search(self) -> Tuple[TilePlan, List[Priced]]:
+        o = self.o
+        # the space was thinned in __init__: keep that flag (re-thinning
+        # an already-thinned space is a no-op and would report False)
+        with telemetry.span("dse.shortlist", thinned=self.thinned) as ssp:
+            cands, _, explored, pruned = shortlist(
+                self.p, vmem_budget=o.vmem_budget, align=o.align,
+                space=self.space, max_points=o.max_points,
+                profile=o.profile, depths=o.depths)
+            ssp.set(explored=explored, pruned=pruned, feasible=len(cands))
+        if not cands:
+            raise ValueError(
+                f"DSE: no tile candidate fits VMEM budget {o.vmem_budget} "
+                f"B ({explored} candidates over {sorted(self.space)})")
+        self.counts = {"explored": explored, "pruned": pruned,
+                       "thinned": self.thinned}
+        return self.point(cands[0])[0], cands
+
+    def point(self, c: Priced) -> Tuple[TilePlan, float, int]:
+        """A ranked candidate as (plan, analytic seconds, grid steps)."""
+        return TilePlan(sizes={k: tuple(v) for k, v in c.sizes.items()},
+                        depths={k: int(c.depth) for k in c.sizes},
+                        traffic_words=c.traffic_words,
+                        vmem_bytes=c.vmem_bytes,
+                        modeled_seconds=c.calibrated_seconds,
+                        **self.counts), c.modeled_seconds, c.steps
+
+    def one_kernel(self, plan: TilePlan) -> bool:
+        return True
+
+    def ident(self, plan: TilePlan) -> Tuple:
+        """Timing and certification identity: the tile sizes, without
+        the depth.  Depth variants of one tile execute identically under
+        the single-pattern templates (the Mosaic pipeliner owns the
+        BlockSpec buffering), so timing them separately would spend the
+        whole top-k on copies of a single measurement."""
+        return (tuple(sorted((k, tuple(v))
+                             for k, v in plan.sizes.items())),)
+
+    def row(self, plan: TilePlan) -> Dict:
+        return {"sizes": {k: list(v) for k, v in plan.sizes.items()}}
+
+    def timing_row(self, t: CandidateTiming) -> Dict:
+        return {**self.row(t.plan), "depth": int(t.plan.depth),
+                "median_s": float(t.measurement.median_s),
+                "lowering": t.lowering}
+
+    def sample_id(self, plan: TilePlan) -> str:
+        return str(sorted(plan.sizes.items()))
+
+    def lower(self, plan: TilePlan):
+        from .codegen_pallas import lower_for_timing
+        return lower_for_timing(self.p, plan.sizes,
+                                vmem_budget=self.o.vmem_budget)
+
+    def certify(self, plan: TilePlan) -> Tuple[bool, str]:
+        return resilience.certify_tile_plan(self.p, plan.sizes,
+                                            vmem_budget=self.o.vmem_budget)
+
+    def retune(self, options: Options) -> TilePlan:
+        return explore(self.p, options=options)
+
+    def span_attrs(self, plan: TilePlan) -> Dict:
+        return {}
+
+    def pruned_reasons(self, n_ranked: int, n_short: int, n_timed: int,
+                       plan: TilePlan) -> Dict:
+        return {"dominated": max(n_ranked - n_short, 0) if n_short else 0,
+                "measure_failures": max(n_short - n_timed, 0)}
+
+    def family(self) -> str:
+        return buckets.tile_family(self.p, vmem_budget=self.o.vmem_budget,
+                                   align=self.o.align)
+
+    def bucket_domains(self) -> Dict[str, Tuple[int, ...]]:
+        return buckets.tile_buckets(self.p, align=self.o.align)
+
+    def refit(self, donor: TilePlan) -> Optional[TilePlan]:
+        """``donor``'s tiles re-fitted onto this shape's candidate grid
+        at the donor's depth, priced uncalibrated (None: no fit)."""
+        sizes: Dict[str, Tuple[int, ...]] = {}
+        for q in ir.walk(self.p):
+            if q.strided or not q.domain or q.name in sizes:
+                continue
+            dt = donor.sizes.get(q.name)
+            if dt is None or len(dt) != len(q.domain):
+                return None
+            sub = dtype_sublane(q.dtype)
+            sizes[q.name] = tuple(
+                _fit(axis_candidates(extent, self.o.align, sublane=sub), t)
+                for extent, t in zip(q.domain, dt))
+        priced = price(self.p, sizes, vmem_budget=self.o.vmem_budget,
+                       profile=False, depth=donor.depth)
+        return None if priced is None else self.point(priced)[0]
 
 
 # --------------------------------------------------------------------------
@@ -1226,8 +1268,6 @@ def pipeline_key(pipe, *, vmem_budget: int = VMEM_BYTES,
     Any stage or wiring change invalidates the cached joint plan;
     reordering the declaration of independent stages does not (the DAG
     is the same program)."""
-    from . import pipeline as plmod  # local import: keep layering thin
-
     device, profile_hash = _key_context(device, profile_hash)
     parts = []
     for s in plmod.topo_stages(pipe):
@@ -1246,14 +1286,10 @@ def pipeline_key(pipe, *, vmem_budget: int = VMEM_BYTES,
 
 
 def _pipeline_candidates(pipe, align: int, max_points: int) -> List[int]:
-    from . import pipeline as plmod  # local import: keep layering thin
-
     sub = max(dtype_sublane(s.dtype) for s in plmod.topo_stages(pipe))
-    cands = axis_candidates(pipe.shared_extent, align, sublane=sub)
-    while len(cands) > max_points and len(cands) > 2:
-        cands = (cands[::2] if cands[-1] == cands[::2][-1]
-                 else cands[::2] + [cands[-1]])
-    return cands
+    space, _ = _thin({"extent": axis_candidates(pipe.shared_extent, align,
+                                                sublane=sub)}, max_points)
+    return space["extent"]
 
 
 def _price_pipeline_group(sub_pipe, b: int, *, vmem_budget: int,
@@ -1262,8 +1298,6 @@ def _price_pipeline_group(sub_pipe, b: int, *, vmem_budget: int,
     """Price the sub-pipeline fused at tile ``b`` with stage-buffer
     ``depth``: returns ``(hbm_words, vmem_bytes, analytic_s,
     calibrated_s, steps)`` or None when it busts VMEM / cannot fuse."""
-    from . import pipeline as plmod  # local import: keep layering thin
-
     budget_words = max(vmem_budget // 4, 1)
     try:
         fdag = plmod.fuse_dag(sub_pipe, b, vmem_budget_words=budget_words)
@@ -1304,98 +1338,6 @@ def _price_pipeline_group(sub_pipe, b: int, *, vmem_budget: int,
     return (reads + out_w, mem.total_bytes, seconds, calibrated, steps)
 
 
-@dataclasses.dataclass(frozen=True)
-class PipelineTiming:
-    """One shortlisted fused-pipeline candidate, lowered + timed."""
-
-    block: int
-    traffic_words: int
-    vmem_bytes: int
-    analytic_seconds: float
-    calibrated_seconds: float
-    steps: int
-    measurement: measure_mod.Measurement
-    plan: "PipelinePlan"
-    depth: int = 2               # stage-buffer depth of the megakernel
-
-
-def _time_pipeline_candidates(pipe, priced: List[Tuple], *,
-                              vmem_budget: int, align: int,
-                              timing_db, warmup: int, repeat: int,
-                              policy: Optional[resilience.Policy] = None,
-                              cache: Optional[TuningCache] = None
-                              ) -> List[PipelineTiming]:
-    """Lower + time whole fused-pipeline candidates (each a fully fused
-    single-group ``PipelinePlan`` at one (block, depth) point).  Unlike
-    the single-pattern path, depth IS part of the timing key: the
-    megakernel's rotating stage scratch is allocated depth-deep, so
-    depth variants are genuinely different executables.  Same failure
-    discipline as ``_time_candidates``: deadline + retry + quarantine,
-    never a crash of the exploration."""
-    from . import pipeline as plmod
-    from .codegen_pallas import lower_pipeline_for_timing
-
-    n_stages = len(plmod.topo_stages(pipe))
-    unfused = plmod.unfused_traffic_words(pipe)
-    pol = resilience.resolve_policy(policy)
-    out: List[PipelineTiming] = []
-    for (b, d), (words, vmem, s_ana, s_cal, steps) in priced:
-        variant = PipelinePlan(
-            block=int(b), groups=((0, n_stages),),
-            group_blocks=(int(b),), depths=(int(d),),
-            traffic_words=int(words),
-            unfused_traffic_words=unfused, vmem_bytes=int(vmem),
-            modeled_seconds=float(s_cal))
-        key = pipeline_key(pipe, vmem_budget=vmem_budget, align=align,
-                           extra=("timing", int(b), int(d)),
-                           device="", profile_hash="")
-        qkey = "time|" + measure_mod.TimingDB.full_key(key)
-        if cache is not None:
-            q = cache.quarantined(qkey)
-            if q is not None:
-                resilience.record_once(
-                    "time", q.get("kind", "unknown"), qkey, "skipped",
-                    "previously quarantined candidate not re-attempted")
-                continue
-
-        def make_fn(variant=variant):
-            return lower_pipeline_for_timing(pipe, variant,
-                                             vmem_budget=vmem_budget)
-
-        try:
-            m = resilience.call_guarded(
-                lambda: measure_mod.timed(key, make_fn, db=timing_db,
-                                          warmup=warmup, repeat=repeat),
-                stage="time", key=qkey, policy=pol)
-        except resilience.CandidateFailure as e:
-            resilience.record("time", e.kind, qkey, "quarantined",
-                              e.detail)
-            if cache is not None:
-                cache.quarantine(qkey, e.kind, e.detail)
-            continue
-        out.append(PipelineTiming(
-            block=int(b), traffic_words=int(words), vmem_bytes=int(vmem),
-            analytic_seconds=s_ana, calibrated_seconds=s_cal,
-            steps=steps, measurement=m, plan=variant, depth=int(d)))
-    return out
-
-
-def _observe_pipeline(pipe, timings: List[PipelineTiming]) -> None:
-    samples = [calibrate.Sample(
-        workload=f"Pipeline:{pipe.name}:{pipe.shared_extent}",
-        kind="Pipeline",
-        stream_bytes=t.analytic_seconds * HBM_BYTES_PER_S,
-        steps=t.steps, measured_s=t.measurement.median_s,
-        key=f"Pipeline:{pipe.name}:{pipe.shared_extent}"
-            f"|b={t.block}d{t.depth}")
-        for t in timings]
-    if samples:
-        calibrate.observe(samples)
-    _accuracy_gauges("Pipeline", [(t.calibrated_seconds,
-                                   t.measurement.median_s)
-                                  for t in timings])
-
-
 def _price_whole_pipeline(pipe, *, vmem_budget: int, align: int,
                           max_points: int, profile,
                           counters: Dict[str, int],
@@ -1404,8 +1346,6 @@ def _price_whole_pipeline(pipe, *, vmem_budget: int, align: int,
     sorted best-first (the analytic shortlist of the whole DAG).
     Entries are ``((block, depth), (words, vmem, s_ana, s_cal, steps))``;
     ties in calibrated seconds break toward the shallowest depth."""
-    from . import pipeline as plmod
-
     n_stages = len(plmod.topo_stages(pipe))
     try:
         whole = plmod.sub_pipeline(pipe, 0, n_stages)
@@ -1433,33 +1373,30 @@ def measured_pipeline_shortlist(pipe, *,
                                 warmup: int = MEASURE_WARMUP,
                                 repeat: int = MEASURE_REPEAT,
                                 calibrate_update: bool = True,
-                                priced: Optional[List[Tuple]] = None,
                                 depths: Tuple[int, ...] = DEPTHS,
                                 policy: Optional[resilience.Policy]
                                 = None,
                                 cache: Union[None, bool, str,
                                              TuningCache] = False
-                                ) -> List[PipelineTiming]:
+                                ) -> List[CandidateTiming]:
     """Hybrid step for a pipeline DAG: analytically shortlist fully
     fused (block, depth) candidates, lower the top-k whole megakernels
     (depth-deep rotating stage scratch included), time them, optionally
-    fold the samples into the calibration profile.  ``priced`` reuses
-    an already-computed shortlist (``explore_pipeline`` passes its DP's
-    whole-range pricing) instead of re-pricing.  ``policy``/``cache``
+    fold the samples into the calibration profile.  ``policy``/``cache``
     mirror ``measured_shortlist``: deadline + retry per candidate,
     persistent quarantine when a cache is given."""
-    if priced is None:
-        priced = _price_whole_pipeline(
-            pipe, vmem_budget=vmem_budget, align=align,
-            max_points=max_points, profile=_resolve_profile(profile),
-            counters={"explored": 0, "pruned": 0}, depths=depths)
-    timings = _time_pipeline_candidates(
-        pipe, priced[:max(top_k, 1)], vmem_budget=vmem_budget,
-        align=align, timing_db=timing_db, warmup=warmup, repeat=repeat,
-        policy=policy, cache=_resolve_cache(cache))
-    if calibrate_update:
-        _observe_pipeline(pipe, timings)
-    return timings
+    s = _PipelineSpace(pipe, Options(vmem_budget=vmem_budget, align=align,
+                                     max_points=max_points,
+                                     depths=depths).resolved())
+    priced = _price_whole_pipeline(
+        pipe, vmem_budget=vmem_budget, align=align, max_points=max_points,
+        profile=_resolve_profile(profile),
+        counters={"explored": 0, "pruned": 0}, depths=depths)
+    return _time_candidates(s, _timing_shortlist(s, priced, max(top_k, 1)),
+                            timing_db=timing_db, warmup=warmup,
+                            repeat=repeat, policy=policy,
+                            cache=_resolve_cache(cache),
+                            observe=calibrate_update)
 
 
 def explore_pipeline(pipe, *,
@@ -1530,270 +1467,198 @@ def explore_pipeline(pipe, *,
     if o.trace:
         telemetry.enable()
     with telemetry.span("dse.explore_pipeline", pipeline=pipe.name) as sp:
-        return _explore_pipeline_body(pipe, o, sp)
+        return _explore(_PipelineSpace(pipe, o), o, sp)
 
 
-def _explore_pipeline_body(pipe, o: Options, sp) -> PipelinePlan:
-    from . import pipeline as plmod  # local import: keep layering thin
+class _PipelineSpace:
+    """The pipeline engine's search space: one streaming block x buffer
+    depth over the fused DAG, split into contiguous topological groups
+    by a prefix DP when no fused candidate fits.  Only a fully fused
+    plan is one kernel: it alone is timed and lent as a bucket donor
+    (a split plan's cuts are priced for one extent and its groups run
+    as separate kernels)."""
 
-    vmem_budget, align = o.vmem_budget, o.align
-    max_points, measure, top_k = o.max_points, o.measure, o.top_k
-    timing_db, profile = o.timing_db, o.profile
-    warmup, repeat, depths, policy = (o.warmup, o.repeat, o.depths,
-                                      o.policy)
-    prof = _resolve_profile(profile)
-    tc = _resolve_cache(o.cache)
-    topo = plmod.topo_stages(pipe)
-    n_stages = len(topo)
-    cands = _pipeline_candidates(pipe, align, max_points)
+    kind, tag = "pipeline", "pipe"   # bucket-index kind, re-tune tag
+    plan_cls = PipelinePlan
+    calib_kind = "Pipeline"
+    bucketable = True
 
-    extra: Tuple = (tuple(cands),
-                    ("depths",) + tuple(int(d) for d in depths))
-    if measure:
-        extra += (("measure", measure, int(top_k)),)
+    def __init__(self, pipe, o: Options):
+        self.pipe, self.o = pipe, o
+        self.n_stages = len(plmod.topo_stages(pipe))
+        self.cands = _pipeline_candidates(pipe, o.align, o.max_points)
+        self.extra = (tuple(self.cands),) + _search_extra(o)
+        # the search's accounting, mutated by its pricing calls
+        self.counts = {"explored": 0, "pruned": 0}
+        self.workload = f"Pipeline:{pipe.name}:{pipe.shared_extent}"
 
-    def key_now() -> str:
-        return pipeline_key(pipe, vmem_budget=vmem_budget, align=align,
-                            extra=extra)
+    def key(self, extra: Optional[Tuple] = None, **ctx) -> str:
+        return pipeline_key(self.pipe, vmem_budget=self.o.vmem_budget,
+                            align=self.o.align,
+                            extra=self.extra if extra is None else extra,
+                            **ctx)
 
-    bucketing_on = o.bucketing and tc is not None
-    if bucketing_on:
-        from . import buckets as buckets_mod
+    @functools.cached_property
+    def unfused(self) -> int:
+        return plmod.unfused_traffic_words(self.pipe)
 
-    if tc is not None:
-        hit = tc.get(key_now(), PipelinePlan)
-        if hit is not None:
-            if bucketing_on:
-                buckets_mod.note("exact_hits")
-            telemetry.count("dse.cache_hits")
-            hit = dataclasses.replace(hit, key=key_now())
-            sp.set(source="cache")
-            _record_plan(hit, source="cache")
-            return hit
+    def search(self) -> Tuple[PipelinePlan, List[Tuple]]:
+        o, pipe, n_stages = self.o, self.pipe, self.n_stages
+        cands, depths, counters = self.cands, o.depths, self.counts
+        prof = _resolve_profile(o.profile)
+        # the fully fused (whole-range) candidates are priced once and
+        # shared: they seed the DP's (0, n) entry AND the measured
+        # shortlist (no duplicate fuse_dag/plan_memory work)
+        with telemetry.span("dse.shortlist", pipeline=pipe.name) as ssp:
+            priced_whole = _price_whole_pipeline(
+                pipe, vmem_budget=o.vmem_budget, align=o.align,
+                max_points=o.max_points, profile=prof, counters=counters,
+                depths=depths)
+            ssp.set(fused_candidates=len(priced_whole))
 
-    if bucketing_on:
-        warm = buckets_mod.warm_start_pipeline(
-            pipe, tc, vmem_budget=vmem_budget, align=align,
-            max_points=max_points)
-        if warm is not None:
-            buckets_mod.note("warm_hits")
-            pol = resilience.resolve_policy(policy)
-            # cache=False: the re-tune must not write the cache itself
-            # -- only its *certified* winner is promoted, below
-            retune_opts = dataclasses.replace(o, bucketing=False,
-                                              cache=False)
-            tag = "pipe|" + key_now()
+        def best_group(i0: int, i1: int, memo: Dict):
+            """Per-group (block, depth) choice: cheapest (words, seconds,
+            vmem, block, depth) for topo stages [i0, i1) over the
+            candidate tiles crossed with the buffer depths (shallowest
+            wins ties)."""
+            if (i0, i1) in memo:
+                return memo[(i0, i1)]
+            best = None
+            try:
+                # built once per range: block-independent (validate /
+                # topo analysis is not free, cands can be large)
+                sub_pipe = plmod.sub_pipeline(pipe, i0, i1)
+            except (ValueError, NotImplementedError):
+                # e.g. a cut that makes a terminal both output and
+                # consumed: this grouping is simply infeasible
+                sub_pipe = None
+            if sub_pipe is not None:
+                for b in cands:
+                    for d in depths:
+                        priced = _price_pipeline_group(
+                            sub_pipe, b, vmem_budget=o.vmem_budget,
+                            profile=prof, counters=counters, depth=d)
+                        if priced is None:
+                            continue
+                        rank = (priced[0], priced[3], d, -priced[1])
+                        if best is None or rank < (best[0], best[1],
+                                                   best[4], -best[2]):
+                            best = (priced[0], priced[3], priced[1], b, d)
+            memo[(i0, i1)] = best
+            return best
 
-            def _retune() -> PipelinePlan:
-                return explore_pipeline(pipe, options=retune_opts)
+        # prefix DP over contiguous topological groups; fewer groups
+        # preferred on ties (the j == 0 single-group candidate is tried
+        # first and later candidates must be strictly cheaper)
+        memo: Dict = {}
+        if priced_whole:
+            (b, d), (words, vmem, _, s_cal, _) = priced_whole[0]
+            memo[(0, n_stages)] = (words, s_cal, vmem, b, d)
+        else:
+            memo[(0, n_stages)] = None
+        state: List = [None] * (n_stages + 1)
+        # words, seconds, vmem, groups, blocks, depths
+        state[0] = (0, 0.0, 0, (), (), ())
+        for i in range(1, n_stages + 1):
+            for j in range(0, i):
+                if state[j] is None:
+                    continue
+                g = best_group(j, i, memo)
+                if g is None:
+                    continue
+                cand = (state[j][0] + g[0], state[j][1] + g[1],
+                        max(state[j][2], g[2]),
+                        state[j][3] + ((j, i),), state[j][4] + (g[3],),
+                        state[j][5] + (g[4],))
+                if state[i] is None or (cand[0], cand[1]) \
+                        < (state[i][0], state[i][1]):
+                    state[i] = cand
+        best = state[n_stages]
+        if best is None:
+            raise ValueError(
+                "pipeline DSE: no tile candidate fits VMEM budget "
+                f"{o.vmem_budget} B for '{pipe.name}' "
+                f"({counters['explored']} candidates over {cands})")
+        plan = PipelinePlan(
+            block=int(best[4][0]), groups=best[3], group_blocks=best[4],
+            traffic_words=int(best[0]), unfused_traffic_words=self.unfused,
+            vmem_bytes=int(best[2]), modeled_seconds=float(best[1]),
+            depths=best[5], **counters)
+        return plan, priced_whole
 
-            def _certify(plan: PipelinePlan):
-                return resilience.certify_guarded(
-                    lambda: resilience.certify_pipeline_plan(
-                        pipe, plan, vmem_budget=vmem_budget),
-                    key="retune|" + tag, policy=pol)
+    def point(self, c: Tuple) -> Tuple[PipelinePlan, float, int]:
+        """A fused candidate ``((block, depth), (words, vmem, analytic
+        s, calibrated s, steps))`` as (one-group plan, analytic seconds,
+        grid steps)."""
+        (b, d), (words, vmem, s_ana, s_cal, steps) = c
+        return PipelinePlan(
+            block=int(b), groups=((0, self.n_stages),),
+            group_blocks=(int(b),), depths=(int(d),),
+            traffic_words=int(words), unfused_traffic_words=self.unfused,
+            vmem_bytes=int(vmem), modeled_seconds=float(s_cal),
+            **self.counts), s_ana, steps
 
-            def _promote(plan: PipelinePlan) -> None:
-                # key recomputed at promotion time: the background
-                # explore may have refreshed the calibration profile
-                tc.put(key_now(), plan)
-                buckets_mod.record_pipeline(pipe, plan, tc,
-                                            vmem_budget=vmem_budget,
-                                            align=align)
+    def one_kernel(self, plan: PipelinePlan) -> bool:
+        return plan.fused
 
-            buckets_mod.schedule_retune(tag, _retune, certify=_certify,
-                                        promote=_promote, policy=pol)
-            warm = dataclasses.replace(warm, key=key_now())
-            sp.set(source="warm_start", bucket=warm.bucket)
-            _record_plan(warm, source="warm_start", bucket=warm.bucket,
-                         retune_tag=tag)
-            return warm
-        buckets_mod.note("misses")
+    def ident(self, plan: PipelinePlan) -> Tuple:
+        """Timing and certification identity: (block, depth).  Depth is
+        part of it because the megakernel's rotating stage scratch is
+        allocated depth-deep: depth variants are different
+        executables."""
+        return int(plan.block), int(plan.depth)
 
-    counters = {"explored": 0, "pruned": 0}
+    def row(self, plan: PipelinePlan) -> Dict:
+        return {"block": int(plan.block), "depth": int(plan.depth)}
 
-    # the fully fused (whole-range) candidates are priced once and
-    # shared: they seed the DP's (0, n) entry AND the measured
-    # shortlist below (no duplicate fuse_dag/plan_memory work)
-    with telemetry.span("dse.shortlist", pipeline=pipe.name) as ssp:
-        priced_whole = _price_whole_pipeline(
-            pipe, vmem_budget=vmem_budget, align=align,
-            max_points=max_points, profile=prof, counters=counters,
-            depths=depths)
-        ssp.set(fused_candidates=len(priced_whole))
+    def timing_row(self, t: CandidateTiming) -> Dict:
+        return {**self.row(t.plan),
+                "median_s": float(t.measurement.median_s)}
 
-    def best_group(i0: int, i1: int, memo: Dict):
-        """Per-group (block, depth) choice: cheapest (words, seconds,
-        vmem, block, depth) for topo stages [i0, i1) over the candidate
-        tiles crossed with the buffer depths (shallowest wins ties)."""
-        if (i0, i1) in memo:
-            return memo[(i0, i1)]
-        best = None
+    def sample_id(self, plan: PipelinePlan) -> str:
+        return f"b={plan.block}d{plan.depth}"
+
+    def lower(self, plan: PipelinePlan):
+        from .codegen_pallas import lower_pipeline_for_timing
+        return lower_pipeline_for_timing(
+            self.pipe, plan, vmem_budget=self.o.vmem_budget), "pallas"
+
+    def certify(self, plan: PipelinePlan) -> Tuple[bool, str]:
+        return resilience.certify_pipeline_plan(
+            self.pipe, plan, vmem_budget=self.o.vmem_budget)
+
+    def retune(self, options: Options) -> PipelinePlan:
+        return explore_pipeline(self.pipe, options=options)
+
+    def span_attrs(self, plan: PipelinePlan) -> Dict:
+        return {"groups": len(plan.groups)}
+
+    def pruned_reasons(self, n_ranked: int, n_short: int, n_timed: int,
+                       plan: PipelinePlan) -> Dict:
+        return {"dominated": max(n_ranked - plan.timed, 0)
+                if plan.timed else 0}
+
+    def family(self) -> str:
+        return buckets.pipeline_family(
+            self.pipe, vmem_budget=self.o.vmem_budget, align=self.o.align)
+
+    def bucket_domains(self) -> Dict[str, Tuple[int, ...]]:
+        return buckets.pipeline_buckets(self.pipe)
+
+    def refit(self, donor: PipelinePlan) -> Optional[PipelinePlan]:
+        """A fully fused plan at ``donor``'s block re-fitted onto this
+        extent's candidates and at its depth, priced uncalibrated
+        (None: no fit)."""
+        b = _fit(self.cands, donor.block)
         try:
-            # built once per range: block-independent (validate / topo
-            # analysis is not free, cands can be large)
-            sub_pipe = plmod.sub_pipeline(pipe, i0, i1)
+            whole = plmod.sub_pipeline(self.pipe, 0, self.n_stages)
         except (ValueError, NotImplementedError):
-            # e.g. a cut that makes a terminal both output and
-            # consumed: this grouping is simply infeasible
-            sub_pipe = None
-        if sub_pipe is not None:
-            for b in cands:
-                for d in depths:
-                    priced = _price_pipeline_group(
-                        sub_pipe, b, vmem_budget=vmem_budget,
-                        profile=prof, counters=counters, depth=d)
-                    if priced is None:
-                        continue
-                    rank = (priced[0], priced[3], d, -priced[1])
-                    if best is None or rank < (best[0], best[1],
-                                               best[4], -best[2]):
-                        best = (priced[0], priced[3], priced[1], b, d)
-        memo[(i0, i1)] = best
-        return best
-
-    # prefix DP over contiguous topological groups; fewer groups
-    # preferred on ties (the j == 0 single-group candidate is tried
-    # first and later candidates must be strictly cheaper)
-    memo: Dict = {}
-    if priced_whole:
-        (b, d), (words, vmem, _, s_cal, _) = priced_whole[0]
-        memo[(0, n_stages)] = (words, s_cal, vmem, b, d)
-    else:
-        memo[(0, n_stages)] = None
-    state: List = [None] * (n_stages + 1)
-    # words, seconds, vmem, groups, blocks, depths
-    state[0] = (0, 0.0, 0, (), (), ())
-    for i in range(1, n_stages + 1):
-        for j in range(0, i):
-            if state[j] is None:
-                continue
-            g = best_group(j, i, memo)
-            if g is None:
-                continue
-            cand = (state[j][0] + g[0], state[j][1] + g[1],
-                    max(state[j][2], g[2]),
-                    state[j][3] + ((j, i),), state[j][4] + (g[3],),
-                    state[j][5] + (g[4],))
-            if state[i] is None or (cand[0], cand[1]) \
-                    < (state[i][0], state[i][1]):
-                state[i] = cand
-    best = state[n_stages]
-    if best is None:
-        raise ValueError(
-            "pipeline DSE: no tile candidate fits VMEM budget "
-            f"{vmem_budget} B for '{pipe.name}' "
-            f"({counters['explored']} candidates over {cands})")
-
-    plan = PipelinePlan(
-        block=int(best[4][0]), groups=best[3], group_blocks=best[4],
-        traffic_words=int(best[0]),
-        unfused_traffic_words=plmod.unfused_traffic_words(pipe),
-        vmem_bytes=int(best[2]), modeled_seconds=float(best[1]),
-        explored=counters["explored"], pruned=counters["pruned"],
-        depths=best[5])
-
-    prov_measured: List[Dict] = []
-    prov_cert: List[Dict] = []
-    if measure == "top_k" and plan.fused:
-        pol = resilience.resolve_policy(policy)
-        with telemetry.span("dse.measure", top_k=int(top_k)) as msp:
-            # the resolved profile (prof=None means "uncalibrated",
-            # whether from an explicit False or from no profile on
-            # disk) must not re-resolve back to the on-disk profile
-            # downstream
-            timings = measured_pipeline_shortlist(
-                pipe, top_k=top_k, vmem_budget=vmem_budget, align=align,
-                max_points=max_points,
-                profile=prof if prof is not None else False,
-                timing_db=timing_db, warmup=warmup, repeat=repeat,
-                priced=priced_whole, depths=depths, policy=pol,
-                cache=tc if tc is not None else False)
-            ranked = sorted(timings,
-                            key=lambda t: (t.measurement.median_s,
-                                           t.traffic_words, t.depth,
-                                           -t.vmem_bytes))
-            prov_measured = [
-                {"block": int(t.block), "depth": int(t.depth),
-                 "median_s": float(t.measurement.median_s)}
-                for t in ranked]
-            msp.set(timed=len(timings))
-            promoted = False
-            for win in ranked:
-                if pol.certify:
-                    ckey = "certify|" + measure_mod.TimingDB.full_key(
-                        pipeline_key(pipe, vmem_budget=vmem_budget,
-                                     align=align,
-                                     extra=("certify", win.block,
-                                            win.depth),
-                                     device="", profile_hash=""))
-                    if tc is not None \
-                            and tc.quarantined(ckey) is not None:
-                        # failed certification in a past run
-                        prov_cert.append({"block": int(win.block),
-                                          "depth": int(win.depth),
-                                          "ok": False,
-                                          "reason": "quarantined"})
-                        continue
-                    ok, reason = resilience.certify_guarded(
-                        lambda w=win: resilience.certify_pipeline_plan(
-                            pipe, w.plan, vmem_budget=vmem_budget),
-                        key=ckey, policy=pol)
-                    prov_cert.append({"block": int(win.block),
-                                      "depth": int(win.depth),
-                                      "ok": bool(ok), "reason": reason})
-                    if not ok:
-                        resilience.record("certify", "certify-failed",
-                                          ckey, "quarantined", reason)
-                        if tc is not None:
-                            tc.quarantine(ckey, "certify-failed", reason)
-                        continue
-                plan = dataclasses.replace(
-                    win.plan,
-                    unfused_traffic_words=plan.unfused_traffic_words,
-                    explored=counters["explored"],
-                    pruned=counters["pruned"],
-                    measured=True,
-                    measured_seconds=win.measurement.median_s,
-                    timed=len(timings))
-                promoted = True
-                break
-            if not promoted:
-                resilience.record(
-                    "explore", "no-measured-winner",
-                    f"Pipeline:{pipe.name}:{pipe.shared_extent}",
-                    "fallback",
-                    f"{len(timings)} timed, 0 certified; analytic plan "
-                    "promoted instead")
-
-    # key recomputed AFTER any calibration update: the next call
-    # prices under the new profile hash and must hit this entry
-    final_key = key_now()
-    plan = dataclasses.replace(plan, key=final_key)
-    if tc is not None:
-        tc.put(final_key, plan)
-        if bucketing_on:
-            buckets_mod.record_pipeline(pipe, plan, tc,
-                                        vmem_budget=vmem_budget,
-                                        align=align)
-    sp.set(source="explored", explored=plan.explored,
-           pruned=plan.pruned, groups=len(plan.groups),
-           timed=plan.timed)
-    _record_plan(
-        plan, source="explored",
-        enumerated=plan.explored,
-        pruned={"vmem": plan.pruned,
-                "dominated": max(len(priced_whole) - plan.timed, 0)
-                if plan.timed else 0},
-        analytic_ranks=[
-            {"block": int(b), "depth": int(d),
-             "traffic_words": int(words),
-             "calibrated_seconds": float(s_cal)}
-            for (b, d), (words, _v, _sa, s_cal, _st)
-            in priced_whole[:max(int(top_k), 3)]],
-        measured_ranks=prov_measured,
-        certification=prov_cert)
-    return plan
+            return None
+        res = _price_pipeline_group(
+            whole, b, vmem_budget=self.o.vmem_budget, profile=None,
+            counters={"explored": 0, "pruned": 0}, depth=donor.depth)
+        return None if res is None \
+            else self.point(((b, donor.depth), res))[0]
 
 
 # --------------------------------------------------------------------------

@@ -10,12 +10,7 @@ decode for attention families, an in-jit token scan for recurrent
 ones), then ``--gen`` tokens greedy-decode one step at a time.
 
 ``--prompt-lens 24,100,100,360`` serves a mixed batch: requests are
-grouped by prompt length and each group prefills in one call.  With
-``--bucketing`` the tuning plans backing each group's attention shape
-resolve through the shape-bucket layer (``core.buckets``): a cold
-prompt length whose bucket is already tuned is served a warm-start
-plan immediately (zero foreground lowering) while a bounded background
-re-tune promotes the certified exact-shape winner into the cache.
+grouped by prompt length and each group prefills in one call.
 
 ``--continuous`` switches to continuous batching over a *paged* KV
 pool (``models.paged``): requests are admitted into and evicted from a
@@ -67,48 +62,9 @@ def _ring_len(cfg, max_len: int) -> int:
     return max_len
 
 
-def _resolve_group_plans(cfg, lengths: Sequence[int], gen: int
-                         ) -> List[Dict]:
-    """Resolve the DSE attention plan for each prompt-length group
-    through the shape-bucket layer.  Returns per-group provenance:
-    did the plan come from the exact tuning cache, a bucket warm
-    start, or a fresh exploration?  Each group runs with its own
-    ``ln + gen`` cache, so the KV extent is per group -- not the
-    global ``max(lens) + gen``."""
-    from repro.core import buckets
-    from repro.core.options import Options
-    from repro.kernels import ops
-
-    opts = Options(bucketing=True)
-    head_dim = cfg.head_dim or (cfg.d_model // max(cfg.n_heads, 1))
-    # snapshot at entry: the process-wide bucket counters accumulate
-    # across serve invocations, so per-call hit rates come from the
-    # delta, not the raw totals
-    before = buckets.snapshot()
-    rows = []
-    for plen in lengths:
-        t0 = time.time()
-        _, plan = ops.resolve_plan("attention", int(plen),
-                                   int(plen + gen),
-                                   int(head_dim), options=opts)
-        rows.append({
-            "prompt_len": int(plen),
-            "resolve_s": time.time() - t0,
-            "warm_start": bool(plan.warm_start),
-            "bucket": plan.bucket,
-            "cached": bool(plan.cached),
-            "sizes": {k: tuple(v) for k, v in plan.sizes.items()},
-        })
-    d = buckets.delta(before)
-    rows.append({"bucket_stats": d,
-                 "bucket_hit_rate": buckets.delta_hit_rate(d)})
-    return rows
-
-
 def serve(arch: str, smoke: bool, batch: int, prompt_len: int,
           gen: int, seed: int = 0,
           prompt_lens: Optional[Sequence[int]] = None,
-          bucketing: bool = False,
           stats_out: Optional[Dict] = None) -> np.ndarray:
     """Serve ``batch`` requests; returns the (batch, gen) generated
     tokens (requests keep their input order even when mixed prompt
@@ -136,10 +92,6 @@ def serve(arch: str, smoke: bool, batch: int, prompt_len: int,
     groups: Dict[int, List[int]] = {}
     for r, ln in enumerate(lens):
         groups.setdefault(ln, []).append(r)
-
-    if bucketing:
-        for row in _resolve_group_plans(cfg, sorted(groups), gen):
-            print("plan:", row)
 
     out = np.zeros((batch, gen), np.int64)
     prefill_s = decode_s = 0.0
@@ -546,8 +498,9 @@ def main():
                          "(mixed batch; overrides --prompt-len)")
     ap.add_argument("--gen", type=int, default=16)
     ap.add_argument("--bucketing", action="store_true",
-                    help="resolve tuning plans through the shape-bucket "
-                         "warm-start layer and print their provenance")
+                    help="resolve the paged-decode plan through the "
+                         "shape-bucket warm-start layer (--continuous "
+                         "only)")
     ap.add_argument("--continuous", action="store_true",
                     help="continuous batching over a paged KV pool: "
                          "--batch is the slot count, --prompt-lens the "
@@ -572,8 +525,7 @@ def main():
             bucketing=args.bucketing)
     else:
         toks = serve(args.arch, args.smoke, args.batch, args.prompt_len,
-                     args.gen, prompt_lens=_parse_lens(args.prompt_lens),
-                     bucketing=args.bucketing)
+                     args.gen, prompt_lens=_parse_lens(args.prompt_lens))
     print("generated token block:", toks.shape)
 
 

@@ -84,29 +84,6 @@ def test_serve_mixed_prompt_lengths_preserve_order():
     np.testing.assert_array_equal(mixed[[0, 2]], uniform[[0, 2]])
 
 
-def test_resolve_group_plans_use_per_group_extent(monkeypatch):
-    """Regression (ISSUE 9): each prompt-length group's attention plan
-    resolves at ITS OWN KV extent ``ln + gen``, not the global
-    ``max(lens) + gen`` every group used to be priced at."""
-    from repro.kernels import ops
-
-    cfg = get_config("granite-3-2b", smoke=True)
-    calls = []
-
-    def fake_resolve(kind, *shape, **kw):
-        calls.append((kind, shape))
-
-        class P:
-            warm_start, bucket, cached, sizes = False, "", False, {}
-        return (None, P())
-
-    monkeypatch.setattr(ops, "resolve_plan", fake_resolve)
-    serve._resolve_group_plans(cfg, [4, 6], gen=2)
-    hd = cfg.head_dim or (cfg.d_model // max(cfg.n_heads, 1))
-    assert calls == [("attention", (4, 6, hd)),
-                     ("attention", (6, 8, hd))]
-
-
 def test_zero_length_prompts_rejected():
     """Regression (ISSUE 9): a zero-length prompt must fail loudly at
     validation, not prefill garbage."""
