@@ -462,12 +462,12 @@ def lower_tiled_flatmap(p: ir.FlatMap) -> Callable:
 _CHUNK_ROWS = 1024
 
 
-def _chunk_rows(b: int) -> int:
+def _chunk_rows(b: int, most: int = _CHUNK_ROWS) -> int:
     """All of a small tile; else the largest power-of-two part of ``b``
-    up to ``_CHUNK_ROWS``, kept lane-aligned (a multiple of 128)."""
-    if b <= _CHUNK_ROWS:
+    up to ``most``, kept lane-aligned (a multiple of 128)."""
+    if b <= most:
         return b
-    c = math.gcd(b, _CHUNK_ROWS)
+    c = math.gcd(b, most)
     return c if c >= 128 else b
 
 
@@ -524,22 +524,37 @@ def _collect_dag_loads(terminals):
 class _Terminal:
     """How one fused-DAG terminal writes its output: the full output
     array shape, the logical shape results reshape to, the output
-    BlockSpec, ``init(out)`` run at grid step 0 (None for streamed
-    outputs), and ``emit(g, out, env, r0, rows)`` which folds or writes
-    the terminal's rows ``r0 + [0, rows)`` of grid step ``g``."""
+    BlockSpec, ``init(out, acc)`` run at grid step 0 (None for streamed
+    outputs), and ``emit(g, out, env, r0, rows, part)`` which folds or
+    writes the terminal's rows ``r0 + [0, rows)`` of grid step ``g`` and
+    returns the chunk loop's carry.  ``template`` names the template
+    (``lanes``, ``tree``, ``cam``, ``map``).  A ``lanes`` terminal
+    carries a per-lane ``(rows,)`` partial through the chunk loop and
+    keeps a VMEM accumulator ``acc`` of that shape; its ``flush(g, out,
+    acc, part)`` merges the two after each grid step's loop.  The others
+    carry None and have no ``flush``."""
 
     full: Tuple[int, ...]
     shape: Tuple[int, ...]
     spec: Any
     init: Optional[Callable]
     emit: Callable
+    template: str
+    flush: Optional[Callable] = None
 
 
-def _terminal_emitter(p: ir.Pattern) -> _Terminal:
+def _terminal_emitter(p: ir.Pattern, grid_n: int) -> _Terminal:
     """Template selection for one fused-DAG terminal:
 
-      * fold terminal       -> revisited accumulator block (init at
-                               g == 0, partial fold merged via combine)
+      * additive scalar fold -> lanes template: each chunk's rows added
+                               elementwise into a carried per-lane
+                               partial, the partial into a VMEM
+                               accumulator once per grid step, and the
+                               lanes reduced to the output block once,
+                               at the last grid step (``grid_n - 1``)
+      * other fold terminal -> revisited accumulator block (init at
+                               g == 0, each chunk's partial folded by a
+                               tree and merged via combine)
       * keyed-fold terminal -> CAM template, one-hot MXU scatter into a
                                revisited dense block
       * Map terminal        -> write-once streaming template: the tile
@@ -563,14 +578,14 @@ def _terminal_emitter(p: ir.Pattern) -> _Terminal:
         row = elem if elem else (1,)
         out_shape = tuple(p.range_shape)            # (n,) + elem
 
-        def emit_map(g, out, env, r0, rows):
+        def emit_map(g, out, env, r0, rows, part):
             vals = _env_apply(q, q.fn, g, env, r0, rows)
             out[pl.ds(r0, rows)] = jnp.asarray(vals, out.dtype
                                                ).reshape((rows,) + row)
 
         spec = pl.BlockSpec((b,) + row, lambda g: (g,) + (0,) * len(row))
         return _Terminal((out_shape[0],) + row, out_shape, spec, None,
-                         emit_map)
+                         emit_map, "map")
 
     if isinstance(p, ir.MultiFold):
         # terminal fold: revisited accumulator block, inner partial
@@ -584,28 +599,49 @@ def _terminal_emitter(p: ir.Pattern) -> _Terminal:
         out_block = _padded_out(range_shape)
         if len(range_shape) > 2:
             raise NotImplementedError("fold accumulators of rank <= 2")
+        dtype = jnp.dtype(p.dtype)
 
-        def init_fold(out):
-            out[...] = jnp.asarray(p.init(), out.dtype).reshape(out_block)
+        # every index's update applied to the identity, then folded
+        # with combine: a fold body updates its accumulator by
+        # combining in its own contribution, f(acc, x) =
+        # combine(acc, f(init, x)), so any order of combines is the
+        # sequential fold
+        def one(s, *w):
+            z = jnp.asarray(q.init(), dtype)
+            return jnp.asarray(q.fn(s, z, *w), dtype)
 
-        def emit_fold(g, out, env, r0, rows):
-            # every index's update applied to the identity, then folded
-            # with combine: a fold body updates its accumulator by
-            # combining in its own contribution, f(acc, x) =
-            # combine(acc, f(init, x)), so the order-free tree is the
-            # sequential fold
-            def one(s, *w):
-                z = jnp.asarray(q.init(), out.dtype)
-                return jnp.asarray(q.fn(s, z, *w), out.dtype)
-
-            per = _env_apply(q, one, g, env, r0, rows)
-            partial = _combine_rows(q.combine, per).reshape(out_block)
-            out[...] = jnp.asarray(p.combine(out[...], partial),
-                                   out.dtype)
+        def init_fold(out, acc):
+            out[...] = jnp.asarray(p.init(), dtype).reshape(out_block)
 
         spec = pl.BlockSpec(out_block, lambda g: (0,) * len(out_block))
+        if range_shape == () and _is_add(p.combine, (), dtype) \
+                and _is_add(q.combine, (), dtype):
+            # row r of a chunk adds into lane r of the partial: nothing
+            # leaves the vector unit until the last grid step
+            def init_lanes(out, acc):
+                init_fold(out, acc)
+                acc[...] = jnp.zeros(acc.shape, dtype)
+
+            def emit_lanes(g, out, env, r0, rows, part):
+                return part + _env_apply(q, one, g, env, r0, rows)
+
+            def flush_lanes(g, out, acc, part):
+                acc[...] += part
+
+                @pl.when(g == grid_n - 1)
+                def _():
+                    out[...] += jnp.sum(acc[...]).reshape(out_block)
+
+            return _Terminal(out_block, range_shape, spec, init_lanes,
+                             emit_lanes, "lanes", flush_lanes)
+
+        def emit_fold(g, out, env, r0, rows, part):
+            per = _env_apply(q, one, g, env, r0, rows)
+            partial = _combine_rows(q.combine, per).reshape(out_block)
+            out[...] = jnp.asarray(p.combine(out[...], partial), dtype)
+
         return _Terminal(out_block, range_shape, spec, init_fold,
-                         emit_fold)
+                         emit_fold, "tree")
 
     if isinstance(p, ir.GroupByFold):
         # terminal keyed fold: CAM template (one-hot MXU scatter) into a
@@ -623,16 +659,17 @@ def _terminal_emitter(p: ir.Pattern) -> _Terminal:
         # (Mosaic wants >= 2-D blocks, same as _padded_out for folds)
         out_block = (k,) + (elem if elem else (1,))
 
-        def init_cam(out):
+        def init_cam(out, acc):
             out[...] = jnp.asarray(p.init(), out.dtype).reshape(out_block)
 
-        def emit_cam(g, out, env, r0, rows):
+        def emit_cam(g, out, env, r0, rows, part):
             keys, vals = _env_apply(q, q.fn, g, env, r0, rows)
             out[...] += _cam_update(keys, vals, k, out.dtype
                                     ).reshape(out_block)
 
         spec = pl.BlockSpec(out_block, lambda g: (0,) * len(out_block))
-        return _Terminal(out_block, (k,) + elem, spec, init_cam, emit_cam)
+        return _Terminal(out_block, (k,) + elem, spec, init_cam, emit_cam,
+                         "cam")
 
     raise NotImplementedError(
         f"no fused-chain template for terminal {type(p).__name__}")
@@ -674,12 +711,12 @@ def lower_fused_dag(terminals, grid_n: int, depth: int = 2,
     # kernel body stays telemetry-free
     with telemetry.span("codegen.lower_fused_dag",
                         terminals=len(terminals), grid=int(grid_n),
-                        depth=int(depth)):
-        return _lower_fused_dag_body(terminals, grid_n, depth, name)
+                        depth=int(depth)) as sp:
+        return _lower_fused_dag_body(terminals, grid_n, depth, name, sp)
 
 
 def _lower_fused_dag_body(terminals, grid_n: int, depth: int,
-                          name: str) -> Callable:
+                          name: str, sp) -> Callable:
     from jax.experimental.pallas import tpu as pltpu
 
     if depth < 2:
@@ -714,15 +751,26 @@ def _lower_fused_dag_body(terminals, grid_n: int, depth: int,
             raise NotImplementedError(
                 "fused chain: producer stages must be 1-D tile Maps")
 
-    emitters = [_terminal_emitter(t) for _, t in terminals]
+    emitters = [_terminal_emitter(t, grid_n) for _, t in terminals]
+    sp.set(fold=",".join(t.template for t in emitters))
     (b,) = terminals[0][1].inner.domain
-    rows = _chunk_rows(b)
-    n_in, n_out = len(reps), len(terminals)
+    # a kernel of lanes folds alone takes two vregs a chunk: at one, its
+    # loop and not the DMA set the grid step's time (a 6.44 GB Q6 query
+    # took 8.94 ms at 1024 rows a chunk, 8.56 at 2048 and 4096; TPU v5e)
+    lanes_only = all(t.flush for t in emitters)
+    rows = _chunk_rows(b, 2 * _CHUNK_ROWS if lanes_only else _CHUNK_ROWS)
+    dtypes = [jnp.dtype(p.dtype) for _, p in terminals]
+    # a lanes terminal's accumulator follows the stage scratch
+    scratch_shapes += [pltpu.VMEM((rows,), dt)
+                       for t, dt in zip(emitters, dtypes) if t.flush]
+    n_in, n_out, n_st = len(reps), len(terminals), len(stage_loads)
 
     def kernel(*refs):
         ins = refs[:n_in]
         outs = refs[n_in:n_in + n_out]
-        scratch = refs[n_in + n_out:]
+        scratch = refs[n_in + n_out:n_in + n_out + n_st]
+        more = iter(refs[n_in + n_out + n_st:])
+        accs = [next(more) if t.flush else None for t in emitters]
         g = pl.program_id(0)
         env: Dict[str, Any] = {}
         for uids, r in zip(uid_lists, ins):
@@ -742,32 +790,36 @@ def _lower_fused_dag_body(terminals, grid_n: int, depth: int,
 
         @pl.when(g == 0)
         def _init():
-            for t, out in zip(emitters, outs):
+            for t, out, acc in zip(emitters, outs, accs):
                 if t.init is not None:
-                    t.init(out)
+                    t.init(out, acc)
 
-        def chunk(r0):
+        def chunk(r0, parts):
             for tc, st, sc in zip(stage_loads, stages, scratch):
                 vals = _env_apply(st, st.fn, g, env, r0, rows)
                 sc[at[tc.uid] + (pl.ds(r0, rows),)] = jnp.asarray(
                     vals, sc.dtype).reshape((rows,) + tuple(st.elem_shape))
-            for t, out in zip(emitters, outs):
-                t.emit(g, out, env, r0, rows)
+            return tuple(t.emit(g, out, env, r0, rows, part)
+                         for t, out, part in zip(emitters, outs, parts))
 
+        parts = tuple(jnp.zeros((rows,), dt) if t.flush else None
+                      for t, dt in zip(emitters, dtypes))
         if rows == b:
-            chunk(0)
+            parts = chunk(0, parts)
         else:
-            def body(i, carry):
-                chunk(pl.multiple_of(i * rows, rows))
-                return carry
-
-            jax.lax.fori_loop(0, b // rows, body, 0)
+            parts = jax.lax.fori_loop(
+                0, b // rows,
+                lambda i, c: chunk(pl.multiple_of(i * rows, rows), c),
+                parts)
+        for t, out, acc, part in zip(emitters, outs, accs, parts):
+            if t.flush:
+                t.flush(g, out, acc, part)
 
     kernel_call = pl.pallas_call(
         kernel, grid=(grid_n,), in_specs=in_specs,
         out_specs=[t.spec for t in emitters],
-        out_shape=[jax.ShapeDtypeStruct(t.full, jnp.dtype(p.dtype))
-                   for t, (_, p) in zip(emitters, terminals)],
+        out_shape=[jax.ShapeDtypeStruct(t.full, dt)
+                   for t, dt in zip(emitters, dtypes)],
         scratch_shapes=scratch_shapes, interpret=backend.interpret(),
         name=name)
 

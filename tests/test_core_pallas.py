@@ -100,6 +100,7 @@ def test_chunk_rows_bounds_the_vector_pass():
     assert _chunk_rows(131072) == 1024
     assert _chunk_rows(1536) == 512
     assert _chunk_rows(1000 * 3) == 3000     # no lane-aligned part
+    assert _chunk_rows(131072, 2048) == 2048
 
 
 def test_fused_fold_with_non_additive_combine():

@@ -373,3 +373,125 @@ def test_block_index_map_accepts_aligned():
     amap = AffineMap((32,), ((16,),), arity=1)
     imap = _block_index_map(amap, (16,), 1)
     assert imap(3) == (5,)  # (32 + 3*16) // 16
+
+
+# ------------------------------------------------------ fold templates
+# the template each PIPELINE's terminals lower to, in terminal order,
+# as the codegen.lower_fused_dag span's ``fold`` attribute reports it
+FOLDS = {"tpchq6": "lanes", "gda": "cam", "kmeans": "cam,cam",
+         "gda_moments": "cam,cam", "normalize": "map"}
+
+
+def _lower_reporting_fold(fdag):
+    """The fused kernel of ``fdag`` and its span's ``fold`` attribute."""
+    from repro.core import telemetry
+    from repro.core.codegen_pallas import lower_fused_dag
+
+    telemetry.enable()
+    kern = lower_fused_dag(fdag.terminals, fdag.grid)
+    [span] = [s for s in telemetry.span_log()
+              if s["name"] == "codegen.lower_fused_dag"]
+    return kern, span["args"]["fold"]
+
+
+@pytest.mark.parametrize("name", ALL)
+def test_megakernel_reports_fold_template(name):
+    """Only an additive scalar fold (tpchq6's q6_sum) takes the lanes
+    template; keyed folds stay CAM and Map terminals stream, with the
+    results they had."""
+    pipe, inputs, ref = _setup(name)
+    kern, fold = _lower_reporting_fold(plmod.fuse_dag(pipe, 128))
+    assert fold == FOLDS[name]
+    _check(pipe, kern(**inputs), ref)
+
+
+def test_q6_lanes_fold_over_steps_and_chunks_matches_float64():
+    """The lanes template across 16 grid steps of 8 chunks each: the
+    per-lane partial carried through the chunk loop, flushed into the
+    accumulator every step and reduced once at the last, gives Q6's
+    answer within the benchmark's limit of the float64 reference."""
+    from repro.core.codegen_pallas import _chunk_rows
+
+    n, block = 2 ** 18, 2 ** 14
+    pipe, _, _ = PIPELINES["tpchq6"](n)
+    fdag = plmod.fuse_dag(pipe, block)
+    assert fdag.grid == 16 and block // _chunk_rows(block, 2048) == 8
+    kern, fold = _lower_reporting_fold(fdag)
+    assert fold == "lanes"
+    rng = np.random.RandomState(3)
+    cols = {c: rng.rand(n).astype(np.float32)
+            for c in ("qty", "price", "disc")}
+    got = float(kern(**{c: jnp.asarray(v) for c, v in cols.items()}
+                     )["q6_sum"])
+    q = cols["qty"]
+    keep = (q >= np.float32(0.05)) & (q < np.float32(0.95))
+    want = float(np.sum(np.where(keep, cols["price"].astype(np.float64)
+                                 * cols["disc"], 0.0)))
+    assert abs(got - want) / abs(want) < 2.5e-4
+
+
+def _square_folds(n, names):
+    """``sq = x * x`` folded by the named terminals: ``total`` (a sum)
+    and ``top`` (a max)."""
+    x = ir.Tensor("x", (n,))
+    sq = ir.Map(domain=(n,), reads=(ir.elem(x),),
+                fn=lambda s, e: e * e, name="sq")
+
+    def fold(name, init, combine):
+        return ir.MultiFold(
+            domain=(n,), range_shape=(), init=init,
+            reads=(ir.elem(ir.Tensor("sq", (n,))),),
+            out_index_map=lambda i: (), update_shape=(),
+            fn=lambda s, acc, v: combine(acc, v), combine=combine,
+            name=name)
+
+    folds = {"total": fold("total", lambda: jnp.zeros(()),
+                           lambda a, b: a + b),
+             "top": fold("top", lambda: jnp.full((), -jnp.inf),
+                         jnp.maximum)}
+    return plmod.Pipeline(name="sqfolds",
+                          stages=(sq,) + tuple(folds[k] for k in names))
+
+
+def _column_sums(n, d=4):
+    x = ir.Tensor("x", (n,))
+    spread = ir.Map(domain=(n,), elem_shape=(d,), reads=(ir.elem(x),),
+                    fn=lambda s, e: jnp.stack([e * (j + 1.0)
+                                               for j in range(d)]),
+                    name="spread")
+    total = ir.MultiFold(
+        domain=(n,), range_shape=(d,), init=lambda: jnp.zeros((d,)),
+        reads=(ir.Access(ir.Tensor("spread", (n, d)), lambda i: (i, 0),
+                         (1, d)),),
+        out_index_map=lambda i: (0,), update_shape=(d,),
+        fn=lambda s, acc, row: acc + row, combine=lambda a, b: a + b,
+        name="colsum")
+    return plmod.Pipeline(name="colsum", stages=(spread, total))
+
+
+@pytest.mark.parametrize("names,fold", [
+    (("top",), "tree"),                # a non-additive combine
+    (("total", "top"), "tree,lanes"),  # both in one kernel: top, total
+])
+def test_fold_template_follows_combine_and_shape(names, fold):
+    """A fold takes the lanes template only where its combine is
+    addition and its update a scalar; a max fold keeps the tree, side
+    by side with a lanes fold in one kernel, over several steps and
+    chunks."""
+    n = 2 ** 13
+    kern, got_fold = _lower_reporting_fold(
+        plmod.fuse_dag(_square_folds(n, names), 2048))
+    assert got_fold == fold
+    xs = np.random.RandomState(4).rand(n).astype(np.float32)
+    out = kern(x=jnp.asarray(xs))
+    sq = xs.astype(np.float64) ** 2
+    want = {"total": sq.sum(), "top": sq.max()}
+    for k in names:
+        np.testing.assert_allclose(np.asarray(out[k]), want[k], rtol=1e-5)
+
+
+def test_vector_fold_keeps_tree_template():
+    """An additive fold whose update is a vector is not a lanes fold."""
+    _, fold = _lower_reporting_fold(plmod.fuse_dag(_column_sums(2 ** 13),
+                                                   2048))
+    assert fold == "tree"

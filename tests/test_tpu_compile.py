@@ -221,3 +221,28 @@ def test_certify_step_copies_no_pool(shape, one_chip, mosaic):
     well under a stack)."""
     compiled, _, stack = _granite_step(shape, one_chip, False)
     assert compiled.memory_analysis().temp_size_in_bytes < stack
+
+
+# the Q6 cells' partitions: q6-partition's 2**22 rows, q6-scan's 2**29
+Q6_ROWS = [2 ** 22, 2 ** 29]
+COLUMN_MOVES = ("copy", "transpose")
+
+
+@pytest.mark.parametrize("rows", Q6_ROWS)
+def test_q6_columns_reach_the_kernel_unmoved(rows, one_chip, mosaic):
+    """The Q6 query as the benchmark calls it, ``lower_pipeline(pipe,
+    fused=True)`` at the DSE's plan: one Mosaic kernel, which the
+    columns reach as parameters or bitcasts, with no column-sized copy
+    or transpose in front of it."""
+    pipe, _, _ = PIPELINES["tpchq6"](rows)
+    call = plmod.lower_pipeline(pipe, fused=True)
+    specs = {t.name: _spec(t.shape, t.dtype, one_chip)
+             for t in plmod.external_inputs(pipe)}
+    text = jax.jit(lambda **kw: call(**kw)).lower(**specs).compile(
+    ).as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert "/fused_dag_tpchq6/pallas_call" in text
+    moves = [name for name, dims, op in HLO_OP.findall(text)
+             if (op in COLUMN_MOVES or any(m in name for m in COLUMN_MOVES))
+             and np.prod([int(d) for d in dims.split(",") if d]) >= rows]
+    assert moves == []
